@@ -1,7 +1,6 @@
-"""mesh-api — no dead ``jax.shard_map``, one mesh factory, serving
-takes a MeshPlane (engine port of ``scripts/check_mesh_api.py``; the
-shim's docstring carries the eight-PR outage history this rule
-exists to make unrepeatable)."""
+"""mesh-api — no deprecated ``jax.experimental.shard_map``, one
+``jax.shard_map`` call site, one mesh factory, serving takes a
+MeshPlane (engine port of ``scripts/check_mesh_api.py``)."""
 
 from __future__ import annotations
 
@@ -21,6 +20,16 @@ SERVING_DIRS = ("deeplearning4j_tpu/serving/",)
 SERVING_BANNED_CALLS = ("make_mesh", "mesh_from_grid")
 
 
+#: the deprecated shim (``check_rep=`` spelling) — banned everywhere,
+#: ``parallel/mesh.py`` included: ``jax.shard_map(..., check_vma=)`` is
+#: the API of the installed JAX.
+_DEPRECATED = "jax.experimental.shard_map"
+_DEPRECATED_MSG = (
+    "jax.experimental.shard_map is the deprecated shim — per-device "
+    "programs go through parallel.mesh.device_collective, which calls "
+    "jax.shard_map(..., check_vma=)")
+
+
 def _in_serving(rel: str) -> bool:
     rel = rel.replace(os.sep, "/")
     return any(d in rel for d in SERVING_DIRS)
@@ -37,9 +46,9 @@ def _is_mesh_ctor(node: ast.Call) -> bool:
 
 class MeshApiRule(Rule):
     name = "mesh-api"
-    description = ("no jax.shard_map (dead API), shard_map and raw "
-                   "Mesh() only in parallel/mesh.py, serving/ is handed "
-                   "a MeshPlane")
+    description = ("no jax.experimental.shard_map (deprecated shim), "
+                   "jax.shard_map and raw Mesh() only in "
+                   "parallel/mesh.py, serving/ is handed a MeshPlane")
 
     def check(self, project: Project) -> List[Finding]:
         out: List[Finding] = []
@@ -50,24 +59,25 @@ class MeshApiRule(Rule):
             for node in ast.walk(m.tree):
                 if isinstance(node, ast.Attribute):
                     chain = attr_chain(node)
-                    if chain == "jax.shard_map":
+                    if chain.startswith(_DEPRECATED):
                         out.append(Finding(
-                            self.name, m.rel, node.lineno,
-                            "jax.shard_map does not exist on this jax "
-                            "(the dead API that killed the multi-chip "
-                            "plane) — use parallel.mesh."
-                            "device_collective, or jax.jit with "
-                            "shardings"))
+                            self.name, m.rel, node.lineno, _DEPRECATED_MSG))
                     elif "shard_map" in chain.split(".") and not allowed:
                         out.append(Finding(
                             self.name, m.rel, node.lineno,
                             "shard_map reference outside "
                             "parallel/mesh.py — per-device programs go "
                             "through parallel.mesh.device_collective"))
-                elif isinstance(node, (ast.Import, ast.ImportFrom)) \
-                        and not allowed:
+                elif isinstance(node, (ast.Import, ast.ImportFrom)):
                     mod = getattr(node, "module", "") or ""
                     names = [a.name for a in node.names]
+                    if mod.startswith(_DEPRECATED) or any(
+                            n.startswith(_DEPRECATED) for n in names):
+                        out.append(Finding(
+                            self.name, m.rel, node.lineno, _DEPRECATED_MSG))
+                        continue
+                    if allowed:
+                        continue
                     if "shard_map" in mod or \
                             any("shard_map" in n for n in names):
                         out.append(Finding(

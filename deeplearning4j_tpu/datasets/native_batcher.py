@@ -32,27 +32,25 @@ from deeplearning4j_tpu.datasets.iterators import DataSetIterator, _ListBatchCor
 from deeplearning4j_tpu.native import get_lib
 
 
-def _bind(lib) -> bool:
+def _bind(lib) -> None:
+    # the library is keyed on its source's content (native/__init__.py),
+    # so a loaded one always carries the batch kernels
     if hasattr(lib, "_batcher_bound"):
-        return True
-    try:
-        fp = ctypes.POINTER(ctypes.c_float)
-        lp = ctypes.POINTER(ctypes.c_int64)
-        lib.dl4j_gather_rows.argtypes = [fp, ctypes.c_int64, ctypes.c_int64,
-                                         lp, ctypes.c_int64, fp, ctypes.c_int]
-        lib.dl4j_gather_rows.restype = ctypes.c_int64
-        lib.dl4j_gather_normalize.argtypes = [fp, ctypes.c_int64,
-                                              ctypes.c_int64, lp,
-                                              ctypes.c_int64, fp, fp, fp,
-                                              ctypes.c_int]
-        lib.dl4j_gather_normalize.restype = ctypes.c_int64
-        lib.dl4j_onehot.argtypes = [lp, ctypes.c_int64, ctypes.c_int64, fp,
-                                    ctypes.c_int]
-        lib.dl4j_onehot.restype = ctypes.c_int64
-        lib._batcher_bound = True
-        return True
-    except AttributeError:  # stale .so without the batch kernels
-        return False
+        return
+    fp = ctypes.POINTER(ctypes.c_float)
+    lp = ctypes.POINTER(ctypes.c_int64)
+    lib.dl4j_gather_rows.argtypes = [fp, ctypes.c_int64, ctypes.c_int64,
+                                     lp, ctypes.c_int64, fp, ctypes.c_int]
+    lib.dl4j_gather_rows.restype = ctypes.c_int64
+    lib.dl4j_gather_normalize.argtypes = [fp, ctypes.c_int64,
+                                          ctypes.c_int64, lp,
+                                          ctypes.c_int64, fp, fp, fp,
+                                          ctypes.c_int]
+    lib.dl4j_gather_normalize.restype = ctypes.c_int64
+    lib.dl4j_onehot.argtypes = [lp, ctypes.c_int64, ctypes.c_int64, fp,
+                                ctypes.c_int]
+    lib.dl4j_onehot.restype = ctypes.c_int64
+    lib._batcher_bound = True
 
 
 def _as_f32_2d(a: np.ndarray) -> Tuple[np.ndarray, Tuple[int, ...]]:
@@ -73,7 +71,8 @@ def gather_rows(src: np.ndarray, idx: np.ndarray,
     flat, tail = _as_f32_2d(src)
     idx = np.ascontiguousarray(idx, np.int64)
     lib = get_lib()
-    if lib is not None and _bind(lib):
+    if lib is not None:
+        _bind(lib)
         out = np.empty((len(idx), flat.shape[1]), np.float32)
         fp = ctypes.POINTER(ctypes.c_float)
         lp = ctypes.POINTER(ctypes.c_int64)
@@ -119,7 +118,8 @@ def one_hot(labels: np.ndarray, num_classes: int,
     if labels.ndim != 1:
         raise ValueError(f"labels must be [n] or [n, 1], got {labels.shape}")
     lib = get_lib()
-    if lib is not None and _bind(lib):
+    if lib is not None:
+        _bind(lib)
         out = np.empty((len(labels), num_classes), np.float32)
         rc = lib.dl4j_onehot(
             labels.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),

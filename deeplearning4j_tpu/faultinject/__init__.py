@@ -562,9 +562,8 @@ class HostTierPressure:
 def kill_endpoint(fleet, name: str) -> str:
     """Process-kill injector for the serving fleet: abruptly stop the
     named endpoint's engine worker — consumed requests vanish without
-    replies and heartbeats go silent (SIGKILL's wire signature; thread
-    mode stops the worker threads, process mode delivers the real
-    signal). Returns the name so tests can ``fleet.restart(name)``
+    replies and heartbeats go silent (SIGKILL's wire signature).
+    Returns the name so tests can ``fleet.restart(name)``
     after asserting the failover. The router must keep every affected
     future resolving (timeout → failover) and eject the endpoint."""
     fleet.kill(name)

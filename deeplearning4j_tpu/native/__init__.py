@@ -1,18 +1,23 @@
 """ctypes bindings for the native IO kernels, with pure-Python fallback.
 
 The shared library is compiled on first use (g++, baked into the image)
-and cached next to the source; environments without a toolchain fall
-back to NumPy implementations transparently — the helper-SPI "graceful
-CPU fallback" doctrine of the reference's accelerator seam
-(``ConvolutionLayer.java:60-67``) applied to the data plane.
+and cached next to the source under a name keyed on the CONTENT of
+``io_kernels.cpp`` — what loads is always built from the source that is
+there, whatever copied or checked out the tree and whenever.
+Environments without a toolchain fall back to NumPy implementations —
+the helper-SPI "graceful CPU fallback" doctrine of the reference's
+accelerator seam (``ConvolutionLayer.java:60-67``) applied to the data
+plane; :func:`data_plane` says which one is in use.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
+import tempfile
 import threading
 from typing import Optional
 
@@ -22,7 +27,6 @@ logger = logging.getLogger("deeplearning4j_tpu")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "io_kernels.cpp")
-_LIB = os.path.join(_HERE, "libdl4jtpu_io.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -31,19 +35,36 @@ _IDX_DTYPES = {0x08: np.uint8, 0x09: np.int8, 0x0B: np.dtype(">i2"),
                0x0C: np.dtype(">i4"), 0x0D: np.dtype(">f4"), 0x0E: np.dtype(">f8")}
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libdl4jtpu_io.{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
+    # build beside the target and rename: a concurrent process never
+    # loads a half-written library
+    fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
+    os.close(fd)
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-           "-o", _LIB, _SRC]
+           "-o", tmp, _SRC]
     try:
-        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=240)
-    except (FileNotFoundError, subprocess.TimeoutExpired) as e:
-        logger.info("native io build unavailable (%s); using python fallback", e)
-        return False
-    if proc.returncode != 0:
-        logger.warning("native io build failed, using python fallback:\n%s",
-                       proc.stderr[-1000:])
-        return False
-    return True
+        try:
+            proc = subprocess.run(cmd, capture_output=True, text=True,
+                                  timeout=240)
+        except (FileNotFoundError, subprocess.TimeoutExpired) as e:
+            logger.info("native io build unavailable (%s); using python "
+                        "fallback", e)
+            return False
+        if proc.returncode != 0:
+            logger.warning("native io build failed, using python "
+                           "fallback:\n%s", proc.stderr[-1000:])
+            return False
+        os.replace(tmp, lib_path)
+        return True
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -53,13 +74,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_LIB)):
-            if not _build():
-                return None
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path) and not _build(lib_path):
+            return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(lib_path)
         except OSError as e:
             logger.warning("native io load failed (%s); python fallback", e)
             return None
@@ -78,6 +97,12 @@ def get_lib() -> Optional[ctypes.CDLL]:
                                       ctypes.POINTER(ctypes.c_ubyte), ctypes.c_int64]
         _lib = lib
         return _lib
+
+
+def data_plane() -> str:
+    """``"native"`` when the C++ kernels loaded, ``"numpy"`` when the
+    fallbacks are serving."""
+    return "native" if get_lib() is not None else "numpy"
 
 
 def csv_read_floats(path: str, skip_rows: int = 0, threads: int = 0,

@@ -375,9 +375,13 @@ class TransformerGenerator(_GeneratorBase):
                     return carry, carry[1]
 
                 carry0 = (caches, tok0, lengths.astype(jnp.int32), done0)
-                _, ys = jax.lax.scan(body, carry0, jnp.arange(max_new - 1))
+                carry, ys = jax.lax.scan(body, carry0,
+                                         jnp.arange(max_new - 1))
+                # the final caches ride out (callers drop them): a
+                # donated argument that no output can alias is not
+                # donated at all, and the loop would copy the caches
                 return jnp.concatenate(
-                    [tok0[:, None], jnp.swapaxes(ys, 0, 1)], axis=1)
+                    [tok0[:, None], jnp.swapaxes(ys, 0, 1)], axis=1), carry[0]
             return decode
         return self._jit(("gen_decode", max_new) + sampler, builder,
                          donate_caches=True)
@@ -421,7 +425,8 @@ class TransformerGenerator(_GeneratorBase):
             # SANCTIONED SYNC (2 of 2): the whole burst's tokens come
             # home in ONE fetch — the fused path's entire host traffic
             # dl4j-lint: disable=hot-path-host-sync
-            toks = np.asarray(dec(params, caches, logits0, len_d, keys_d))
+            toks = np.asarray(
+                dec(params, caches, logits0, len_d, keys_d)[0])
         t2 = time.perf_counter()
         # dl4j-lint: disable=hot-path-host-sync — host ints, ms math
         self._observe(reg, b, int(np.sum(lengths)), max_new,
@@ -997,10 +1002,12 @@ class RecurrentGenerator(_GeneratorBase):
                         carry = live(carry, s)
                     return carry, carry[1]
 
-                _, ys = jax.lax.scan(body, (rstate, tok0, done0),
-                                     jnp.arange(max_new - 1))
+                carry, ys = jax.lax.scan(body, (rstate, tok0, done0),
+                                         jnp.arange(max_new - 1))
+                # final carries ride out so the donation can alias them
+                # (same contract as TransformerGenerator's decode)
                 return jnp.concatenate(
-                    [tok0[:, None], jnp.swapaxes(ys, 0, 1)], axis=1)
+                    [tok0[:, None], jnp.swapaxes(ys, 0, 1)], axis=1), carry[0]
             return decode
         return self._jit(("gen_rnn_decode", max_new) + sampler, builder,
                          donate_caches=True)
@@ -1035,7 +1042,8 @@ class RecurrentGenerator(_GeneratorBase):
                   path="generate_decode", rows=b, max_new=max_new):
             # SANCTIONED SYNC (2 of 2): one whole-burst token fetch
             # dl4j-lint: disable=hot-path-host-sync
-            toks = np.asarray(dec(params, rstate, logits0, len_d, keys_d))
+            toks = np.asarray(
+                dec(params, rstate, logits0, len_d, keys_d)[0])
         t2 = time.perf_counter()
         # dl4j-lint: disable=hot-path-host-sync — host ints, ms math
         self._observe(reg, b, int(np.sum(lengths)), max_new,

@@ -16,13 +16,16 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.base import LayerImpl, register_impl
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.attention import scaled_dot_product_attention
 from deeplearning4j_tpu.ops.flash_attention import flash_attention
-from deeplearning4j_tpu.parallel.mesh import current_sequence_mesh
+from deeplearning4j_tpu.parallel.mesh import (current_sequence_mesh,
+                                              device_collective)
 from deeplearning4j_tpu.parallel.ring_attention import ring_attention
 
 _FORCE_XLA: list = []
@@ -43,13 +46,37 @@ def xla_attention():
         _FORCE_XLA.pop()
 
 
-def dispatch_attention(q, k, v, causal: bool, mask=None):
+def _flash_per_device(q, k, v, causal: bool, mesh):
+    """The flash kernel mapped over a placement mesh: attention is
+    independent per (batch row, head), so every device runs the kernel
+    on its own rows and heads — batch divided over the mesh's
+    ``data``/``fsdp`` axes, heads over ``tp``, whatever else the mesh
+    has replicated. The partitioner cannot do this itself: a Pallas
+    kernel is opaque to it, and Mosaic refuses to lower one under a
+    multi-device jit outside a per-device region."""
+    b, _, h, _ = q.shape
+    batch = tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1)
+    if b % int(np.prod([mesh.shape[a] for a in batch], dtype=int)):
+        batch = ()
+    heads = next((a for a in ("tp", "model")
+                  if mesh.shape.get(a, 1) > 1 and h % mesh.shape[a] == 0),
+                 None)
+    spec = P(batch or None, None, heads, None)
+    local = lambda q, k, v: flash_attention(q, k, v, causal=causal)
+    # the kernel's outputs carry no varying-axes type: skip that check
+    return device_collective(local, mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
+
+
+def dispatch_attention(q, k, v, causal: bool, mask=None, mesh=None):
     """Shared parallelism dispatch for every attention-bearing layer:
     ring attention under an active sequence mesh (DP×SP when the mesh
-    also has a 'data' axis), otherwise the flash Pallas kernel
-    (key-validity masks fall back to the XLA path inside it; ring
-    blocks assume dense time, so masked inputs also stay off the ring).
-    An active ``xla_attention()`` context overrides both."""
+    also has a 'data' axis), otherwise the flash Pallas kernel — per
+    device when the net is placed over ``mesh`` (the impl's ``_mesh``).
+    Key-validity masks fall back to the XLA path inside the kernel
+    wrapper (which the partitioner handles, so they skip the per-device
+    map; ring blocks assume dense time, so masked inputs also stay off
+    the ring). An active ``xla_attention()`` context overrides all."""
     if _FORCE_XLA:
         return scaled_dot_product_attention(q, k, v, causal=causal, mask=mask)
     seq = current_sequence_mesh()
@@ -58,6 +85,8 @@ def dispatch_attention(q, k, v, causal: bool, mask=None):
         batch_axis = "data" if "data" in mesh.shape else None
         return ring_attention(q, k, v, mesh, axis=axis, causal=causal,
                               batch_axis=batch_axis)
+    if mesh is not None and mesh.size > 1 and mask is None:
+        return _flash_per_device(q, k, v, causal, mesh)
     return flash_attention(q, k, v, causal=causal, mask=mask)
 
 
@@ -95,7 +124,8 @@ class AttentionImpl(LayerImpl):
         q = split(x @ params["Wq"].astype(x.dtype))
         k = split(x @ params["Wk"].astype(x.dtype))
         v = split(x @ params["Wv"].astype(x.dtype))
-        o = dispatch_attention(q, k, v, causal=c.causal, mask=mask)
+        o = dispatch_attention(q, k, v, causal=c.causal, mask=mask,
+                               mesh=self._mesh)
         out = o.reshape(b, t, c.n_out) @ params["Wo"].astype(x.dtype) \
             + params["bo"].astype(x.dtype)
         if c.residual:

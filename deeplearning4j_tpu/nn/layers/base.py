@@ -85,6 +85,13 @@ class LayerImpl:
     # None (the default) keeps every existing path byte-identical.
     _slice_mesh = None
 
+    # Placement seam (parallel/tensor_parallel.py apply_shardings): the
+    # mesh the net's params were placed over, or None on one device. A
+    # Pallas kernel is opaque to the SPMD partitioner (Mosaic refuses to
+    # lower one under a multi-device jit), so attention impls hand this
+    # to ``dispatch_attention``, which maps the kernel over the mesh.
+    _mesh = None
+
     def _slice_replicate(self, x):
         """Constrain ``x`` to replicated over the slice mesh (identity
         when the net is not slice-served)."""

@@ -125,7 +125,8 @@ class TransformerBlockImpl(LayerImpl):
             with xla_attention():
                 o = dispatch_attention(q, k, v, causal=c.causal, mask=mask)
         else:
-            o = dispatch_attention(q, k, v, causal=c.causal, mask=mask)
+            o = dispatch_attention(q, k, v, causal=c.causal, mask=mask,
+                                   mesh=self._mesh)
         attn = qmatmul(self._slice_replicate(o.reshape(b, t, d)),
                        params, "Wo")
         if train and self.dropout_rate > 0.0 and rng is not None:
@@ -202,7 +203,8 @@ class TransformerBlockImpl(LayerImpl):
             with xla_attention():
                 o = dispatch_attention(q, k, v, causal=c.causal, mask=None)
         else:
-            o = dispatch_attention(q, k, v, causal=c.causal, mask=None)
+            o = dispatch_attention(q, k, v, causal=c.causal, mask=None,
+                                   mesh=self._mesh)
         x = self._slice_replicate(
             x + qmatmul(self._slice_replicate(o.reshape(b, t, d)),
                         params, "Wo"))

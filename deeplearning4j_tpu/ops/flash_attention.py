@@ -46,7 +46,7 @@ Design (online-softmax blocking fitted to the MXU/VMEM):
 
 CPU processes (the test mesh) run the same kernels under the Pallas
 interpreter, so fwd+bwd are exercised everywhere; the TPU path
-compiles via Mosaic.
+compiles via Mosaic. ``util.device.pallas_interpret`` decides which.
 """
 
 from __future__ import annotations
@@ -57,15 +57,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports fail on some non-TPU builds; interpreter needs only pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.attention import scaled_dot_product_attention
+from deeplearning4j_tpu.util.device import pallas_interpret
 
 _NEG_INF = -1e30  # finite sentinel: -inf scratch + exp() is nan-prone in bf16
 
@@ -78,9 +73,12 @@ def _pick_block(t: int, preferred: int) -> int:
 
 
 def _scratch(shape):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, jnp.float32)
-    return jax.ShapeDtypeStruct(shape, jnp.float32)
+    return pltpu.VMEM(shape, jnp.float32)
+
+
+_VMEM = dict(memory_space=pltpu.VMEM)
+_GRID_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _causal_live(offset, q0, bq, k0):
@@ -157,24 +155,17 @@ def _flash_fwd_impl(q, k, v, causal: bool, block_q: int, block_k: int,
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q,
         block_k=block_k, offset=tk - tq)
-    if _HAS_PLTPU and not interpret:
-        vmem = dict(memory_space=pltpu.VMEM)
-        params = dict(compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")))
-    else:  # interpreter path (CPU test meshes)
-        vmem = {}
-        params = dict(interpret=True)
     return pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **vmem),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **vmem),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **vmem),
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **_VMEM),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **_VMEM),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **_VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **vmem),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0), **vmem),
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **_VMEM),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0), **_VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
@@ -185,7 +176,8 @@ def _flash_fwd_impl(q, k, v, causal: bool, block_q: int, block_k: int,
             _scratch((block_q, 128)),
             _scratch((block_q, 128)),
         ],
-        **params,
+        compiler_params=_GRID_SEMANTICS, interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -302,17 +294,10 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal: bool,
                     axis=-1).reshape(bh, 1, tq)
     lse = lse.reshape(bh, 1, tq)
 
-    if _HAS_PLTPU and not interpret:
-        vmem = dict(memory_space=pltpu.VMEM)
-        params = dict(compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")))
-    else:
-        vmem = {}
-        params = dict(interpret=True)
-
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **vmem)
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **vmem)
-    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i), **vmem)
+    qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **_VMEM)
+    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **_VMEM)
+    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
+                           **_VMEM)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -322,13 +307,15 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal: bool,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         scratch_shapes=[_scratch((block_q, d))],
-        **params,
+        compiler_params=_GRID_SEMANTICS, interpret=interpret,
+        name="flash_dq",
     )(q, k, v, g, lse, delta)
 
     # dk/dv grid: (bh, k_blocks, q_blocks) — q innermost
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0), **vmem)
-    qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0), **vmem)
-    rowspec2 = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i), **vmem)
+    kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0), **_VMEM)
+    qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0), **_VMEM)
+    rowspec2 = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i),
+                            **_VMEM)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal,
                           block_q=block_q, block_k=block_k, offset=offset),
@@ -339,7 +326,8 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal: bool,
                    jax.ShapeDtypeStruct((bh, tk, d), v.dtype)],
         scratch_shapes=[_scratch((block_k, d)),
                         _scratch((block_k, d))],
-        **params,
+        compiler_params=_GRID_SEMANTICS, interpret=interpret,
+        name="flash_dkv",
     )(k, v, q, g, lse, delta)
     return dq, dk, dv
 
@@ -404,7 +392,7 @@ def flash_attention(
     if mask is not None or not bq or not bk or (causal and tq > tk):
         return scaled_dot_product_attention(q, k, v, causal=causal, mask=mask)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     fold = lambda z: z.transpose(0, 2, 1, 3).reshape(b * h, z.shape[1], d)
     o = _flash(fold(q), fold(k), fold(v), causal, bq, bk, interpret)
     return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

@@ -35,19 +35,16 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pragma: no cover - absent on some non-TPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from deeplearning4j_tpu.util.device import pallas_interpret
 
 
 def _scratch(shape, dtype=jnp.float32):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return pltpu.VMEM(shape, dtype)
+
+
+_VMEM = dict(memory_space=pltpu.VMEM)
 
 
 def _cell(xg_ref, wr_ref, wci_ref, wcf_ref, wco_ref, h0_ref, c0_ref,
@@ -123,18 +120,11 @@ def _fwd_pallas(xg, wr, wci, wcf, wco, h0, c0, block_b: int, interpret: bool,
     nb = b // block_b
     kernel = functools.partial(
         _fwd_kernel if with_residuals else _fwd_only_kernel, n=n)
-    if _HAS_PLTPU and not interpret:
-        vmem = dict(memory_space=pltpu.VMEM)
-        params = dict(compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")))
-    else:
-        vmem = {}
-        params = dict(interpret=True)
     step_spec = lambda last: pl.BlockSpec((1, block_b, last),
-                                          lambda i, s: (s, i, 0), **vmem)
-    wr_spec = pl.BlockSpec((n, g4), lambda i, s: (0, 0), **vmem)
-    row_spec = pl.BlockSpec((1, n), lambda i, s: (0, 0), **vmem)
-    carry_spec = pl.BlockSpec((block_b, n), lambda i, s: (i, 0), **vmem)
+                                          lambda i, s: (s, i, 0), **_VMEM)
+    wr_spec = pl.BlockSpec((n, g4), lambda i, s: (0, 0), **_VMEM)
+    row_spec = pl.BlockSpec((1, n), lambda i, s: (0, 0), **_VMEM)
+    carry_spec = pl.BlockSpec((block_b, n), lambda i, s: (i, 0), **_VMEM)
     if with_residuals:
         out_specs = [step_spec(n)] * 6
         out_shape = [jax.ShapeDtypeStruct((t, b, n), xg.dtype)] * 6
@@ -152,7 +142,10 @@ def _fwd_pallas(xg, wr, wci, wcf, wco, h0, c0, block_b: int, interpret: bool,
         out_shape=out_shape,
         scratch_shapes=[_scratch((block_b, n), xg.dtype),
                         _scratch((block_b, n))],
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="lstm_fwd" if with_residuals else "lstm_fwd_only",
     )(xg, wr, wci.reshape(1, n), wcf.reshape(1, n), wco.reshape(1, n),
       h0, c0)
     return out[0], tuple(out[1:])
@@ -265,28 +258,16 @@ def _bwd_pallas(res, wr, wci, wcf, wco, h0, c0, gout, g_clast,
     g4 = 4 * n
     nb = b // block_b
     kernel = functools.partial(_bwd_kernel, n=n)
-    if _HAS_PLTPU and not interpret:
-        vmem = dict(memory_space=pltpu.VMEM)
-        # BOTH dims "arbitrary": the dWr/peephole accumulators live in
-        # scratch SHARED across batch blocks (init at bi==0, store at
-        # bi==nb-1) — a "parallel" first dim would let a multi-core
-        # Mosaic schedule split the blocks across cores and silently
-        # lose contributions. (v5e is single-core; this is for v4/v5p.)
-        params = dict(compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")))
-    else:
-        vmem = {}
-        params = dict(interpret=True)
     rev = lambda last: pl.BlockSpec((1, block_b, last),
-                                    lambda bi, s: (t - 1 - s, bi, 0), **vmem)
+                                    lambda bi, s: (t - 1 - s, bi, 0), **_VMEM)
     # previous-timestep view: index t-2-s clamped at 0 (the t==0 program
     # overrides with h0/c0 in-kernel, so the clamped read is discarded)
     prev = pl.BlockSpec((1, block_b, n),
                         lambda bi, s: (jnp.maximum(t - 2 - s, 0), bi, 0),
-                        **vmem)
-    wr_spec = pl.BlockSpec((n, g4), lambda bi, s: (0, 0), **vmem)
-    row_spec = pl.BlockSpec((1, n), lambda bi, s: (0, 0), **vmem)
-    carry_spec = pl.BlockSpec((block_b, n), lambda bi, s: (bi, 0), **vmem)
+                        **_VMEM)
+    wr_spec = pl.BlockSpec((n, g4), lambda bi, s: (0, 0), **_VMEM)
+    row_spec = pl.BlockSpec((1, n), lambda bi, s: (0, 0), **_VMEM)
+    carry_spec = pl.BlockSpec((block_b, n), lambda bi, s: (bi, 0), **_VMEM)
     out = pl.pallas_call(
         kernel,
         grid=(nb, t),
@@ -305,7 +286,15 @@ def _bwd_pallas(res, wr, wci, wcf, wco, h0, c0, gout, g_clast,
         scratch_shapes=[_scratch((block_b, n)), _scratch((block_b, n)),
                         _scratch((n, g4)), _scratch((1, n)),
                         _scratch((1, n)), _scratch((1, n))],
-        **params,
+        # BOTH dims "arbitrary": the dWr/peephole accumulators live in
+        # scratch SHARED across batch blocks (init at bi==0, store at
+        # bi==nb-1) — a "parallel" first dim would let a multi-core
+        # Mosaic schedule split the blocks across cores and silently
+        # lose contributions. (v5e is single-core; this is for v4/v5p.)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="lstm_bptt",
     )(i, f, o, blk, c, c, o, gout, wr,
       wci.reshape(1, n), wcf.reshape(1, n), wco.reshape(1, n),
       h0, c0, g_clast)
@@ -445,7 +434,7 @@ def _pick_block_b(b: int) -> int:
 
 
 def _on_tpu() -> bool:  # patchable seam for tests
-    return jax.default_backend() == "tpu"
+    return not pallas_interpret()
 
 
 #: largest hidden size the kernel accepts per dtype width: the
@@ -512,7 +501,6 @@ def fused_lstm_scan(xg, wr, wci, wcf, wco, h0, c0
         raise ValueError(
             f"batch {b} is not tileable (must be a multiple of 8); "
             f"gate with fused_lstm_applicable or use the XLA scan")
-    interpret = jax.default_backend() != "tpu"
     h_seq, h_last, c_last = _fused(xg, wr, wci, wcf, wco, h0, c0,
-                                   block_b, interpret)
+                                   block_b, pallas_interpret())
     return h_seq, (h_last, c_last)

@@ -341,7 +341,9 @@ class ParallelInference:
     window is a throughput knob under load, not a latency floor at
     light load. ``queue_capacity`` + ``reject_when_full`` set the
     backpressure policy, ``replicas`` limits how many ``jax.devices()``
-    entries get a pinned copy of the model, ``coalesce=False`` is
+    entries get a pinned copy of the model (default: all of them; one
+    under ``continuous=True``, whose scheduler serves from one device),
+    ``coalesce=False`` is
     INPLACE mode (one request = one dispatch, no padding)."""
 
     def __init__(self, net=None, max_batch_size: int = 32,
@@ -416,6 +418,13 @@ class ParallelInference:
         devs = list(devices) if devices is not None else jax.devices()
         if replicas is not None:
             devs = devs[:max(1, int(replicas))]
+        elif devices is None and continuous:
+            # the decode scheduler serves from ONE device (one slot
+            # batch, one paged pool): a default replica on every other
+            # chip would hold weights and serve nothing. replicas= /
+            # devices= still widen the classify path; to decode on every
+            # chip run one engine per chip (devices=[d]).
+            devs = devs[:1]
         if not devs:
             raise ValueError("no devices to place replicas on")
         if net is not None and self.slice_plane is not None:

@@ -10,8 +10,8 @@ discipline, Xu et al.):
 - :class:`MeshPlane` owns the named-axis ``jax.sharding.Mesh`` plus a
   :class:`SpecLayout`, and is the ONLY place a raw ``Mesh`` may be
   constructed (``scripts/check_mesh_api.py`` lints the repo for rogue
-  mesh construction and for the dead ``jax.shard_map`` attribute that
-  killed the plane once already);
+  mesh construction and for the deprecated experimental ``shard_map``
+  import);
 - :class:`SpecLayout` maps parameter names → ``PartitionSpec``s. It is
   JSON-serializable, which is what makes checkpoints MESH-PORTABLE: the
   layout rides in the checkpoint manifest and ``restore_checkpoint``
@@ -33,9 +33,7 @@ sharded inputs (or explicit ``in_shardings``/``out_shardings``) lets
 GSPMD insert the collectives. The exceptions — programs whose SEMANTICS
 are per-device (ring ppermute schedules, pipeline tick loops, psum'd
 scatter-adds) — go through :func:`device_collective`, the one sanctioned
-``shard_map`` entry point (``jax.shard_map`` does not exist on this
-jax; the experimental spelling is quarantined here so the dead-API
-family can never creep back).
+``jax.shard_map`` entry point.
 
 Multi-host: call ``jax.distributed.initialize()`` before ``make_mesh``
 and the same code spans hosts — device order follows ``jax.devices()``,
@@ -51,7 +49,6 @@ from typing import Any, Dict, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.monitor import (MESH_AXIS_SIZE_GAUGE,
@@ -90,15 +87,15 @@ def make_mesh(axes: Optional[Dict[str, int]] = None,
 
 
 def device_collective(fn, mesh: Mesh, in_specs, out_specs,
-                      check_rep: bool = True):
+                      check_vma: bool = True):
     """Map ``fn`` as a per-device program over ``mesh`` — the sanctioned
     entry point for code whose semantics are genuinely per-device
     (``ppermute`` rings, pipeline tick loops, psum'd scatter-adds).
     Anything expressible as global-array math should instead use
     ``jax.jit`` over sharded inputs and let GSPMD derive the
     collectives."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_rep)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # -------------------------------------------------------------- SpecLayout
@@ -332,11 +329,11 @@ class MeshPlane:
     # ----------------------------------------------------- collectives
 
     def device_collective(self, fn, in_specs, out_specs,
-                          check_rep: bool = True):
+                          check_vma: bool = True):
         """Per-device program over THIS plane's mesh (see module-level
         :func:`device_collective`)."""
         return device_collective(fn, self.mesh, in_specs, out_specs,
-                                 check_rep=check_rep)
+                                 check_vma=check_vma)
 
     # ------------------------------------------------- model placement
 
@@ -432,8 +429,9 @@ def apply_serving_slice(net, plane: MeshPlane,
     bitwise-exactness seam on every layer impl (``_slice_mesh``: the
     impls constrain activations back to replicated before each
     cross-shard reduction, and attention stays on the XLA formulation —
-    a Pallas kernel cannot see the mesh). Existing jit caches are
-    dropped: programs traced before the placement baked no constraints.
+    a Pallas kernel cannot see the mesh). ``apply_shardings`` drops the
+    existing jit caches: programs traced before the placement baked no
+    constraints.
 
     The net must be dedicated to this slice (restore the mesh-portable
     checkpoint per slice, or deep-copy): program caches live on the net
@@ -469,8 +467,6 @@ def apply_serving_slice(net, plane: MeshPlane,
     for impl in impls:
         impl._slice_mesh = net.mesh_plane.mesh
     net.slice_plane = net.mesh_plane
-    net._jits.clear()
-    net.__dict__.pop("_generator", None)
     return net.mesh_plane
 
 

@@ -80,8 +80,11 @@ def pipeline_apply(stage_params, fn: Callable, x: jnp.ndarray,
                                       [(i, (i + 1) % p) for i in range(p)])
             return h_next, outs
 
-        h0 = jnp.zeros(mb_shape, x.dtype)
-        outs0 = jnp.zeros((m,) + mb_shape, x.dtype)
+        # typed varying over the stage axis: every tick writes this
+        # device's stage output back into the carries
+        h0, outs0 = jax.lax.pcast(
+            (jnp.zeros(mb_shape, x.dtype),
+             jnp.zeros((m,) + mb_shape, x.dtype)), axis, to="varying")
         _, outs = jax.lax.fori_loop(0, n_ticks, tick, (h0, outs0))
         # only the last stage holds real outputs; broadcast over the axis
         outs = jnp.where(my == p - 1, outs, jnp.zeros_like(outs))

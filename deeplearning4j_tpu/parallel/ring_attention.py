@@ -67,8 +67,14 @@ def ring_attention(
     def local(qb, kb, vb):
         my = jax.lax.axis_index(axis)
         b, tq, h, d = qb.shape
-        m0 = jnp.full((b, h, tq), jnp.finfo(qb.dtype).min, qb.dtype)
-        l0 = jnp.zeros((b, h, tq), qb.dtype)
+        # fresh constants are typed replicated; the loop writes values
+        # that vary over the sharded axes back into the same carries,
+        # and shard_map's type check needs carry-in == carry-out
+        # (a0 inherits qb's type through zeros_like)
+        vary = (axis,) if batch_axis is None else (batch_axis, axis)
+        m0, l0 = jax.lax.pcast(
+            (jnp.full((b, h, tq), jnp.finfo(qb.dtype).min, qb.dtype),
+             jnp.zeros((b, h, tq), qb.dtype)), vary, to="varying")
         a0 = jnp.zeros_like(qb)
         qpos = my * blk + jnp.arange(blk)
 

@@ -94,7 +94,10 @@ def apply_shardings(model, mesh: Mesh,
     ``specs``; unlisted params are replicated. Subsequent ``fit`` calls
     compile SPMD with these placements. The layout is pinned on the
     model as ``model.mesh_plane`` (a :class:`~..mesh.MeshPlane`) — the
-    seam mesh-portable checkpoints and the supervisor read."""
+    seam mesh-portable checkpoints and the supervisor read — and on
+    every layer impl as ``_mesh``, which per-device kernels map over
+    (``nn/layers/attention.py``). Existing jit caches are dropped:
+    programs traced before the placement baked no mesh in."""
     from deeplearning4j_tpu.parallel.mesh import MeshPlane, SpecLayout
 
     place = _placer(mesh, specs)
@@ -105,3 +108,8 @@ def apply_shardings(model, mesh: Mesh,
     if plane is None:
         plane = MeshPlane(mesh, SpecLayout(specs))
     model.mesh_plane = plane
+    impls = model.impls
+    for impl in (impls if isinstance(impls, list) else impls.values()):
+        impl._mesh = mesh
+    model._jits.clear()
+    model.__dict__.pop("_generator", None)

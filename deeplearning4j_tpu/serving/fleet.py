@@ -6,32 +6,19 @@ owns a broker, spawns engine workers (each with its OWN
 ``RemoteEndpoint`` per worker, and applies
 :class:`~deeplearning4j_tpu.serving.policy.ScalePolicy` decisions.
 
-Endpoint modes:
-
-- ``mode="thread"`` (default): workers run on daemon threads in this
-  process, reached through the SAME broker wire protocol remote
-  workers use. ``kill()`` stops a worker abruptly — no replies, no
-  heartbeats, requests already consumed vanish — which is exactly the
-  wire signature of SIGKILL on an engine process, while staying
-  deterministic and safe on this box (the conftest notes:
-  fork-after-jax segfaults, so tier-1 tests must not spawn compute
-  subprocesses).
-- ``mode="process"``: workers are real OS processes
-  (``python -m deeplearning4j_tpu.serving.procworker``) reached over a
-  ``TcpBrokerServer``; ``kill()`` is SIGKILL. The model is shipped as
-  a zip via ``util/model_serializer``. For benches/deployments — not
-  used by tier-1 tests.
+Workers run on daemon threads in THIS process, reached through the
+same broker wire protocol remote workers use: an accelerator belongs
+to one process at a time, so one process drives every chip of a host
+(a child spawned by a parent that has touched JAX could never claim
+one). ``kill()`` stops a worker abruptly — no replies, no heartbeats,
+requests already consumed vanish — which is exactly the wire signature
+of SIGKILL on an engine process, while staying deterministic.
 """
 
 from __future__ import annotations
 
 import itertools
 import logging
-import os
-import signal
-import subprocess
-import sys
-import tempfile
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -39,24 +26,19 @@ from typing import Any, Callable, Dict, List, Optional
 from deeplearning4j_tpu.serving.endpoint import RemoteEndpoint
 from deeplearning4j_tpu.serving.policy import ScaleDecision, ScalePolicy
 from deeplearning4j_tpu.serving.worker import EngineWorker
-from deeplearning4j_tpu.streaming.broker import (InMemoryBroker,
-                                                 MessageBroker, TcpBroker,
-                                                 TcpBrokerServer)
+from deeplearning4j_tpu.streaming.broker import InMemoryBroker
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
 
 class _Member:
-    """One fleet slot: endpoint + however it is backed."""
+    """One fleet slot: endpoint + the worker thread backing it."""
 
     def __init__(self, name: str, endpoint: RemoteEndpoint,
-                 worker: Optional[EngineWorker] = None,
-                 proc: Optional[subprocess.Popen] = None,
-                 plane=None):
+                 worker: EngineWorker, plane=None):
         self.name = name
         self.endpoint = endpoint
         self.worker = worker
-        self.proc = proc
         # mesh-slice backing (slice_width mode): the MeshPlane this
         # member's engine is sharded over — rebuild_slice narrows it
         self.plane = plane
@@ -66,30 +48,18 @@ class LocalFleet:
     """Manage a fleet of engine endpoints behind one broker.
 
     ``engine_factory()`` must return a fresh started
-    ``ParallelInference`` (thread mode). ``router=`` (optional) keeps
+    ``ParallelInference``. ``router=`` (optional) keeps
     an :class:`InferenceRouter` membership in sync with the fleet.
     """
 
-    def __init__(self, engine_factory: Optional[Callable] = None,
-                 mode: str = "thread",
+    def __init__(self, engine_factory: Callable,
                  service_prefix: str = "engine",
                  router=None,
                  heartbeat_s: float = 0.1,
                  request_timeout_s: float = 5.0,
                  heartbeat_timeout_s: float = 1.0,
-                 model_path: Optional[str] = None,
-                 procworker_args: Optional[List[str]] = None,
                  slice_width: Optional[int] = None,
                  slice_devices: Optional[List] = None):
-        if mode not in ("thread", "process"):
-            raise ValueError(f"mode must be thread|process, got {mode!r}")
-        if mode == "thread" and engine_factory is None:
-            raise ValueError("thread mode needs engine_factory")
-        if mode == "process" and model_path is None:
-            raise ValueError("process mode needs model_path")
-        if slice_width is not None and mode != "thread":
-            raise ValueError("slice_width is a thread-mode feature")
-        self.mode = mode
         self.engine_factory = engine_factory
         # mesh-sharded slices: each endpoint's engine runs on a
         # slice_width-chip MeshPlane carved from slice_devices (default:
@@ -109,23 +79,10 @@ class LocalFleet:
         self.heartbeat_s = float(heartbeat_s)
         self.request_timeout_s = float(request_timeout_s)
         self.heartbeat_timeout_s = float(heartbeat_timeout_s)
-        self.model_path = model_path
-        self.procworker_args = list(procworker_args or [])
         self._members: Dict[str, _Member] = {}
         self._ids = itertools.count()
         self._lock = threading.Lock()
-        self._server: Optional[TcpBrokerServer] = None
-        if mode == "process":
-            self._server = TcpBrokerServer().start()
-            self._broker: MessageBroker = self._connect()
-        else:
-            self._broker = InMemoryBroker()
-
-    def _connect(self) -> MessageBroker:
-        if self._server is not None:
-            host, port = self._server.address
-            return TcpBroker(host, port)
-        return self._broker
+        self._broker = InMemoryBroker()
 
     # --------------------------------------------------------- members
 
@@ -146,33 +103,19 @@ class LocalFleet:
         name = name or f"{self.service_prefix}-{next(self._ids)}"
         service = name
         plane = None
-        if self.mode == "thread":
-            if self.slice_width is not None:
-                plane = self._carve_slice(self.slice_width)
-                engine = self.engine_factory(plane)
-            else:
-                engine = self.engine_factory()
-            worker = EngineWorker(engine, self._broker, service, name=name,
-                                  heartbeat_s=self.heartbeat_s)
-            proc = None
+        if self.slice_width is not None:
+            plane = self._carve_slice(self.slice_width)
+            engine = self.engine_factory(plane)
         else:
-            worker = None
-            host, port = self._server.address
-            proc = subprocess.Popen(
-                [sys.executable, "-m",
-                 "deeplearning4j_tpu.serving.procworker",
-                 "--broker", f"{host}:{port}", "--service", service,
-                 "--model", self.model_path,
-                 "--heartbeat-s", str(self.heartbeat_s),
-                 *self.procworker_args])
-        factory = (self._connect if self._server is not None else None)
+            engine = self.engine_factory()
+        worker = EngineWorker(engine, self._broker, service, name=name,
+                              heartbeat_s=self.heartbeat_s)
         endpoint = RemoteEndpoint(
-            self._connect(), service, name=name, broker_factory=factory,
+            self._broker, service, name=name,
             request_timeout_s=self.request_timeout_s,
             heartbeat_timeout_s=self.heartbeat_timeout_s)
         with self._lock:
-            self._members[name] = _Member(name, endpoint, worker, proc,
-                                          plane)
+            self._members[name] = _Member(name, endpoint, worker, plane)
         if self.router is not None:
             self.router.add_endpoint(endpoint)
         return endpoint
@@ -220,45 +163,37 @@ class LocalFleet:
 
     def kill(self, name: str) -> None:
         """Abrupt endpoint death (the faultinject process-kill seam):
-        thread mode stops the worker without replies or heartbeats;
-        process mode SIGKILLs. The endpoint object stays registered —
-        the router observes the death through missed heartbeats and
-        reply timeouts, exactly as it would a remote host loss."""
+        the worker stops without replies or heartbeats. The endpoint
+        object stays registered — the router observes the death through
+        missed heartbeats and reply timeouts, exactly as it would a
+        remote host loss."""
         with self._lock:
             m = self._members[name]
-        if m.worker is not None:
-            m.worker.kill()
-            try:  # the process's engine dies with it
-                m.worker.engine.shutdown(drain=False)
-            except BaseException:
-                pass
-        if m.proc is not None:
-            m.proc.send_signal(signal.SIGKILL)
-            m.proc.wait(timeout=10)
+        m.worker.kill()
+        try:  # the worker's engine dies with it
+            m.worker.engine.shutdown(drain=False)
+        except BaseException:
+            pass
         logger.info("fleet: killed %s", name)
 
     def wedge(self, name: str) -> None:
-        """Faultinject seam (thread mode): the member keeps
-        heartbeating but silently drops every consumed request — the
-        liveness-without-progress failure the router's wedge watchdog
-        exists for."""
+        """Faultinject seam: the member keeps heartbeating but silently
+        drops every consumed request — the liveness-without-progress
+        failure the router's wedge watchdog exists for."""
         with self._lock:
             m = self._members[name]
-        if m.worker is None:
-            raise RuntimeError("wedge() is a thread-mode seam")
         m.worker.wedge()
         logger.info("fleet: wedged %s", name)
 
     def unwedge(self, name: str) -> None:
         with self._lock:
             m = self._members[name]
-        if m.worker is not None:
-            m.worker.unwedge()
+        m.worker.unwedge()
         logger.info("fleet: unwedged %s", name)
 
     def kill_chip(self, name: str, victim: Optional[int] = None,
                   seed: int = 0):
-        """Faultinject seam (thread + slice mode): arm a seeded
+        """Faultinject seam (slice mode): arm a seeded
         :class:`~deeplearning4j_tpu.faultinject.SliceKill` on the
         member's engine — its next dispatch (classify batch or decode
         burst) raises a ``ChipFailure`` naming the slice's survivors,
@@ -269,8 +204,8 @@ class LocalFleet:
         from deeplearning4j_tpu.faultinject import SliceKill
         with self._lock:
             m = self._members[name]
-        if m.worker is None or m.plane is None:
-            raise RuntimeError("kill_chip() is a thread+slice-mode seam")
+        if m.plane is None:
+            raise RuntimeError("kill_chip() is a slice-mode seam")
         eng = m.worker.engine
         inj = SliceKill(m.plane, victim=victim, seed=seed, fail_at=0)
         eng._poison_hook = inj
@@ -297,9 +232,8 @@ class LocalFleet:
                                                 get_registry)
         with self._lock:
             m = self._members[name]
-        if m.worker is None or m.plane is None:
-            raise RuntimeError("rebuild_slice() is a thread+slice-mode "
-                               "seam")
+        if m.plane is None:
+            raise RuntimeError("rebuild_slice() is a slice-mode seam")
         old_devs = list(m.plane.mesh.devices.flat)
         # the dead chip: named by the engine's ChipFailure when it
         # carries survivor ids, else assume the first chip died
@@ -319,7 +253,7 @@ class LocalFleet:
         new_width = int(width) if width is not None \
             else max(1, len(old_devs) // 2)
         new_width = min(new_width, max(1, len(survivors)))
-        if m.worker is not None and not m.worker._killed.is_set():
+        if not m.worker._killed.is_set():
             m.worker.kill()
         try:
             m.worker.engine.shutdown(drain=False)
@@ -353,22 +287,12 @@ class LocalFleet:
         endpoint reconnects through its existing consumer threads)."""
         with self._lock:
             m = self._members[name]
-        if self.mode == "thread":
-            if m.worker is not None and not m.worker._killed.is_set():
-                m.worker.kill()
-            engine = (self.engine_factory(m.plane) if m.plane is not None
-                      else self.engine_factory())
-            m.worker = EngineWorker(engine, self._broker, name, name=name,
-                                    heartbeat_s=self.heartbeat_s)
-        else:
-            host, port = self._server.address
-            m.proc = subprocess.Popen(
-                [sys.executable, "-m",
-                 "deeplearning4j_tpu.serving.procworker",
-                 "--broker", f"{host}:{port}", "--service", name,
-                 "--model", self.model_path,
-                 "--heartbeat-s", str(self.heartbeat_s),
-                 *self.procworker_args])
+        if not m.worker._killed.is_set():
+            m.worker.kill()
+        engine = (self.engine_factory(m.plane) if m.plane is not None
+                  else self.engine_factory())
+        m.worker = EngineWorker(engine, self._broker, name, name=name,
+                                heartbeat_s=self.heartbeat_s)
         logger.info("fleet: restarted %s", name)
 
     def remove_endpoint(self, name: str,
@@ -379,15 +303,7 @@ class LocalFleet:
             m = self._members.pop(name)
         if self.router is not None:
             self.router.remove_endpoint(name)
-        if m.worker is not None:
-            m.worker.drain_and_stop(timeout=drain_timeout)
-        if m.proc is not None:
-            m.proc.terminate()  # procworker drains on SIGTERM
-            try:
-                m.proc.wait(timeout=drain_timeout)
-            except subprocess.TimeoutExpired:
-                m.proc.kill()
-                m.proc.wait(timeout=10)
+        m.worker.drain_and_stop(timeout=drain_timeout)
         m.endpoint.close()
 
     # -------------------------------------------------------- autoscale
@@ -431,8 +347,6 @@ class LocalFleet:
                         m.endpoint.close()
             except KeyError:
                 pass
-        if self._server is not None:
-            self._server.stop()
 
     def __enter__(self) -> "LocalFleet":
         return self

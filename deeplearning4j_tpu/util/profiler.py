@@ -4,115 +4,76 @@ Parity: SURVEY §5 tracing — the reference has PerformanceListener +
 Spark phase timers + StatsListener telemetry (all rebuilt:
 ``optimize/listeners.py``, ``optimize/training_stats.py``,
 ``ui/stats.py``); the named TPU equivalent "XLA/TPU profiler traces"
-is this module: thin, dependency-tolerant wrappers over
-``jax.profiler`` producing TensorBoard-loadable traces of the real
-device timeline (compilation, fusion, HBM traffic — the layers Python
-timers can't see).
+is this module: thin wrappers over ``jax.profiler`` producing traces of
+the real device timeline (compilation, fusion, HBM traffic — the layers
+Python timers can't see), and the reader that finds them again.
+
+A trace that was asked for and cannot be taken is an error: a silent
+no-op here once hid that device tracing had been off for a whole round.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Optional
-
-def _shield_tensorflow() -> None:
-    """XLA's profiler session tries ``import tensorflow.python.profiler``
-    from inside C++ (python_hooks.cc); on this stack loading tensorflow's
-    C extensions into a process that already holds jaxlib SEGFAULTS —
-    not an ImportError, nothing downstream can catch it. Pre-inserting a
-    stub module turns that import into a clean failure XLA logs and
-    ignores, and the trace still writes its TensorBoard/Perfetto files
-    (the TF hook is optional). No-op when tensorflow is already imported
-    (the user made that call) or ``DL4J_TPU_ALLOW_TF=1``."""
-    import os
-    import sys
-    import types
-
-    if os.environ.get("DL4J_TPU_ALLOW_TF") == "1" or "tensorflow" in sys.modules:
-        return
-    stub = types.ModuleType("tensorflow")
-    stub.__getattr__ = lambda name: (_ for _ in ()).throw(ImportError(
-        f"tensorflow.{name} unavailable: tensorflow is stubbed out — "
-        "loading it alongside jaxlib crashes this process "
-        "(set DL4J_TPU_ALLOW_TF=1 to disable the shield)"))
-    sys.modules["tensorflow"] = stub
+import glob
+import os
+from typing import List
 
 
 @contextlib.contextmanager
-def trace(log_dir: str, create_perfetto_link: bool = False,
-          python_tracer: bool = False):
+def trace(log_dir: str):
     """Capture a device trace for the enclosed block::
 
         with profiler.trace("/tmp/jax-trace"):
             net.fit_scan(ds, 512, epochs=1)
-        # then: tensorboard --logdir /tmp/jax-trace
-        # (or load the *.trace.json.gz into https://ui.perfetto.dev)
+        profile = profiler.load_trace("/tmp/jax-trace")
 
-    No-ops (with a warning) when the backend can't trace.
-
-    The default drives a ProfilerSession directly with the PYTHON tracer
-    disabled: the host-side story lives in ``monitor/`` spans already,
-    and XLA's python hooks both pull tensorflow into the process and
-    crash at session-stop when other threads (async prefetch, UI server)
-    are live. ``python_tracer=True`` (or ``create_perfetto_link=True``)
-    opts back into the stock ``jax.profiler.start_trace`` path.
-    """
+    Writes ``<log_dir>/plugins/profile/<time>/<host>.xplane.pb``
+    (TensorBoard's profile plugin loads the directory). The Python
+    tracer is off: the host-side story lives in ``monitor/`` spans and
+    :func:`annotate` regions, which the trace still records. Raises if
+    the profiler cannot start or cannot write."""
     import jax
-    import os
 
-    session = None
-    started = False
-    try:
-        if os.environ.get("DL4J_TPU_DISABLE_DEVICE_TRACE") == "1":
-            # explicit kill-switch: environments where ProfilerSession is
-            # known to crash the process outright (the pytest CPU harness
-            # — C++-level segfault, uncatchable) set this and get the
-            # documented warn-and-no-op degradation instead
-            raise RuntimeError("device tracing disabled by "
-                               "DL4J_TPU_DISABLE_DEVICE_TRACE=1")
-        _shield_tensorflow()  # session creation may import TF regardless
-        if python_tracer or create_perfetto_link:
-            jax.profiler.start_trace(log_dir,
-                                     create_perfetto_link=create_perfetto_link)
-            started = True
-        else:
-            from jaxlib import xla_client
-            opts = xla_client.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            session = xla_client.profiler.ProfilerSession(opts)
-    except Exception as e:  # tunneled/experimental backends may refuse
-        import logging
-        logging.getLogger(__name__).warning("profiler trace unavailable: %s", e)
-        session = None
-        started = False
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.raise_error_on_start_failure = True
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
     try:
         yield
     finally:
-        try:
-            if session is not None:
-                session.export(session.stop(), str(log_dir))
-            elif started:
-                jax.profiler.stop_trace()
-        except Exception:
-            pass
+        jax.profiler.stop_trace()
 
 
-def start_server(port: int = 9999) -> Optional[object]:
-    """Start the on-demand profiling server (connect with TensorBoard's
-    capture-profile button). Returns the server or None if unsupported."""
+def load_trace(log_dir: str):
+    """The newest capture under ``log_dir`` as a
+    ``jax.profiler.ProfileData`` (planes → lines → events with
+    ``start_ns``/``duration_ns``); raises when there is none."""
     import jax
-    import os
 
-    try:
-        if os.environ.get("DL4J_TPU_DISABLE_DEVICE_TRACE") == "1":
-            raise RuntimeError("device tracing disabled by "
-                               "DL4J_TPU_DISABLE_DEVICE_TRACE=1")
-        _shield_tensorflow()
-        return jax.profiler.start_server(port)
-    except Exception as e:
-        import logging
-        logging.getLogger(__name__).warning("profiler server unavailable: %s", e)
-        return None
+    paths = sorted(glob.glob(os.path.join(
+        str(log_dir), "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+    return jax.profiler.ProfileData.from_file(paths[-1])
+
+
+def device_planes(profile) -> List:
+    """The accelerator planes of a capture (``/device:TPU:0`` …) — a
+    CPU-only capture has none, only ``/host:CPU``. The runtime's own
+    ``/device:CUSTOM:…`` planes (e.g. the Megascale trace, empty on one
+    host) are not devices."""
+    return [p for p in profile.planes
+            if p.name.startswith("/device:")
+            and not p.name.startswith("/device:CUSTOM:")]
+
+
+def start_server(port: int = 9999):
+    """Start the on-demand profiling server (connect with TensorBoard's
+    capture-profile button)."""
+    import jax
+
+    return jax.profiler.start_server(port)
 
 
 def annotate(name: str):

@@ -1,11 +1,13 @@
 #!/usr/bin/env python
-"""Perf-regression trend gate over the committed bench history.
+"""Perf-regression trend gate over a directory of bench rounds.
 
-The repo commits one ``BENCH_r<NN>.json`` per growth round — the raw
-driver record ``{"n", "cmd", "rc", "tail", "parsed"}`` where ``parsed``
-is ``bench.py``'s stdout JSON (``schema_version`` + headline +
-``sub_benchmarks``). This script turns that history into per-metric
-trend series and GATES a candidate payload against them:
+A round is one ``BENCH_r<NN>.json`` — the raw driver record ``{"n",
+"cmd", "rc", "tail", "parsed"}`` where ``parsed`` is ``bench.py``'s
+stdout JSON (``schema_version`` + headline + ``sub_benchmarks``). The
+repo itself holds none any more (the driver's ``PERF_LEDGER.jsonl``
+replaced them); point ``--history`` at wherever rounds are kept. This
+script turns that history into per-metric trend series and GATES a
+candidate payload against them:
 
 - **history** — every ``BENCH_r*.json`` in ``--history`` (default:
   repo root), ordered by round number; malformed rounds fail loudly

@@ -3,13 +3,12 @@
 static-analysis engine (``deeplearning4j_tpu/analysis/``; run
 everything via ``scripts/analyze.py``).
 
-The invariants, unchanged since PR 9/12 (the ``jax.shard_map``
-AttributeError family was dead code for eight PRs before this lint):
+The invariants:
 
-1. **No dead API**: any ``jax.shard_map`` attribute access is an
-   error, and ``jax.experimental.shard_map`` may be imported or
-   referenced ONLY by ``parallel/mesh.py`` — per-device programs go
-   through its one sanctioned ``device_collective`` wrapper.
+1. **One shard_map call site**: ``jax.shard_map`` may be referenced
+   ONLY by ``parallel/mesh.py`` — per-device programs go through its
+   one sanctioned ``device_collective`` wrapper — and the deprecated
+   ``jax.experimental.shard_map`` shim is an error everywhere.
 2. **One mesh factory**: ``Mesh(...)`` construction outside
    ``parallel/mesh.py`` is an error — topology lives on the MeshPlane.
 3. **Serving goes through the plane**: inside
@@ -74,7 +73,7 @@ def main(argv=None) -> int:
     for p in problems:
         print(p, file=sys.stderr)
     if not problems:
-        print(f"ok: no dead shard_map API and no rogue mesh construction "
+        print(f"ok: no stray shard_map and no rogue mesh construction "
               f"under {root}")
     return 1 if problems else 0
 

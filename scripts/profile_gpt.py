@@ -2,81 +2,61 @@
 
 Usage: python scripts/profile_gpt.py [--trace] [--d-model N] ...
 Prints tokens/sec + MFU; with --trace, aggregates device op self-times
-from the captured trace into components (attention fwd/bwd, matmuls,
-loss, elementwise, other) — the BASELINE.md attribution workflow.
+from the captured trace by op group (flash fwd/dq/dkv kernels,
+fusions, copies) — the BASELINE.md attribution workflow.
 """
 import argparse
 import collections
-import glob
-import gzip
-import json
 import os
+import re
 import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax
-
-jax.config.update("jax_compilation_cache_dir", "/root/.cache/jax_comp_cache")
-
 import numpy as np
 
-PEAK_BF16 = 197e12
+from deeplearning4j_tpu.util import profiler
+from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+from deeplearning4j_tpu.util.device import device_peaks
 
 
 def aggregate_trace(log_dir):
-    """Aggregate XLA-Ops-lane SELF times (events nest: jit_run > while >
-    fusion — walk each lane's intervals with a stack and subtract child
-    time) from the newest trace.json.gz under ``log_dir``.
-    Returns [(group_name, hlo_category, total_us, count)] sorted by
-    time, where group_name strips trailing .N instance suffixes."""
-    import re
-    paths = sorted(glob.glob(os.path.join(
-        log_dir, "plugins", "profile", "*", "*.trace.json.gz")))
-    if not paths:
-        return None
-    with gzip.open(paths[-1], "rt") as f:
-        ev = json.load(f)["traceEvents"]
-    # device lanes: pid whose process_name metadata mentions TPU/device
-    dev_pids = set()
-    for e in ev:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            name = e.get("args", {}).get("name", "")
-            if "TPU" in name or "/device" in name.lower():
-                dev_pids.add(e["pid"])
-    lanes = collections.defaultdict(list)
-    for e in ev:
-        if e.get("ph") == "X" and e.get("pid") in dev_pids:
-            lanes[(e["pid"], e.get("tid"))].append(e)
+    """Aggregate XLA-Ops-line SELF times (events nest: while > fusion —
+    walk each line's intervals with a stack and subtract child time)
+    from the newest ``.xplane.pb`` under ``log_dir``.
+    Returns [(group_name, total_us, count)] sorted by time, where
+    group_name is the HLO instruction's name with trailing .N instance
+    suffixes stripped (``fusion``, ``copy``, ``jvp_flash_fwd_`` …; the
+    capture carries no hlo_category per event)."""
     agg = collections.Counter()
     cnt = collections.Counter()
-    cat = {}
-    for lane in lanes.values():
-        lane.sort(key=lambda e: (e["ts"], -e.get("dur", 0)))
-        stack = []  # [end_ts, event, child_dur]
+    for plane in profiler.device_planes(profiler.load_trace(log_dir)):
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            events = sorted(line.events,
+                            key=lambda e: (e.start_ns, -e.duration_ns))
+            stack = []  # [end_ns, event, child_ns]
 
-        def pop_one():
-            end0, e0, child0 = stack.pop()
-            key = re.sub(r"(\.\d+)+$", "", e0["name"])
-            c = e0.get("args", {}).get("hlo_category", "?")
-            # whole-module/step container lanes mirror total time;
-            # keep only real HLO ops (they carry hlo_category)
-            if c != "?":
-                agg[key] += max(e0.get("dur", 0) - child0, 0)
+            def pop_one():
+                _, e0, child0 = stack.pop()
+                # event names are HLO text: "%fusion.12 = bf16[...] ..."
+                key = re.sub(r"(\.\d+)+$", "",
+                             e0.name.split(" = ")[0].lstrip("%"))
+                agg[key] += max(e0.duration_ns - child0, 0) / 1e3
                 cnt[key] += 1
-                cat[key] = c
-            if stack:
-                stack[-1][2] += e0.get("dur", 0)
+                if stack:
+                    stack[-1][2] += e0.duration_ns
 
-        for e in lane:
-            while stack and e["ts"] >= stack[-1][0]:
+            for e in events:
+                while stack and e.start_ns >= stack[-1][0]:
+                    pop_one()
+                stack.append([e.start_ns + e.duration_ns, e, 0])
+            while stack:
                 pop_one()
-            stack.append([e["ts"] + e.get("dur", 0), e, 0])
-        while stack:
-            pop_one()
-    return sorted(((n, cat[n], d, cnt[n]) for n, d in agg.items()),
-                  key=lambda t: -t[2])
+    return sorted(((n, d, cnt[n]) for n, d in agg.items()),
+                  key=lambda t: -t[1])
 
 
 def main():
@@ -90,6 +70,8 @@ def main():
     ap.add_argument("--steps", type=int, default=4)
     ap.add_argument("--epochs", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
+    peak = device_peaks().bf16_flops
 
     from deeplearning4j_tpu.datasets.dataset import DataSet
     from deeplearning4j_tpu.models.zoo.transformer import (
@@ -117,32 +99,22 @@ def main():
     fpt = gpt_train_flops_per_token(args.vocab, args.d_model, args.layers,
                                     args.seq)
     print(f"d_model={args.d_model} L={args.layers} seq={args.seq} "
-          f"b={args.batch}: {tps:.0f} tok/s  mfu={tps*fpt/PEAK_BF16:.4f}  "
+          f"b={args.batch}: {tps:.0f} tok/s  mfu={tps*fpt/peak:.4f}  "
           f"ms/step={1000*dt/(args.epochs*args.steps):.2f}")
     assert np.isfinite(np.asarray(scores)).all()
 
     if args.trace:
-        from deeplearning4j_tpu.util import profiler
-        log_dir = "/tmp/jax-trace-gpt-r5"
+        log_dir = os.path.join("chiprun_out", "trace-gpt")
         net.fit_scan(None, args.batch, epochs=1, staged=staged)  # warm
         with profiler.trace(log_dir):
             net.fit_scan(None, args.batch, epochs=1, staged=staged)
         rows = aggregate_trace(log_dir)
-        if rows is None:
-            print("no trace captured")
-            return
-        total = sum(d for _, _, d, _ in rows)
+        total = sum(d for _, d, _ in rows)
         print(f"\ndevice self-time total: {total/1e3:.1f} ms "
               f"over {len(rows)} op groups")
-        buckets = collections.Counter()
-        for _, c, d, _ in rows:
-            buckets[c] += d
-        print("by hlo_category:")
-        for b, d in buckets.most_common():
-            print(f"  {b:28s} {d/1e3:8.1f} ms  {100*d/total:5.1f}%")
-        print("\ntop 20 op groups:")
-        for n, c, d, k in rows[:20]:
-            print(f"  {d/1e3:8.1f} ms  x{k:<5d} [{c}] {n[:70]}")
+        print("top 20 op groups:")
+        for n, d, k in rows[:20]:
+            print(f"  {d/1e3:8.1f} ms  {100*d/total:5.1f}%  x{k:<5d} {n[:70]}")
 
 
 if __name__ == "__main__":

@@ -15,8 +15,8 @@ import numpy as np
 from deeplearning4j_tpu.datasets.dataset import MultiDataSet
 from deeplearning4j_tpu.models.zoo.resnet import (
     resnet50, resnet50_train_flops_per_example)
-
-PEAK_BF16 = 197e12
+from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+from deeplearning4j_tpu.util.device import device_peaks
 
 
 def main():
@@ -27,6 +27,8 @@ def main():
     ap.add_argument("--epochs", type=int, default=3)
     ap.add_argument("--image-size", type=int, default=224)
     args = ap.parse_args()
+    enable_compile_cache()
+    peak = device_peaks().bf16_flops
 
     net = resnet50()
     rng = np.random.default_rng(0)
@@ -45,15 +47,16 @@ def main():
     if args.trace:
         from deeplearning4j_tpu.util import profiler
         net.fit_scan(None, args.batch, epochs=1, staged=staged)  # warm epochs=1 program
-        with profiler.trace("/tmp/jax-trace-resnet"):
+        log_dir = os.path.join("chiprun_out", "trace-resnet")
+        with profiler.trace(log_dir):
             net.fit_scan(None, args.batch, epochs=1, staged=staged)
-        print("trace written to /tmp/jax-trace-resnet")
+        print(f"trace written to {log_dir}")
 
     t0 = time.perf_counter()
     scores = net.fit_scan(None, args.batch, epochs=args.epochs, staged=staged)
     dt = time.perf_counter() - t0
     eps = args.epochs * n / dt
-    mfu = eps * resnet50_train_flops_per_example(args.image_size) / PEAK_BF16
+    mfu = eps * resnet50_train_flops_per_example(args.image_size) / peak
     assert np.isfinite(np.asarray(scores)).all()
     print(f"batch={args.batch} eps={eps:.1f} mfu={mfu:.4f} "
           f"ms/step={1000*dt/(args.epochs*args.steps):.1f}")

@@ -1,5 +1,6 @@
-"""SEEDED VIOLATIONS: the dead jax.shard_map attribute, a rogue
-shard_map import, and raw Mesh construction outside parallel/mesh.py."""
+"""SEEDED VIOLATIONS: the deprecated experimental shard_map import, a
+jax.shard_map reference outside parallel/mesh.py, and raw Mesh
+construction outside parallel/mesh.py."""
 import jax
 from jax.sharding import Mesh
 from jax.experimental.shard_map import shard_map

@@ -95,3 +95,29 @@ def test_ring_attention_auto_select_matches_full(rng):
     full2 = net.output(x)  # and revert cleanly after exit
     np.testing.assert_allclose(ringed, full, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(full2, full, rtol=1e-6)
+
+
+def test_flash_runs_per_device_under_a_placement_mesh(rng):
+    """A net placed over a mesh maps the flash kernel over it — batch
+    over 'data', heads over 'tp' (Mosaic refuses to lower a Pallas
+    kernel under a multi-device jit, and the partitioner could only
+    gather its operands): the placed program holds a per-device region
+    around the kernel and computes what the unplaced net computes."""
+    from deeplearning4j_tpu.parallel.tensor_parallel import apply_shardings
+    devs = jax.devices()
+    if len(devs) < 4:
+        import pytest
+        pytest.skip("needs 4 CPU devices")
+    net = MultiLayerNetwork(_conf(causal=True)).init()
+    x = rng.standard_normal((4, 8, 8)).astype(np.float32)
+    full = net.output(x)
+    mesh = make_mesh({"data": 2, "tp": 2}, devices=devs[:4])
+    apply_shardings(net, mesh, {})
+    assert all(impl._mesh is mesh for impl in net.impls)
+    placed = net.output(x)
+    np.testing.assert_allclose(placed, full, rtol=2e-4, atol=2e-5)
+    program = jax.make_jaxpr(net.infer_output_fn())(
+        net.params, net.states, x, None)
+    assert "shard_map" in str(program)
+    net.init()   # back on one device: no mesh left on the impls
+    assert all(impl._mesh is None for impl in net.impls)

@@ -30,7 +30,8 @@ import pytest
 from deeplearning4j_tpu import monitor
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iterators import (DeviceFeedIterator,
-                                                   ListDataSetIterator)
+                                                   ListDataSetIterator,
+                                                   bucket_sizes)
 from deeplearning4j_tpu.faultinject import (FailingDataSetIterator,
                                             FlakyBroker, InjectedFault,
                                             ReplicaPoison, TornWrites,
@@ -406,7 +407,8 @@ def _drive_until_quarantined(eng, net, rng, max_requests=200):
     raise AssertionError("poisoned replica never quarantined")
 
 
-def test_replica_quarantine_keeps_serving_bitwise(rng, fresh_registry):
+def test_replica_quarantine_keeps_serving_bitwise(rng, fresh_registry,
+                                                  assert_bucket_exact):
     net = _net()
     import jax
     dev = jax.devices()[0]
@@ -430,8 +432,8 @@ def test_replica_quarantine_keeps_serving_bitwise(rng, fresh_registry):
         # degraded engine keeps serving bitwise-correct results
         for _ in range(5):
             x = rng.standard_normal((3, N_IN)).astype(np.float32)
-            np.testing.assert_array_equal(eng.output(x, timeout=60),
-                                          np.asarray(net.output(x)))
+            assert_bucket_exact(eng.output(x, timeout=60), net, x,
+                                eng.buckets)
         # poison exhausted → the probe passes → replica reinstated
         assert _spin_until(
             lambda: (eng.probe_now() or not eng.stats()["quarantined"]))
@@ -440,8 +442,7 @@ def test_replica_quarantine_keeps_serving_bitwise(rng, fresh_registry):
         assert fresh_registry.get(
             monitor.FAULT_QUARANTINED_GAUGE).value == 0
         x = rng.standard_normal((2, N_IN)).astype(np.float32)
-        np.testing.assert_array_equal(eng.output(x, timeout=60),
-                                      np.asarray(net.output(x)))
+        assert_bucket_exact(eng.output(x, timeout=60), net, x, eng.buckets)
         assert served >= 1
     finally:
         eng.shutdown()  # recovered faults must NOT poison shutdown
@@ -587,7 +588,7 @@ def test_streaming_trainer_dead_letters_and_keeps_training(
 
 
 def test_streaming_inference_dead_letters_poison_requests(
-        rng, fresh_registry):
+        rng, fresh_registry, assert_bucket_exact):
     broker = InMemoryBroker()
     net = _net()
     xs = [rng.standard_normal((2, N_IN)).astype(np.float32)
@@ -601,7 +602,7 @@ def test_streaming_inference_dead_letters_poison_requests(
     # good requests answered IN ORDER despite the interleaved poison
     for x in xs:
         pred = ndarray_from_bytes(broker.consume("out", timeout=5))
-        np.testing.assert_array_equal(pred, np.asarray(net.output(x)))
+        assert_bucket_exact(pred, net, x, bucket_sizes(serve.max_batch_size))
     assert broker.consume("in.deadletter", timeout=5) == b"poison request"
     assert fresh_registry.get(monitor.FAULT_DEAD_LETTER_COUNTER,
                               topic="in").value == 1

@@ -98,8 +98,9 @@ def test_rule_bad_fixture_caught(rule_name):
 def test_mesh_bad_fixture_flags_all_three_shapes():
     msgs = [f.message
             for f in _fixture_findings("mesh-api", "mesh_api_bad.py")]
-    assert any("jax.shard_map does not exist" in m for m in msgs)
-    assert any("shard_map import" in m for m in msgs)
+    assert any("jax.experimental.shard_map is the deprecated" in m
+               for m in msgs)
+    assert any("shard_map reference outside" in m for m in msgs)
     assert any("raw Mesh(...)" in m for m in msgs)
 
 

@@ -384,9 +384,10 @@ def test_mesh_api_lint_repo_clean_and_catches_violations(tmp_path):
         "from jax.experimental.shard_map import shard_map\n")
     problems = lint.check_file(str(bad))
     assert len(problems) == 3
-    assert any("jax.shard_map does not exist" in p for p in problems)
+    assert any("shard_map reference outside" in p for p in problems)
     assert any("raw Mesh(...)" in p for p in problems)
-    assert any("shard_map import" in p for p in problems)
+    assert any("jax.experimental.shard_map is the deprecated" in p
+               for p in problems)
     good = tmp_path / "good.py"
     good.write_text(
         "from deeplearning4j_tpu.parallel.mesh import make_mesh,"
