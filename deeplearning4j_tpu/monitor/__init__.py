@@ -19,8 +19,19 @@ Canonical span names threaded through the training paths:
 program), ``device_step`` (compiled train step), ``all_reduce``
 (parameter averaging / collective), ``checkpoint``, ``eval``,
 ``broadcast``, ``inference``, ``score_sync`` (batched device→host score
-resolution of the deferred-score ring). ``scripts/check_telemetry_schema.py``
-validates the emitted streams.
+resolution of the deferred-score ring), and under ``fit_scan``'s
+``compile`` / ``device_step`` its two children: ``launch`` (the call of
+the compiled program: argument handling and enqueue, it returns before
+the device is done; under ``compile`` it is ``compile_launch``, the
+call that also traces, lowers and loads or compiles the program, so
+that the ``launch`` histogram holds steady-state calls only) and
+``fetch`` (the score fetch: the wait for the device and the
+device→host copy). Every span record carries ``id``,
+``parent`` (the span open on the same thread when it started, or null)
+and ``dispatch`` (its tree's root, shared by the spans of one dispatch);
+the same spans appear as ``dl4j/<name>`` on the host plane of a device
+trace. ``scripts/check_telemetry_schema.py`` validates the emitted
+streams.
 
 The device-feed pipeline (datasets/iterators.py + the fit() paths)
 publishes four counters/gauges under the names below so a BENCH round
@@ -31,6 +42,8 @@ loop), ``dl4j_feed_padded_batches_total`` (ragged tail batches padded
 to the canonical shape), ``dl4j_jit_cache_miss_total`` (train-step
 dispatches that had to trace+compile), ``dl4j_score_sync_total``
 (device→host score fetches — each one is a chip round-trip).
+``fit_scan`` ticks ``dl4j_jit_cache_miss_total`` on the first dispatch of
+each program, as ``fit`` does.
 
 The serving plane (parallel/inference.py ``ParallelInference``)
 publishes ``dl4j_infer_requests_total`` / ``dl4j_infer_batches_total``

@@ -692,11 +692,14 @@ class ComputationGraph:
             self._jits[key] = self._make_scan_fit(epochs)
         fit = self._jits[key]
         rng_key = self._train_rng()
+        # the span tree of MultiLayerNetwork.fit_scan: the call, the fetch
         with span("compile" if compiling else "device_step",
                   path="graph_fit_scan", epochs=epochs):
-            self.params, self.opt_state, self.states, scores = fit(
-                self.params, self.opt_state, self.states, xb, yb, rng_key)
-            out = np.asarray(scores)  # score fetch = device sync
+            with span("compile_launch" if compiling else "launch"):
+                self.params, self.opt_state, self.states, scores = fit(
+                    self.params, self.opt_state, self.states, xb, yb, rng_key)
+            with span("fetch"):
+                out = np.asarray(scores)  # score fetch = device sync
         self._score = float(out[-1])
         return out
 

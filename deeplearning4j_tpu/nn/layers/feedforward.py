@@ -111,10 +111,14 @@ class OutputImpl(BaseDenseImpl):
         """Mean-over-examples data loss for this output layer."""
         x = self.maybe_dropout_input(x, train, rng)
         params = self.maybe_drop_connect(params, train, rng)
-        z = self.preout(params, x)
-        if _fused_logits_pair(self.activation, self.loss_function):
-            return compute_loss(self.loss_function, labels, z, mask=mask, from_logits=True)
-        return compute_loss(self.loss_function, labels, activate(self.activation, z), mask=mask)
+        # scoped apart: the head's matmul, and the loss proper (the f32
+        # log-softmax over the vocabulary)
+        with jax.named_scope("lm_head"):
+            z = self.preout(params, x)
+        with jax.named_scope("loss"):
+            if _fused_logits_pair(self.activation, self.loss_function):
+                return compute_loss(self.loss_function, labels, z, mask=mask, from_logits=True)
+            return compute_loss(self.loss_function, labels, activate(self.activation, z), mask=mask)
 
 
 @register_impl(L.RnnOutputLayer)
@@ -139,10 +143,11 @@ class LossImpl(LayerImpl):
         return activate(self.activation, x), state
 
     def score(self, params, x, labels, state, train, rng=None, mask=None):
-        if _fused_logits_pair(self.activation, self.loss_function):
-            return compute_loss(self.loss_function, labels, x, mask=mask, from_logits=True)
-        return compute_loss(self.loss_function, labels,
-                            activate(self.activation, x), mask=mask)
+        with jax.named_scope("loss"):
+            if _fused_logits_pair(self.activation, self.loss_function):
+                return compute_loss(self.loss_function, labels, x, mask=mask, from_logits=True)
+            return compute_loss(self.loss_function, labels,
+                                activate(self.activation, x), mask=mask)
 
 
 @register_impl(L.EmbeddingLayer)

@@ -62,7 +62,8 @@ class SequenceEmbeddingImpl(LayerImpl):
         t = idx.shape[1]
         if t > self.conf.max_len:
             raise ValueError(f"sequence length {t} > max_len {self.conf.max_len}")
-        z = qtake(params, "W", idx) + params["P"][:t][None]
+        with jax.named_scope("embed"):
+            z = qtake(params, "W", idx) + params["P"][:t][None]
         return self._slice_replicate(z), state
 
 
@@ -113,22 +114,30 @@ class TransformerBlockImpl(LayerImpl):
             raise ValueError(f"TransformerBlock needs [b, t, d], got {x.shape}")
         b, t, d = x.shape
         h_count, hd = c.num_heads, c.n_out // c.num_heads
-        h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
-        qkv = qmatmul(h, params, "Wqkv")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = lambda z: z.reshape(b, t, h_count, hd)
-        q, k, v = shape(q), shape(k), shape(v)
-        if self._slice_mesh is not None:
-            # sliced serving: heads are sharded over tp — the Pallas
-            # flash kernel cannot see the mesh, so stay on the XLA
-            # formulation GSPMD partitions per-head
-            with xla_attention():
-                o = dispatch_attention(q, k, v, causal=c.causal, mask=mask)
-        else:
-            o = dispatch_attention(q, k, v, causal=c.causal, mask=mask,
-                                   mesh=self._mesh)
-        attn = qmatmul(self._slice_replicate(o.reshape(b, t, d)),
-                       params, "Wo")
+        # static scope names: the device trace reads each part of the
+        # block under them (util/profiler.scope_seconds); JAX adds
+        # jvp(...) / transpose(jvp(...)) for forward and backward
+        with jax.named_scope("ln1"):
+            h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
+        with jax.named_scope("qkv_proj"):
+            qkv = qmatmul(h, params, "Wqkv")
+        with jax.named_scope("attention"):
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            shape = lambda z: z.reshape(b, t, h_count, hd)
+            q, k, v = shape(q), shape(k), shape(v)
+            if self._slice_mesh is not None:
+                # sliced serving: heads are sharded over tp — the Pallas
+                # flash kernel cannot see the mesh, so stay on the XLA
+                # formulation GSPMD partitions per-head
+                with xla_attention():
+                    o = dispatch_attention(q, k, v, causal=c.causal,
+                                           mask=mask)
+            else:
+                o = dispatch_attention(q, k, v, causal=c.causal, mask=mask,
+                                       mesh=self._mesh)
+            o = o.reshape(b, t, d)
+        with jax.named_scope("attn_out_proj"):
+            attn = qmatmul(self._slice_replicate(o), params, "Wo")
         if train and self.dropout_rate > 0.0 and rng is not None:
             attn = apply_dropout(attn, self.dropout_rate,
                                  jax.random.fold_in(rng, 1))
@@ -136,7 +145,8 @@ class TransformerBlockImpl(LayerImpl):
         # the attn matmul left sharded
         x = self._slice_replicate(x + attn)
 
-        h2 = _layer_norm(x, params["ln2_g"], params["ln2_b"])
+        with jax.named_scope("ln2"):
+            h2 = _layer_norm(x, params["ln2_g"], params["ln2_b"])
         mlp, new_state = self._ffn(params, h2.reshape(-1, d), state,
                                    mask=mask,
                                    capacity_factor=c.capacity_factor)
@@ -157,14 +167,16 @@ class TransformerBlockImpl(LayerImpl):
         if c.num_experts > 0:
             return run_moe_ffn(params, h2, capacity_factor,
                                c.aux_loss_weight, mask=mask)
-        mlp = jax.nn.gelu(qmatmul(h2, params, "W1")
-                          + params["b1"].astype(h2.dtype))
+        with jax.named_scope("mlp_fc"):
+            mlp = jax.nn.gelu(qmatmul(h2, params, "W1")
+                              + params["b1"].astype(h2.dtype))
         # sliced: W1 is column-sharded so mlp is sharded on its hidden
         # dim — all-gather it before W2 contracts over that dim, so the
         # contraction never reduces across shards (bitwise seam)
         mlp = self._slice_replicate(mlp)
-        mlp = qmatmul(mlp, params, "W2") \
-            + params["b2"].astype(h2.dtype)
+        with jax.named_scope("mlp_proj"):
+            mlp = qmatmul(mlp, params, "W2") \
+                + params["b2"].astype(h2.dtype)
         return mlp, state
 
     # ------------------------------------------- incremental decoding

@@ -45,6 +45,7 @@ from deeplearning4j_tpu.nn.updater import (
 from deeplearning4j_tpu.monitor import H2D_BYTES_COUNTER, get_registry, span
 from deeplearning4j_tpu.nn.observed import SyncedStateAttr
 from deeplearning4j_tpu.optimize.deferred import (
+    count_jit_cache_miss,
     host_step,
     note_dispatch,
     score_sink,
@@ -53,6 +54,15 @@ from deeplearning4j_tpu.optimize.deferred import (
 from deeplearning4j_tpu.util.dtypes import cast_floats, cast_like, resolve_compute_dtype
 
 Params = Dict[str, Dict[str, jnp.ndarray]]
+
+#: the ``jax.named_scope`` names of the compiled train step, all static
+#: strings: the step's own (``_make_train_step``), the head's and the
+#: loss's (nn/layers/feedforward.py), the block's and the embedding's
+#: (nn/layers/transformer.py), the head fold (ops/flash_attention.py).
+#: Readers of a device trace (util/profiler.scope_seconds) take this list
+STEP_SCOPES = ("grad_norm", "optimizer_update", "lm_head", "loss", "embed",
+               "ln1", "qkv_proj", "attention", "attn_out_proj", "ln2",
+               "mlp_fc", "mlp_proj", "fold_heads", "unfold_heads")
 
 
 class MultiLayerNetwork:
@@ -227,12 +237,16 @@ class MultiLayerNetwork:
             new_upd: Dict[str, Any] = {}
             for impl, (nt, thr), ucfg in zip(self.impls, gn_specs, ucfgs):
                 name = impl.name
-                g = normalize_gradient(nt, grads[name], thr)
+                with jax.named_scope("grad_norm"):
+                    g = normalize_gradient(nt, grads[name], thr)
                 new_params[name] = {}
                 new_upd[name] = {}
                 for pname, gval in g.items():
-                    upd, ust = apply_updater(ucfg, gval, opt_state["updater"][name][pname], it)
-                    new_params[name][pname] = params[name][pname] - upd.astype(params[name][pname].dtype)
+                    # one static name for every leaf: the device trace
+                    # reads the whole update under it
+                    with jax.named_scope("optimizer_update"):
+                        upd, ust = apply_updater(ucfg, gval, opt_state["updater"][name][pname], it)
+                        new_params[name][pname] = params[name][pname] - upd.astype(params[name][pname].dtype)
                     new_upd[name][pname] = ust
             return new_params, {"step": it + 1, "updater": new_upd}, new_states, score
 
@@ -255,9 +269,6 @@ class MultiLayerNetwork:
 
     def _get_jit(self, kind: str, **flags):
         key = (kind, tuple(sorted(flags.items())), self._seq_token())
-        # telemetry: the dispatch after a cache miss traces+compiles, so
-        # callers label it span("compile") instead of "device_step"
-        self._jit_missed = key not in self._jits
         if key not in self._jits:
             if kind == "train":
                 self._jits[key] = self._make_train_step(flags["fm"], flags["lm"])
@@ -676,11 +687,20 @@ class MultiLayerNetwork:
             self._jits[key] = self._make_scan_fit(epochs)
         fit = self._jits[key]
         rng_key = self._train_rng()
+        if compiling:
+            count_jit_cache_miss()
+        # one dispatch = one span tree: the parent, the call (argument
+        # handling and enqueue: it returns before the device is done; the
+        # first call of a program also traces, lowers and loads it, and
+        # goes by a name of its own) and the fetch (the wait and the
+        # device-to-host copy)
         with span("compile" if compiling else "device_step",
                   path="fit_scan", epochs=epochs):
-            self.params, self.opt_state, self.states, scores = fit(
-                self.params, self.opt_state, self.states, xb, yb, rng_key)
-            out = np.asarray(scores)  # score fetch = device sync
+            with span("compile_launch" if compiling else "launch"):
+                self.params, self.opt_state, self.states, scores = fit(
+                    self.params, self.opt_state, self.states, xb, yb, rng_key)
+            with span("fetch"):
+                out = np.asarray(scores)  # score fetch = device sync
         self._score = float(out[-1])
         return out
 

@@ -394,5 +394,10 @@ def flash_attention(
     if interpret is None:
         interpret = pallas_interpret()
     fold = lambda z: z.transpose(0, 2, 1, 3).reshape(b * h, z.shape[1], d)
-    o = _flash(fold(q), fold(k), fold(v), causal, bq, bk, interpret)
-    return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+    # scoped so that the [b, t, h, d] <-> [b*h, t, d] copies have an
+    # owner in the device trace, apart from the kernels
+    with jax.named_scope("fold_heads"):
+        q, k, v = fold(q), fold(k), fold(v)
+    o = _flash(q, k, v, causal, bq, bk, interpret)
+    with jax.named_scope("unfold_heads"):
+        return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

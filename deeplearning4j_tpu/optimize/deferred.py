@@ -135,12 +135,18 @@ def note_dispatch(model, sig) -> bool:
     and every first-seen signature ticks ``dl4j_jit_cache_miss_total``.
     The signature set lives next to the model's jit cache and resets
     with it (``init()``)."""
-    from deeplearning4j_tpu.monitor import JIT_CACHE_MISS_COUNTER
     seen = model.__dict__.setdefault("_dispatch_sigs", set())
     if sig in seen:
         return False
     seen.add(sig)
+    count_jit_cache_miss()
+    return True
+
+
+def count_jit_cache_miss() -> None:
+    """Tick ``dl4j_jit_cache_miss_total``: a train-step dispatch traced
+    and compiled (or loaded) a program."""
+    from deeplearning4j_tpu.monitor import JIT_CACHE_MISS_COUNTER
     get_registry().counter(
         JIT_CACHE_MISS_COUNTER,
         "Train-step dispatches that traced+compiled a fresh program").inc()
-    return True

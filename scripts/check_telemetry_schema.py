@@ -40,6 +40,11 @@ SPAN_KEYS = {"type": str, "name": str, "ts_us": (int, float),
 INSTANT_KEYS = {"type": str, "name": str, "ts_us": (int, float),
                 "pid": int, "tid": int}
 OPTIONAL_KEYS = {"attrs": dict}
+# the span tree (monitor/tracing.py): a span's own id, the id of the span
+# that was open on its thread when it started (null for a root), and the id
+# of its tree's root, which the spans of one dispatch share — fit_scan emits
+# ``compile`` / ``device_step`` with the children ``launch`` and ``fetch``
+SPAN_TREE_KEYS = {"id": int, "parent": (int, type(None)), "dispatch": int}
 
 
 def validate_event(obj: Any, where: str = "event") -> List[str]:
@@ -56,9 +61,18 @@ def validate_event(obj: Any, where: str = "event") -> List[str]:
         elif not isinstance(obj[key], types):
             errors.append(f"{where}: key {key!r} has type "
                           f"{type(obj[key]).__name__}")
+    tree = SPAN_TREE_KEYS if etype == "span" else {}
     for key in obj:
-        if key not in required and key not in OPTIONAL_KEYS:
+        if key in tree:
+            if not isinstance(obj[key], tree[key]) \
+                    or isinstance(obj[key], bool):
+                errors.append(f"{where}: key {key!r} has type "
+                              f"{type(obj[key]).__name__}")
+        elif key not in required and key not in OPTIONAL_KEYS:
             errors.append(f"{where}: unknown key {key!r}")
+    if tree and any(k in obj for k in tree) and not all(k in obj for k in tree):
+        errors.append(f"{where}: a span carries all of "
+                      f"{sorted(SPAN_TREE_KEYS)} or none")
     if "attrs" in obj and not isinstance(obj["attrs"], dict):
         errors.append(f"{where}: attrs must be an object")
     if not errors:
@@ -75,6 +89,7 @@ def validate_events_lines(lines: Iterable[str],
                           where: str = "events") -> List[str]:
     errors: List[str] = []
     n = 0
+    spans: Dict[int, Dict[str, Any]] = {}
     for i, line in enumerate(lines, 1):
         line = line.strip()
         if not line:
@@ -85,7 +100,33 @@ def validate_events_lines(lines: Iterable[str],
         except json.JSONDecodeError as e:
             errors.append(f"{where}:{i}: invalid JSON: {e}")
             continue
-        errors.extend(validate_event(obj, f"{where}:{i}"))
+        found = validate_event(obj, f"{where}:{i}")
+        errors.extend(found)
+        if not found and obj["type"] == "span" and "id" in obj:
+            if (obj["pid"], obj["id"]) in spans:
+                errors.append(f"{where}:{i}: duplicate span id {obj['id']}")
+            spans[(obj["pid"], obj["id"])] = obj
+    # children close, and are written, before their parents: edges are
+    # checked once the stream has ended. A tree whose root never arrived
+    # was still open when the stream was cut (a crash mid-dispatch, a
+    # tracer switched on or off inside a span): a warning, not an error
+    for (pid, _), obj in spans.items():
+        parent = spans.get((pid, obj["parent"]))
+        if obj["parent"] is None:
+            if obj["dispatch"] != obj["id"]:
+                errors.append(f"{where}: root span {obj['id']} is not its "
+                              f"own dispatch")
+        elif parent is None:
+            msg = (f"{where}: span {obj['id']} ({obj['name']}) has an "
+                   f"unresolved parent {obj['parent']}")
+            if (pid, obj["dispatch"]) in spans:
+                errors.append(msg)
+            else:
+                print(f"warning: {msg}: its tree {obj['dispatch']} never "
+                      f"closed, the stream was cut", file=sys.stderr)
+        elif parent["dispatch"] != obj["dispatch"]:
+            errors.append(f"{where}: span {obj['id']} and its parent "
+                          f"disagree on the dispatch id")
     if n == 0:
         errors.append(f"{where}: no events (empty stream)")
     return errors

@@ -3,12 +3,13 @@
 Usage: python scripts/profile_gpt.py [--trace] [--d-model N] ...
 Prints tokens/sec + MFU; with --trace, aggregates device op self-times
 from the captured trace by op group (flash fwd/dq/dkv kernels,
-fusions, copies) — the BASELINE.md attribution workflow.
+fusions, copies) — the BASELINE.md attribution workflow — then by the
+program's named scopes, and the device's idle time by the program's
+host spans (util/profiler.scope_seconds / gaps_by_host_span).
 """
 import argparse
 import collections
 import os
-import re
 import sys
 import time
 
@@ -16,6 +17,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
+from deeplearning4j_tpu.nn.multilayer import STEP_SCOPES
 from deeplearning4j_tpu.util import profiler
 from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 from deeplearning4j_tpu.util.device import device_peaks
@@ -23,40 +25,62 @@ from deeplearning4j_tpu.util.device import device_peaks
 
 def aggregate_trace(log_dir):
     """Aggregate XLA-Ops-line SELF times (events nest: while > fusion —
-    walk each line's intervals with a stack and subtract child time)
-    from the newest ``.xplane.pb`` under ``log_dir``.
-    Returns [(group_name, total_us, count)] sorted by time, where
-    group_name is the HLO instruction's name with trailing .N instance
-    suffixes stripped (``fusion``, ``copy``, ``jvp_flash_fwd_`` …; the
-    capture carries no hlo_category per event)."""
+    ``util/profiler.self_times`` walks each line with a stack and
+    subtracts child time) from the newest ``.xplane.pb`` under
+    ``log_dir``. Returns [(group_name, total_us, count)] sorted by time,
+    where group_name is the HLO instruction's name with trailing .N
+    instance suffixes stripped (``fusion``, ``copy``, ``jvp_flash_fwd_``
+    …; the capture carries no hlo_category per event)."""
     agg = collections.Counter()
     cnt = collections.Counter()
-    for plane in profiler.device_planes(profiler.load_trace(log_dir)):
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            events = sorted(line.events,
-                            key=lambda e: (e.start_ns, -e.duration_ns))
-            stack = []  # [end_ns, event, child_ns]
-
-            def pop_one():
-                _, e0, child0 = stack.pop()
-                # event names are HLO text: "%fusion.12 = bf16[...] ..."
-                key = re.sub(r"(\.\d+)+$", "",
-                             e0.name.split(" = ")[0].lstrip("%"))
-                agg[key] += max(e0.duration_ns - child0, 0) / 1e3
-                cnt[key] += 1
-                if stack:
-                    stack[-1][2] += e0.duration_ns
-
-            for e in events:
-                while stack and e.start_ns >= stack[-1][0]:
-                    pop_one()
-                stack.append([e.start_ns + e.duration_ns, e, 0])
-            while stack:
-                pop_one()
+    for group, _, _, ns in profiler.scoped_self_times(
+            profiler.load_trace(log_dir), ()):
+        agg[group] += ns / 1e3
+        cnt[group] += 1
     return sorted(((n, d, cnt[n]) for n, d in agg.items()),
                   key=lambda t: -t[1])
+
+
+def print_scope_tables(log_dir, steps):
+    """Device self time by the program's named scopes (and which op
+    groups each scope owns), then the device's idle time by what the
+    host was doing (``dl4j/`` spans on the capture's host plane)."""
+    profile = profiler.load_trace(log_dir)
+    ops = profiler.op_names(log_dir)
+    rows = profiler.scoped_self_times(profile, STEP_SCOPES, ops)
+    total = sum(ns for *_, ns in rows) or 1
+    by_scope = collections.Counter()
+    groups = collections.defaultdict(collections.Counter)
+    for group, scope, pass_, ns in rows:
+        key = f"{scope}/{pass_}" if scope else "(no scope)"
+        by_scope[key] += ns
+        groups[key][group] += ns
+    print(f"\ndevice self time by scope ({len(ops)} op names in the "
+          f"capture; by fusion: XLA gives a fusion one instruction's name):")
+    for key, ns in by_scope.most_common():
+        top = ", ".join(f"{g} {v / steps / 1e6:.2f}"
+                        for g, v in groups[key].most_common(3))
+        print(f"  {ns / steps / 1e6:8.3f} ms/step {100 * ns / total:5.1f}%  "
+              f"{key:22s} [{top}]")
+    claimed = total - by_scope["(no scope)"]
+    print(f"  no scope claimed {100 * (1 - claimed / total):.1f}% of self time")
+    for group in ("fusion", "divide_subtract_fusion", "copy"):
+        owners = collections.Counter()
+        for g, scope, pass_, ns in rows:
+            if g == group:
+                owners[f"{scope}/{pass_}" if scope else "(no scope)"] += ns
+        print(f"  {group} is owned by: " + ", ".join(
+            f"{k} {v / steps / 1e6:.2f} ms" for k, v in owners.most_common(6)))
+    gaps = profiler.gaps_by_host_span(profile)
+    if not gaps:
+        print("\nno dl4j/ dispatch span on the capture's host plane")
+        return
+    n = gaps["dispatches"]
+    print(f"\ndevice idle by host span, {n} dispatches, window "
+          f"{gaps['window_s']:.3f} s, idle {1e3 * gaps['idle_s'] / n:.3f} "
+          f"ms a dispatch:")
+    for key in ("fetch", "python", "launch", "unattributed"):
+        print(f"  {key:13s} {1e3 * gaps[key + '_s'] / n:8.3f} ms a dispatch")
 
 
 def main():
@@ -65,6 +89,8 @@ def main():
     ap.add_argument("--vocab", type=int, default=8192)
     ap.add_argument("--d-model", type=int, default=512)
     ap.add_argument("--layers", type=int, default=8)
+    ap.add_argument("--heads", type=int, default=8)
+    ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seq", type=int, default=1024)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--steps", type=int, default=4)
@@ -78,7 +104,8 @@ def main():
         gpt, gpt_train_flops_per_token)
 
     net = gpt(vocab_size=args.vocab, d_model=args.d_model,
-              n_layers=args.layers, max_len=args.seq).init()
+              n_layers=args.layers, num_heads=args.heads, max_len=args.seq,
+              learning_rate=args.lr).init()
     rng = np.random.default_rng(0)
     ids = rng.integers(0, args.vocab, (args.batch * args.steps, args.seq))
     data = DataSet(ids.astype(np.float32),
@@ -107,7 +134,8 @@ def main():
         log_dir = os.path.join("chiprun_out", "trace-gpt")
         net.fit_scan(None, args.batch, epochs=1, staged=staged)  # warm
         with profiler.trace(log_dir):
-            net.fit_scan(None, args.batch, epochs=1, staged=staged)
+            for _ in range(3):  # three, to hold the gaps between them
+                net.fit_scan(None, args.batch, epochs=1, staged=staged)
         rows = aggregate_trace(log_dir)
         total = sum(d for _, d, _ in rows)
         print(f"\ndevice self-time total: {total/1e3:.1f} ms "
@@ -115,6 +143,7 @@ def main():
         print("top 20 op groups:")
         for n, d, k in rows[:20]:
             print(f"  {d/1e3:8.1f} ms  {100*d/total:5.1f}%  x{k:<5d} {n[:70]}")
+        print_scope_tables(log_dir, 3 * args.steps)
 
 
 if __name__ == "__main__":

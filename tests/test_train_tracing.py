@@ -1,0 +1,429 @@
+"""The training step named from inside: scopes on the compiled program,
+``fit_scan``'s dispatch as a span tree, and the readers of a device capture
+(``util/profiler.scope_seconds`` / ``host_spans`` / ``gaps_by_host_span``).
+
+CPU, tiny net. The persistent compile cache is off around the tests that
+compare a compiling dispatch with a warm one."""
+
+import contextlib
+import re
+import threading
+import types
+
+import jax
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu import monitor
+from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.models.zoo.transformer import gpt
+from deeplearning4j_tpu.nn.multilayer import STEP_SCOPES as SCOPES
+from deeplearning4j_tpu.util import profiler
+
+
+@pytest.fixture
+def registry():
+    reg = monitor.MetricsRegistry()
+    old = monitor.set_registry(reg)
+    try:
+        yield reg
+    finally:
+        monitor.set_registry(old)
+        monitor.disable_tracing()
+
+
+@pytest.fixture
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+
+
+def _net_and_set(seed=3):
+    net = gpt(vocab_size=64, d_model=32, n_layers=2, num_heads=2,
+              max_len=32, compute_dtype="bfloat16", seed=seed).init()
+    ids = np.random.default_rng(0).integers(0, 64, (8, 17))
+    data = DataSet(ids[:, :-1].astype(np.float32),
+                   ids[:, 1:].astype(np.float32))
+    return net, net.stage_scan(data, 2)
+
+
+def _lowered(net, staged, **kw):
+    fit = net._make_scan_fit(1)
+    return fit.lower(net.params, net.opt_state, net.states, *staged,
+                     net._train_rng()).as_text(**kw)
+
+
+# ------------------------------------------------------------------ scopes
+
+def test_scopes_name_the_scan_program_and_change_nothing(monkeypatch):
+    net, staged = _net_and_set()
+    text = _lowered(net, staged, debug_info=True)
+    assert len(SCOPES) == 14  # the list the readers take names them all
+    for scope in SCOPES:
+        if scope == "grad_norm":
+            continue  # no gradient normalization configured: traces nothing
+        assert re.search(r'[/("]%s[)/]' % scope, text), scope
+    # forward and backward read apart, by JAX's own wrappers
+    assert '"jvp(mlp_fc)/' in text and '"transpose(jvp(mlp_fc))/' in text
+    assert '"optimizer_update/' in text
+    assert 'jvp(attention)/fold_heads/' in text
+    scoped_program = _lowered(net, staged)
+    scoped = net.fit_scan(None, 2, staged=staged)
+
+    # the same net with every scope taken out: the same program, the
+    # same losses to the bit
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare_net, bare_staged = _net_and_set()
+    assert "optimizer_update" not in _lowered(bare_net, bare_staged,
+                                              debug_info=True)
+    assert _lowered(bare_net, bare_staged) == scoped_program
+    bare = bare_net.fit_scan(None, 2, staged=bare_staged)
+    assert scoped.tobytes() == bare.tobytes()
+    assert np.isfinite(scoped).all() and len(scoped) == 4
+
+
+def test_grad_norm_scope_appears_when_normalization_is_on():
+    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    conf = (NeuralNetConfiguration.builder().seed(1).learning_rate(0.1)
+            .gradient_normalization("clip_l2_per_layer")
+            .list()
+            .layer(DenseLayer(n_in=4, n_out=8, activation="tanh"))
+            .layer(OutputLayer(n_in=8, n_out=2, activation="softmax",
+                               loss_function="mcxent"))
+            .build())
+    net = MultiLayerNetwork(conf).init()
+    x = np.zeros((4, 4), np.float32)
+    y = np.eye(2, dtype=np.float32)[[0, 1, 0, 1]]
+    staged = net.stage_scan(DataSet(x, y), 2)
+    text = _lowered(net, staged, debug_info=True)
+    assert '"grad_norm/' in text and '"optimizer_update/' in text
+    assert '"jvp(lm_head)/' in text and '"jvp(loss)/' in text
+
+
+# --------------------------------------------------------------- span tree
+
+def test_fit_scan_dispatch_is_a_span_tree(registry, no_compile_cache):
+    net, staged = _net_and_set()
+    tracer = monitor.enable_tracing()
+    net.fit_scan(None, 2, staged=staged)
+    net.fit_scan(None, 2, staged=staged)
+    monitor.disable_tracing()
+    spans = [e for e in tracer.events() if e["type"] == "span"
+             and e["name"] in ("compile", "device_step", "compile_launch",
+                               "launch", "fetch")]
+    # the call that compiles goes by a name of its own
+    assert [s["name"] for s in spans] == ["compile_launch", "fetch", "compile",
+                                          "launch", "fetch", "device_step"]
+    by_id = {s["id"]: s for s in spans}
+    assert len(by_id) == 6
+    for first in (0, 3):
+        launch, fetch, parent = spans[first:first + 3]
+        assert parent["parent"] is None and parent["dispatch"] == parent["id"]
+        t0, t1 = parent["ts_us"], parent["ts_us"] + parent["dur_us"]
+        for child in (launch, fetch):
+            assert by_id[child["parent"]] is parent  # the edge resolves
+            assert child["dispatch"] == parent["id"]  # one id a dispatch
+            assert t0 <= child["ts_us"]
+            assert child["ts_us"] + child["dur_us"] <= t1 + 1e-3
+        assert launch["ts_us"] + launch["dur_us"] <= fetch["ts_us"] + 1e-3
+    assert spans[2]["dispatch"] != spans[5]["dispatch"]
+
+    # the launch of the first dispatch held the trace, the lowering and the
+    # compile; the second's did not
+    assert spans[3]["dur_us"] < spans[0]["dur_us"] / 10
+    assert spans[2]["attrs"] == spans[5]["attrs"] == {"path": "fit_scan",
+                                                      "epochs": 1}
+    # so the launch histogram holds steady-state calls only
+    assert registry.get(monitor.PHASE_HISTOGRAM, phase="launch").count == 1
+    assert registry.get(monitor.PHASE_HISTOGRAM,
+                        phase="compile_launch").count == 1
+    assert registry.get(monitor.PHASE_HISTOGRAM, phase="fetch").count == 2
+
+
+def test_graph_fit_scan_emits_the_same_tree(registry):
+    from deeplearning4j_tpu.datasets.dataset import MultiDataSet
+    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.nn.graph import (
+        ComputationGraph, ComputationGraphConfiguration)
+    base = NeuralNetConfiguration.builder().seed(1).learning_rate(0.1).build()
+    conf = (ComputationGraphConfiguration.builder(base).add_inputs("in")
+            .add_layer("d1", DenseLayer(n_in=4, n_out=8), "in")
+            .add_layer("out", OutputLayer(n_in=8, n_out=2,
+                                          activation="softmax",
+                                          loss_function="mcxent"), "d1")
+            .set_outputs("out").build())
+    graph = ComputationGraph(conf).init()
+    data = MultiDataSet([np.zeros((4, 4), np.float32)],
+                        [np.eye(2, dtype=np.float32)[[0, 1, 0, 1]]])
+    tracer = monitor.enable_tracing()
+    graph.fit_scan(data, 2)
+    graph.fit_scan(data, 2)
+    monitor.disable_tracing()
+    tree = [e for e in tracer.events() if e["type"] == "span"
+            and e["name"] in ("compile", "device_step", "compile_launch",
+                              "launch", "fetch")]
+    assert [s["name"] for s in tree] == ["compile_launch", "fetch", "compile",
+                                         "launch", "fetch", "device_step"]
+    assert tree[3]["parent"] == tree[4]["parent"] == tree[5]["id"]
+    assert tree[5]["attrs"]["path"] == "graph_fit_scan"
+
+
+def test_monitor_spans_need_no_jax():
+    # the registry's other users (router, broker, UI) import no jax: a span
+    # there opens no TraceAnnotation and still nests and feeds its histogram
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "from deeplearning4j_tpu import monitor\n"
+        "with monitor.span('inference') as a:\n"
+        "    with monitor.span('eval') as b:\n"
+        "        assert b.parent == a.id and b._annotation is None\n"
+        "assert 'jax' not in sys.modules\n"
+        "h = monitor.get_registry().get(monitor.PHASE_HISTOGRAM, phase='eval')\n"
+        "assert h.count == 1\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+def test_only_a_programs_first_dispatch_ticks_the_miss_counter(registry):
+    net, staged = _net_and_set()
+    miss = lambda: registry.family_total(monitor.JIT_CACHE_MISS_COUNTER)
+    net.fit_scan(None, 2, staged=staged)
+    assert miss() == 1
+    net.fit_scan(None, 2, staged=staged)
+    assert miss() == 1
+    net.fit_scan(None, 2, epochs=2, staged=staged)  # another program
+    assert miss() == 2
+
+
+def test_no_tracer_no_event(registry):
+    net, staged = _net_and_set()
+    tracer = monitor.enable_tracing()
+    net.fit_scan(None, 2, staged=staged)
+    monitor.disable_tracing()
+    n = len(tracer.events())
+    assert n >= 3
+    net.fit_scan(None, 2, staged=staged)
+    assert len(tracer.events()) == n and monitor.active_tracer() is None
+    # the histogram is fed all the same
+    assert registry.get(monitor.PHASE_HISTOGRAM, phase="launch").count == 1
+
+
+def test_spans_nest_per_thread_and_survive_an_exception(registry):
+    tracer = monitor.enable_tracing()
+    with monitor.span("device_step") as outer:
+        seen = []
+        t = threading.Thread(target=lambda: seen.append(
+            monitor.span("data_load").__enter__()))
+        t.start()
+        t.join(timeout=10)
+        assert seen[0].parent is None  # another thread: another tree
+        with pytest.raises(RuntimeError):
+            with monitor.span("launch"):
+                raise RuntimeError("boom")
+        with monitor.span("fetch") as after:
+            assert after.parent == outer.id  # the failed child was popped
+        # closed out of order: the one that stays open is not orphaned,
+        # and the one that went leaves no stale parent behind
+        first, second = monitor.span("stage"), monitor.span("data_load")
+        first.__enter__(), second.__enter__()
+        first.__exit__(None, None, None)
+        with monitor.span("fetch") as inner:
+            assert inner.parent == second.id
+        second.__exit__(None, None, None)
+        with monitor.span("fetch") as last:
+            assert last.parent == outer.id
+    monitor.disable_tracing()
+    failed = [e for e in tracer.events() if e["name"] == "launch"][0]
+    assert failed["attrs"]["error"] == "RuntimeError"
+
+
+# ---------------------------------------------------- readers of a capture
+
+def _event(name, start, dur, **stats):
+    return types.SimpleNamespace(name=name, start_ns=start, duration_ns=dur,
+                                 stats=list(stats.items()))
+
+
+def _profile(device_events, host_events):
+    line = lambda name, ev: types.SimpleNamespace(name=name, events=ev)
+    plane = lambda name, lines: types.SimpleNamespace(name=name, lines=lines)
+    return types.SimpleNamespace(planes=[
+        plane("/device:TPU:0", [line("XLA Ops", device_events),
+                                line("Steps", [_event("0", 0, 10 ** 9)])]),
+        plane("/device:CUSTOM:Megascale Trace", []),
+        plane("/host:CPU", [line("python3", host_events)])])
+
+
+def test_scope_seconds_on_a_written_out_profile():
+    ops = {"%fusion.2 = f32[4] fusion(...)":
+           "jit(run)/while/body/transpose(jvp(mlp_fc))/dot_general:"[:-1]}
+    profile = _profile([
+        _event("%while.1 = (...) while(...)", 0, 1000),
+        _event("%fusion.1 = bf16[8] fusion(...)", 100, 300,
+               tf_op="jit(run)/while/body/jvp(mlp_fc)/dot_general"),
+        _event("%fusion.2 = f32[4] fusion(...)", 400, 200),
+        _event("%copy.7 = bf16[8] copy(...)", 600, 100,
+               tf_op="jit(run)/while/body/jvp(attention)/fold_heads/transpose"),
+        _event("%divide_subtract_fusion.3 = f32[4] fusion(...)", 700, 250,
+               tf_op="jit(run)/while/body/optimizer_update/sub"),
+        _event("%fusion.9 = f32[] fusion(...)", 950, 50,
+               tf_op="jit(run)/while/body/jvp()/mul"),
+    ], [])
+    got = profiler.scope_seconds(
+        profile, ["mlp_fc", "attention", "fold_heads", "optimizer_update"],
+        ops)
+    assert got == pytest.approx({
+        "mlp_fc/fwd": 300e-9, "mlp_fc/bwd": 200e-9,
+        "fold_heads/fwd": 100e-9,  # the innermost scope wins
+        "optimizer_update/fwd": 250e-9,
+        "": 150e-9})  # the while's own 100 and the unscoped fusion's 50
+    rows = profiler.scoped_self_times(profile, ["optimizer_update"], ops)
+    assert ("divide_subtract_fusion", "optimizer_update", "fwd", 250) in rows
+    # an executable compiled without scopes: nothing is claimed, nothing fails
+    assert profiler.scope_seconds(profile, [], {}) == {"": pytest.approx(1e-6)}
+
+
+def test_host_spans_and_gaps_on_a_written_out_profile():
+    ms = 10 ** 6
+    device = [_event("%while.1", 10 * ms, 100 * ms),
+              _event("%fusion.1", 20 * ms, 30 * ms),
+              _event("%while.1", 120 * ms, 100 * ms)]
+    host = [
+        _event("dl4j/device_step", 5 * ms, 109 * ms, id=1, dispatch=1),
+        _event("dl4j/launch", 6 * ms, 3 * ms, id=2, dispatch=1),
+        _event("dl4j/fetch", 9 * ms, 104 * ms, id=3, dispatch=1),
+        _event("PjitFunction(run)", 6 * ms, 2 * ms),
+        _event("dl4j/device_step", 115 * ms, 108 * ms, id=4, dispatch=4),
+        _event("dl4j/launch", 116 * ms, 2 * ms, id=5, dispatch=4),
+        _event("dl4j/fetch", 118 * ms, 104 * ms, id=6, dispatch=4),
+    ]
+    profile = _profile(device, host)
+    spans = profiler.host_spans(profile)
+    assert [s["name"] for s in spans] == ["device_step", "launch", "fetch",
+                                          "device_step", "launch", "fetch"]
+    assert spans[1]["stats"] == {"id": 2, "dispatch": 1}
+    assert spans[0]["line"] == "python3"
+    got = profiler.gaps_by_host_span(profile)
+    # window 5..223 ms, busy 10..110 and 120..220: idle 5 + 10 + 3
+    assert got["dispatches"] == 2
+    assert got["window_s"] == pytest.approx(0.218)
+    assert got["idle_s"] == pytest.approx(0.018)
+    # 5..6 python, 6..9 launch, 9..10 device start | 110..113 fetch tail,
+    # 113..116 python, 116..118 launch, 118..120 device start | 220..222
+    # fetch tail, 222..223 python
+    assert got["launch_s"] == pytest.approx(0.005)
+    assert got["fetch_s"] == pytest.approx(0.005)
+    assert got["python_s"] == pytest.approx(0.005)
+    assert got["unattributed_s"] == pytest.approx(0.003)
+    # a capture of a program that opens no spans, or of the CPU alone
+    assert profiler.gaps_by_host_span(_profile(device, [])) == {}
+    assert profiler.host_spans(_profile(device, [])) == []
+
+
+def test_op_names_reads_the_metadata_stat_from_the_file(tmp_path):
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+    field = lambda num, body: varint(num << 3 | 2) + varint(len(body)) + body
+    num = lambda n, v: varint(n << 3) + varint(v)
+    stat_meta = lambda i, name: field(5, num(1, i) + field(
+        2, num(1, i) + field(2, name)))
+    event_meta = lambda i, name, stats: field(4, num(1, i) + field(
+        2, num(1, i) + field(2, name) + stats))
+    plane = lambda name, body: field(1, field(2, name) + body)
+    space = (
+        plane(b"/host:CPU", event_meta(1, b"%fusion.1 = host", field(
+            5, num(1, 26) + field(5, b"not/a/device:")))) +
+        plane(b"/device:TPU:0",
+              field(3, b"\x12\x07XLA Ops") +  # a line: skipped
+              stat_meta(26, b"tf_op") + stat_meta(27, b"jit(run)/interned") +
+              event_meta(1, b"%fusion.1 = f32[4] fusion()", field(
+                  5, num(1, 26) + field(5, b"jit(run)/jvp(ln1)/add:"))) +
+              event_meta(2, b"%copy.2 = f32[4] copy()", field(
+                  5, num(1, 26) + num(7, 27))) +
+              event_meta(3, b"%while.3", field(5, num(1, 9) + num(3, 5)))))
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space)
+    assert profiler.op_names(str(path)) == {
+        "%fusion.1 = f32[4] fusion()": "jit(run)/jvp(ln1)/add",
+        "%copy.2 = f32[4] copy()": "jit(run)/interned"}
+
+
+def test_self_times_is_the_walk_the_script_uses():
+    events = [_event("%while.1", 0, 100), _event("%fusion.1", 10, 30),
+              _event("%copy.1", 50, 20), _event("%fusion.2", 120, 5)]
+    got = {e.name: ns for e, ns in profiler.self_times(events)}
+    assert got == {"%while.1": 50, "%fusion.1": 30, "%copy.1": 20,
+                   "%fusion.2": 5}
+    assert profiler.op_group("%fusion.12 = bf16[8]{0} fusion(...)") == "fusion"
+    assert profiler.op_group("%jvp_flash_fwd_.288 = (...)") == "jvp_flash_fwd_"
+
+
+# ------------------------------------------------------------------ schema
+
+def _schema():
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "scripts",
+                        "check_telemetry_schema.py")
+    spec = importlib.util.spec_from_file_location("check_telemetry_schema",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_schema_knows_the_span_tree_and_the_new_families(
+        registry, no_compile_cache, tmp_path, capsys):
+    schema = _schema()
+    jsonl = str(tmp_path / "events.jsonl")
+    tracer = monitor.enable_tracing(jsonl)
+    net, staged = _net_and_set()
+    net.fit_scan(None, 2, staged=staged)
+    monitor.disable_tracing()
+    assert schema.validate_events_file(jsonl) == []
+    trace_path = str(tmp_path / "trace.json")
+    tracer.export_chrome_trace(trace_path)
+    assert schema.validate_chrome_trace_file(trace_path) == []
+    text = registry.prometheus_text()
+    assert "# TYPE dl4j_jit_cache_miss_total" in text
+    assert schema.validate_prometheus_text(text) == []
+    assert schema.validate_known_metrics(text) == []
+
+    span = {"type": "span", "name": "launch", "ts_us": 1.0, "dur_us": 2.0,
+            "pid": 1, "tid": 1, "id": 2, "parent": 1, "dispatch": 1}
+    root = {**span, "name": "device_step", "id": 1, "parent": None}
+    lines = lambda *objs: [__import__("json").dumps(o) for o in objs]
+    assert schema.validate_events_lines(lines(span, root)) == []
+    # a stream cut before the tree's root closed warns, and validates
+    assert schema.validate_events_lines(lines(span)) == []
+    assert "never closed" in capsys.readouterr().err
+    # an edge that does not resolve inside a tree that did close, a
+    # dispatch id that differs, half a tree
+    leaf = {**span, "name": "inner", "id": 3, "parent": 2}
+    assert schema.validate_events_lines(lines(leaf, root)) != []
+    assert schema.validate_events_lines(
+        lines({**span, "dispatch": 7}, root)) != []
+    assert schema.validate_events_lines(lines({**root, "dispatch": 9})) != []
+    half = {k: v for k, v in span.items() if k != "dispatch"}
+    assert schema.validate_events_lines(lines(half, root)) != []
+    assert schema.validate_events_lines(lines({**span, "id": "2"}, root)) != []
+    # spans from before the tree (no ids at all) still validate
+    old = {k: v for k, v in span.items()
+           if k not in ("id", "parent", "dispatch")}
+    assert schema.validate_events_lines(lines(old)) == []
