@@ -197,8 +197,12 @@ def phase_train(s: Smoke) -> None:
     s.log("train", f"compiled fit step: Mosaic custom calls {kernels} "
                    f"({time.perf_counter() - t0:.1f}s to re-obtain)")
     if s.on_chip:
-        want = {k: c["n_layers"] for k in
-                ("flash_fwd", "flash_dq", "flash_dkv")}
+        from deeplearning4j_tpu.ops.flash_attention import flash_path
+        head = c["d_model"] // c["heads"]
+        backward = {"resident": ("flash_dq_dkv",),
+                    "streamed": ("flash_dq", "flash_dkv")}[
+            flash_path(c["seq"], c["seq"], head, "bfloat16")]
+        want = {k: c["n_layers"] for k in ("flash_fwd",) + backward}
         assert kernels == want, (
             f"flash attention is not running as Mosaic kernels in every "
             f"block: found {kernels}, want {want}")

@@ -109,6 +109,10 @@ FEED_QUEUE_DEPTH_GAUGE = "dl4j_feed_queue_depth"
 FEED_PADDED_BATCHES_COUNTER = "dl4j_feed_padded_batches_total"
 JIT_CACHE_MISS_COUNTER = "dl4j_jit_cache_miss_total"
 SCORE_SYNC_COUNTER = "dl4j_score_sync_total"
+# ops/flash_attention.py: calls traced, labeled path="resident" (one
+# program a row, the sequence in VMEM) or "streamed" (blocks in the grid);
+# the choice is made from the shapes while tracing, so it is counted there
+FLASH_PATH_COUNTER = "dl4j_flash_path_total"
 
 # Serving plane (parallel/inference.py ParallelInference — the
 # micro-batching engine behind StreamingInference): request/batch

@@ -6,7 +6,47 @@ rides on, and the framework's custom-kernel slot: where the reference
 dropped to cuDNN helpers (``CudnnConvolutionHelper.java:51``) for its
 hot ops, the TPU build drops to Pallas for its hottest op.
 
-Design (online-softmax blocking fitted to the MXU/VMEM):
+Two sets of kernels, chosen from the shapes by ``flash_path`` (a pure
+function of ``(tq, tk, d, dtype)``; ``dl4j_flash_path_total{path=}``
+counts the choice once a traced call, and a device trace shows it as
+``flash_dq_dkv`` against ``flash_dq`` + ``flash_dkv``):
+
+**Resident** — self-attention whose row fits VMEM (1k / head 64, 2k /
+head 128, every shorter length): one program a (batch, head) row, the
+row's whole q, k, v (and dO, lse, delta) in VMEM, the block loop as
+straight-line code in the body. Dead blocks are not in the loop, only
+diagonal blocks carry the iota mask, the forward takes a plain softmax
+over the keys a block of queries sees (no running max, no rescaling),
+and the backward is ONE kernel that makes the scores once for dq, dk
+and dv (5 products and 1 exp chain a block; the streamed pair makes 7
+and 2). Readings on a v5e (PR 28, device self time from a trace, ms
+a call of bh rows; ``PERF.md`` section 6 has the step's):
+
+    bh x t x d        streamed fwd / dq+dkv   resident fwd / dq_dkv
+    128 x 1024 x 64    0.455 / 1.404           0.302 / 0.734
+    24 x 2048 x 128    0.302 / 0.756           0.188 / 0.419
+    512 x 256 x 64     0.629 / 0.732           0.286 / 0.435
+    1024 x 128 x 64    0.618 / 1.012           0.446 / 0.607
+
+  The in-body block is 512 for both kernels. Block 256 read 0.695 ms
+  in the head-64 backward (5% faster) and level elsewhere (0.423 at
+  2k / 128; forwards 0.324 and 0.187 against 0.333 and 0.189 before
+  the lse became a row), block 128 three times slower; it unrolls
+  into 10 blocks at 1k and 36 at 2k where 512 makes 3 and 10, and is
+  left to a later PR. The same body rolled into ``fori_loop``s over
+  blocks (dynamic slices, two bodies in all) read 16–22% slower than
+  the unrolled one (0.850 against 0.732 and 0.509 against 0.418 ms at
+  block 512). The forward writes lse as the [1, t] row the backward
+  reads: as a [t, 1] column (the streamed layout) every value costs a
+  128-lane tile in the kernel's store and in an XLA pass that repacks
+  it (0.333 against 0.302 ms, and 1.2–3.6 ms a step of ``reduce``).
+  Both wrappers are ``jax.jit``s, so a model's layers share one traced
+  and lowered body: unrolled bodies traced once a layer cost the first
+  dispatch 3.3 s at 2k x 18 layers on the chip's host.
+
+**Streamed** — everything else (cross-length calls, lengths over the
+budget: 4k and up at head 128, the 16k / 32k long-context path), as
+before PR 28:
 
 - forward grid = (batch*heads, q_blocks, k_blocks); the k axis is the
   innermost ("arbitrary") dimension so the [block_q, d] accumulator,
@@ -17,32 +57,32 @@ Design (online-softmax blocking fitted to the MXU/VMEM):
   the backward never has to replay the online softmax.
 - causal masking: k-blocks entirely above the diagonal are skipped
   under ``@pl.when`` (no MXU/DMA compute); live blocks all apply the
-  iota mask — a masked/unmasked branch split was measured ~2x SLOWER
-  per step (duplicated conditional bodies defeat Mosaic's pipelining),
-  so one masked body wins.
-- the softmax scale is folded into q ONCE in XLA before the kernel
-  (a per-step in-kernel multiply over [block_q, d] measured ~6x more
-  expensive than the single pre-pass at 16k).
+  iota mask — in the grid formulation a masked/unmasked branch split
+  was measured ~2x SLOWER per step (duplicated conditional bodies
+  defeat Mosaic's pipelining), so one masked body wins there. (The
+  resident body has no branches: its diagonal and full blocks are
+  different straight-line code.)
 - backward = two more Pallas kernels (the TPU shape of the standard
   two-pass flash backward): a dq kernel (k innermost, dq accumulator
   in VMEM) and a dk/dv kernel (q innermost, dk+dv accumulators in
-  VMEM). Both compute the score block TRANSPOSED ([block_k, block_q])
-  so the per-query ``lse`` and ``delta = rowsum(dO·O)`` vectors enter
-  as [1, block_q] row broadcasts — no per-step relayouts. The O(t²)
-  weights are rebuilt blockwise from (q, k, lse) and never touch HBM,
-  so a 32k-causal TRAINING step fits where the XLA formulation OOMs
-  on the [b, h, t, t] score buffer.
-- all matmuls run on the MXU in f32 accumulation
-  (``preferred_element_type``) from native-bf16 operands.
-- the forward is VPU-bound at ~32% MFU (16k causal, v5e) — a measured
-  plateau, not a tuning gap: per k-step the online-softmax chain
-  (~10M VPU elementwise ops) hides the 2 MXU matmuls. Rejected
-  variants (r4, all measured on-chip): triangular live-block grid,
-  scalar-prefetch index tables, precomputed D-matrix masks (f32 slow,
-  i8 unsupported), masked/unmasked branch split, dead-block index
-  clamping, exp2-space softmax, 2048-wide blocks (VMEM). See
-  BASELINE.md "Flash-attention forward roofline". The backward's
-  higher MFU is structural (7 matmuls per 2 exp chains).
+  VMEM). The O(t²) weights are rebuilt blockwise from (q, k, lse) and
+  never touch HBM, so a 32k-causal TRAINING step fits where the XLA
+  formulation OOMs on the [b, h, t, t] score buffer.
+- at 16k causal on the old platform the forward read ~32% of the MXU
+  peak and was VPU-bound (per k-step the online-softmax chain hides
+  the 2 matmuls); rejected there, all in the grid formulation and at
+  16k only: triangular live-block grid, scalar-prefetch index tables,
+  precomputed D-matrix masks (f32 slow, i8 unsupported), dead-block
+  index clamping, exp2-space softmax, 2048-wide blocks (VMEM). See
+  BASELINE.md "Flash-attention forward roofline". None of that was
+  read at 1k or 2k, where this path no longer runs.
+
+Both paths: the softmax scale is folded into q ONCE in XLA before the
+kernel; the backward builds the score block TRANSPOSED ([keys,
+queries]) so the per-query ``lse`` and ``delta = rowsum(dO·O)`` enter as
+[1, queries] row broadcasts with no relayouts; all matmuls run on the
+MXU in f32 accumulation (``preferred_element_type``) from native-bf16
+operands, the exp and the softmax algebra in float32.
 
 CPU processes (the test mesh) run the same kernels under the Pallas
 interpreter, so fwd+bwd are exercised everywhere; the TPU path
@@ -59,6 +99,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.monitor import FLASH_PATH_COUNTER, get_registry
 from deeplearning4j_tpu.ops.attention import scaled_dot_product_attention
 from deeplearning4j_tpu.util.device import pallas_interpret
 
@@ -333,17 +374,17 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal: bool,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, interpret):
+def _flash_streamed(q, k, v, causal, block_q, block_k, interpret):
     o, _ = _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret)
     return o
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_streamed_fwd(q, k, v, causal, block_q, block_k, interpret):
     o, lse = _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_streamed_bwd(causal, block_q, block_k, interpret, res, g):
     q, k, v, o, lse = res
     # backward blocks: score blocks live in VMEM 4x over (pT/dPT/dsT
     # temporaries), so cap at 512x512. A caller-chosen forward block
@@ -355,7 +396,216 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     return _flash_bwd_impl(q, k, v, o, lse, g, causal, bq, bk, interpret)
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_flash_streamed.defvjp(_flash_streamed_fwd, _flash_streamed_bwd)
+
+
+# ------------------------------------------------- sequence-resident path
+#
+# One program a (batch, head) row of a self-attention call (tq == tk): the
+# row's whole q, k, v (and dO, lse, delta in the backward) sit in VMEM and
+# the block loop is inside the kernel body. Dead blocks are not in the
+# loop, only the diagonal blocks carry the iota mask, and the backward
+# makes the scores once for dq, dk and dv together. Shares no kernel logic
+# with the streamed path: that one wants the block loop in the grid.
+
+#: the in-body block, both kernels (the module docstring has the chip's
+#: readings of 128, 256 and 512)
+_RESIDENT_BLOCK = 512
+#: what a row's operands, accumulators and block temporaries may take of
+#: VMEM for the resident kernels to be chosen: by ``_resident_bytes`` 2k /
+#: head 128 takes 16.3 MiB, 4k / head 128 26.5 MiB, 16k / head 128 88 MiB
+_RESIDENT_BUDGET = 24 * 2 ** 20
+_SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
+
+
+def _resident_block(t: int) -> int:
+    """The in-body block for a length: the whole length when short, else
+    the widest divisor of at least 128 (a narrower one would unroll into
+    hundreds of blocks); 0 where there is none."""
+    if t <= _RESIDENT_BLOCK:
+        return t
+    b = _pick_block(t, _RESIDENT_BLOCK)
+    return b if b >= 128 else 0
+
+
+def _resident_bytes(t: int, d: int, itemsize: int) -> int:
+    """VMEM the resident backward (the larger of the two kernels) holds
+    for one row: inputs and outputs double-buffered, the float32
+    accumulators, the block temporaries. A head narrower than 128 lanes
+    is padded to them."""
+    lanes = -(-d // 128) * 128
+    io = 2 * (7 * t * lanes * itemsize      # q, k, v, dO; dq, dk, dv
+              + 2 * 8 * t * 4)              # lse, delta
+    acc = 3 * t * lanes * 4
+    tmp = 6 * _resident_block(t) ** 2 * 4   # sT, pT, dPT, dsT and their casts
+    return io + acc + tmp
+
+
+def flash_path(tq: int, tk: int, d: int, dtype) -> str:
+    """Which kernels ``flash_attention`` runs at these shapes, a pure
+    function of them: "resident" for self-attention lengths that split
+    into in-body blocks and whose row fits the VMEM budget, "streamed" for
+    everything else (cross-length calls, 16k and 32k), as before."""
+    fits = tq == tk and _resident_block(tq) and _resident_bytes(
+        tq, d, jnp.dtype(dtype).itemsize) <= _RESIDENT_BUDGET
+    return "resident" if fits else "streamed"
+
+
+def _row_params(t, d, dtype):
+    """Compiler parameters of a resident kernel: rows are independent, and
+    the scoped VMEM limit follows the same arithmetic as the rule."""
+    need = 2 * _resident_bytes(t, d, jnp.dtype(dtype).itemsize)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=max(need, _SCOPED_VMEM_DEFAULT))
+
+
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))   # a · b
+_TN = (((0,), (0,)), ((), ()))   # aᵀ · b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _diagonal_keep(block, q_dim):
+    """The causal mask of a diagonal block whose queries run along
+    ``q_dim``: the same for every such block."""
+    qi = jax.lax.broadcasted_iota(jnp.int32, (block, block), q_dim)
+    ki = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1 - q_dim)
+    return qi >= ki
+
+
+def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                         *, causal, block):
+    t = q_ref.shape[1]
+    for q0 in range(0, t, block):
+        rows = slice(q0, q0 + block)
+        q = q_ref[0, rows, :]  # pre-scaled, as in the streamed forward
+        # causal: the keys before this block of queries need no mask, the
+        # diagonal block does; otherwise every key, unmasked
+        parts = []
+        if q0 or not causal:
+            cols = slice(0, q0 if causal else t)
+            parts.append((cols, _dot(q, k_ref[0, cols, :], _NT)))
+        if causal:
+            s = _dot(q, k_ref[0, rows, :], _NT)
+            parts.append((rows, jnp.where(_diagonal_keep(block, 0), s,
+                                          _NEG_INF)))
+        # the whole key row is here: a plain softmax, no running max
+        m = functools.reduce(jnp.maximum, [
+            jnp.max(s, axis=1, keepdims=True) for _, s in parts])
+        ps = [(cols, jnp.exp(s - m)) for cols, s in parts]
+        denom = jnp.maximum(
+            sum(jnp.sum(p, axis=1, keepdims=True) for _, p in ps), 1e-30)
+        acc = sum(_dot(p.astype(v_ref.dtype), v_ref[0, cols, :], _NN)
+                  for cols, p in ps)
+        o_ref[0, rows, :] = (acc / denom).astype(o_ref.dtype)
+        # as a lane-major row, the layout the backward reads: a [t, 1]
+        # column costs a 128-lane tile a value, in the kernel's store and
+        # again in the XLA pass that has to repack it (.T, not a reshape:
+        # Mosaic's relayout for that one read 0.06 ms a call slower)
+        lse_ref[0, :, rows] = (m + jnp.log(denom)).T
+
+
+# jitted, both wrappers: every layer of a model calls with the same shapes,
+# so the unrolled body is traced and lowered once a program, not once a
+# layer; XLA inlines the calls
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _resident_fwd(q, k, v, causal: bool, block: int, interpret: bool):
+    """q,k,v: [bh, t, d] -> (o, lse[bh, 1, t])."""
+    bh, t, d = q.shape
+    q = (q * (1.0 / d ** 0.5)).astype(q.dtype)  # fold softmax scale once
+    spec = pl.BlockSpec((1, t, d), lambda b: (b, 0, 0), **_VMEM)
+    return pl.pallas_call(
+        functools.partial(_resident_fwd_kernel, causal=causal, block=block),
+        grid=(bh,),
+        in_specs=[spec, spec, spec],
+        out_specs=[spec,
+                   pl.BlockSpec((1, 1, t), lambda b: (b, 0, 0), **_VMEM)],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)],
+        compiler_params=_row_params(t, d, q.dtype), interpret=interpret,
+        name="flash_fwd",
+    )(q, k, v)
+
+
+def _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+                         dq_ref, dk_ref, dv_ref, dq_acc,
+                         *, scale, causal, block):
+    """dq, dk and dv of one row in one pass: per live block the scores
+    once, five products and one exp chain (the streamed pair makes seven
+    and two). Same algebra and precision as ``_bwd_block``."""
+    t, d = q_ref.shape[1:]
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+    for k0 in range(0, t, block):
+        keys = slice(k0, k0 + block)
+        k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+        dk = dv = jnp.zeros((block, d), jnp.float32)
+        # causal: the diagonal block, then the queries after it
+        for q0 in range(k0 if causal else 0, t, block):
+            rows = slice(q0, q0 + block)
+            qs, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            sT = _dot(k, qs, _NT)                    # [keys, queries]
+            if causal and q0 == k0:
+                sT = jnp.where(_diagonal_keep(block, 1), sT, _NEG_INF)
+            # lse and delta are [1, t] rows: they broadcast over the keys
+            pT = jnp.exp(sT - lse_ref[0, :, rows])
+            dsT = pT * (_dot(v, do, _NT) - dlt_ref[0, :, rows])
+            pT, dsT = pT.astype(do.dtype), dsT.astype(qs.dtype)
+            dv += _dot(pT, do, _NN)
+            dk += _dot(dsT, qs, _NN)
+            dq_acc[rows, :] += _dot(dsT, k, _TN)
+        dk_ref[0, keys, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, keys, :] = dv.astype(dv_ref.dtype)
+    dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _resident_bwd(q, k, v, o, lse, g, causal: bool, block: int,
+                  interpret: bool):
+    bh, t, d = q.shape
+    scale = 1.0 / (d ** 0.5)
+    q = (q * scale).astype(q.dtype)  # pre-scale once; dq re-scales at the end
+    # delta = rowsum(dO ∘ O) in one fused XLA pass, as a [bh, 1, t] row
+    # like lse
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1).reshape(bh, 1, t)
+    spec = pl.BlockSpec((1, t, d), lambda b: (b, 0, 0), **_VMEM)
+    rowspec = pl.BlockSpec((1, 1, t), lambda b: (b, 0, 0), **_VMEM)
+    return pl.pallas_call(
+        functools.partial(_resident_bwd_kernel, scale=scale, causal=causal,
+                          block=block),
+        grid=(bh,),
+        in_specs=[spec, spec, spec, spec, rowspec, rowspec],
+        out_specs=[spec, spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d), x.dtype)
+                   for x in (q, k, v)],
+        scratch_shapes=[_scratch((t, d))],
+        compiler_params=_row_params(t, d, q.dtype), interpret=interpret,
+        name="flash_dq_dkv",
+    )(q, k, v, g, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_resident(q, k, v, causal, interpret):
+    return _flash_resident_fwd(q, k, v, causal, interpret)[0]
+
+
+def _flash_resident_fwd(q, k, v, causal, interpret):
+    o, lse = _resident_fwd(q, k, v, causal, _resident_block(q.shape[1]),
+                           interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_resident_bwd(causal, interpret, res, g):
+    q, k, v, o, lse = res
+    return _resident_bwd(q, k, v, o, lse, g, causal,
+                         _resident_block(q.shape[1]), interpret)
+
+
+_flash_resident.defvjp(_flash_resident_fwd, _flash_resident_bwd)
 
 
 def flash_attention(
@@ -398,6 +648,14 @@ def flash_attention(
     # owner in the device trace, apart from the kernels
     with jax.named_scope("fold_heads"):
         q, k, v = fold(q), fold(k), fold(v)
-    o = _flash(q, k, v, causal, bq, bk, interpret)
+    path = flash_path(tq, tk, d, q.dtype)
+    if path == "resident":
+        o = _flash_resident(q, k, v, causal, interpret)
+    else:
+        o = _flash_streamed(q, k, v, causal, bq, bk, interpret)
+    # decided while tracing, so counted there: nothing in the compiled step
+    get_registry().counter(
+        FLASH_PATH_COUNTER, "flash_attention calls traced, by the kernels "
+        "their shapes chose", path=path).inc()
     with jax.named_scope("unfold_heads"):
         return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

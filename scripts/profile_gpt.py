@@ -2,7 +2,7 @@
 
 Usage: python scripts/profile_gpt.py [--trace] [--d-model N] ...
 Prints tokens/sec + MFU; with --trace, aggregates device op self-times
-from the captured trace by op group (flash fwd/dq/dkv kernels,
+from the captured trace by op group (the flash kernels,
 fusions, copies) — the BASELINE.md attribution workflow — then by the
 program's named scopes, and the device's idle time by the program's
 host spans (util/profiler.scope_seconds / gaps_by_host_span).
