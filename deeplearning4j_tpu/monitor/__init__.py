@@ -113,6 +113,12 @@ SCORE_SYNC_COUNTER = "dl4j_score_sync_total"
 # program a row, the sequence in VMEM) or "streamed" (blocks in the grid);
 # the choice is made from the shapes while tracing, so it is counted there
 FLASH_PATH_COUNTER = "dl4j_flash_path_total"
+# ops/ssd.py: ssd_scan calls traced, labeled path="kernel" (ssd_fwd / ssd_bwd)
+# or "xla" (the plain chunked form), chosen from the shapes while tracing
+SSD_PATH_COUNTER = "dl4j_ssd_path_total"
+# nn/multilayer.py: blocks whose bodies the train step just built recomputes
+# in its backward pass (conf.recompute_blocks); 0 for a model that keeps them
+RECOMPUTED_BLOCKS_GAUGE = "dl4j_recomputed_blocks"
 
 # Serving plane (parallel/inference.py ParallelInference — the
 # micro-batching engine behind StreamingInference): request/batch

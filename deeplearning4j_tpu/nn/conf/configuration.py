@@ -87,6 +87,11 @@ class NeuralNetConfiguration:
     # compute dtype for the compiled step ("float32" | "bfloat16"):
     # bfloat16 keeps the MXU fed; params/updater state stay float32.
     compute_dtype: str = "float32"
+    # run each block layer's forward again in the backward pass of the
+    # train step instead of keeping its activations (jax.checkpoint around
+    # the layers whose impl says ``recomputable``): a model whose
+    # activations would not fit beside its training state
+    recompute_blocks: bool = False
 
     def updater_config_for(self, layer: L.Layer) -> UpdaterConfig:
         """Effective per-variable updater config = global defaults with the

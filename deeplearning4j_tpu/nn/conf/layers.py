@@ -107,9 +107,17 @@ class OutputLayer(FeedForwardLayer):
 @dataclasses.dataclass(frozen=True)
 class RnnOutputLayer(FeedForwardLayer):
     """``nn/conf/layers/RnnOutputLayer.java`` — per-timestep output + loss,
-    honoring a [batch, T] label mask."""
+    honoring a [batch, T] label mask.
+
+    ``tied_to`` names another layer of the net (``"layer0"``) whose leaf
+    ``W`` this head reads, transposed, in place of a ``W`` of its own (a
+    language model's head tied to its embedding: one leaf, whose
+    gradient is the sum of both uses); such a head owns no parameters, so
+    ``has_bias`` must be False. ``logits_scale`` multiplies the logits."""
 
     loss_function: str = "mcxent"
+    tied_to: Optional[str] = None
+    logits_scale: float = 1.0
 
 
 @register_layer
@@ -231,9 +239,13 @@ class SequenceEmbeddingLayer(FeedForwardLayer):
     """Token + learned positional embedding: int indices [b, t] →
     [b, t, n_out]. No reference counterpart (the reference embeds only
     [b] ids, ``EmbeddingLayer.java``); this is the transformer on-ramp
-    (SURVEY §7.7 extension)."""
+    (SURVEY §7.7 extension). ``positions=False`` is a model without
+    positional embeddings: no ``P`` leaf exists. ``output_multiplier``
+    scales the embedded tokens."""
 
     max_len: int = 2048
+    positions: bool = True
+    output_multiplier: float = 1.0
 
 
 @register_layer
@@ -252,6 +264,59 @@ class TransformerBlock(FeedForwardLayer):
     num_experts: int = 0
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class RMSNormLayer(FeedForwardLayer):
+    """``x / sqrt(mean(x^2) + eps) * g`` over the last axis, one gain a
+    channel: the norm before a modern decoder's head."""
+
+    eps: float = 1e-5
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedDecoderBlock(FeedForwardLayer):
+    """What the decoder blocks of the hybrid family share: pre-RMSNorm,
+    a mixer, a gated MLP ``(silu(a) * b) W_down`` with ``[a, b] = h
+    W_gate_up`` of hidden width ``ffn_hidden``, no biases, and both
+    residual branches scaled by ``residual_multiplier``. Training only:
+    these blocks have no cache (ROADMAP Reach A.8)."""
+
+    ffn_hidden: int = 0
+    rms_eps: float = 1e-5
+    residual_multiplier: float = 1.0
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class Mamba2Block(GatedDecoderBlock):
+    """A Mamba-2 state-space mixer (``ops/ssd.py``) and the gated MLP.
+    ``n_heads`` heads of ``d_head`` channels (``n_heads * d_head`` is the
+    inner width), a state of ``d_state`` a channel, ``n_groups`` groups of
+    heads sharing ``B`` and ``C``, a causal depthwise convolution of width
+    ``d_conv`` before the scan, ``chunk_size`` the chunk of the chunked
+    evaluation. n_in == n_out == d_model."""
+
+    n_heads: int = 64
+    d_head: int = 64
+    d_state: int = 128
+    d_conv: int = 4
+    n_groups: int = 1
+    chunk_size: int = 256
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class GroupedQueryBlock(GatedDecoderBlock):
+    """Causal self-attention with ``num_kv_heads`` key/value heads under
+    ``num_heads`` query heads, no positions and no biases, and the gated
+    MLP. Scores are scaled by ``attention_multiplier`` (``None``: the
+    usual 1/sqrt(head width)). n_in == n_out == d_model."""
+
+    num_heads: int = 8
+    num_kv_heads: int = 8
+    attention_multiplier: Optional[float] = None
 
 
 @register_layer

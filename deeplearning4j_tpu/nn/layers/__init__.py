@@ -14,6 +14,7 @@ from deeplearning4j_tpu.nn.layers import (  # noqa: F401  (registers impls)
     attention,
     convolution,
     feedforward,
+    hybrid,
     moe,
     normalization,
     recurrent,

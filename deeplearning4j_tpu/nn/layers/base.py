@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
+from deeplearning4j_tpu.util.dtypes import cast_floats
 
 _IMPL_REGISTRY: Dict[Type[L.Layer], Type["LayerImpl"]] = {}
 
@@ -91,6 +92,17 @@ class LayerImpl:
     # lower one under a multi-device jit), so attention impls hand this
     # to ``dispatch_attention``, which maps the kernel over the mesh.
     _mesh = None
+
+    #: True for block layers whose forward the container may run again in
+    #: the backward pass instead of keeping its activations
+    #: (``NeuralNetConfiguration.recompute_blocks``)
+    recomputable = False
+
+    def cast_params(self, params, dtype):
+        """The layer's parameters as its forward takes them under a
+        half-precision compute policy: every float leaf cast, unless the
+        layer keeps some in float32."""
+        return cast_floats(params, dtype)
 
     def _slice_replicate(self, x):
         """Constrain ``x`` to replicated over the slice mesh (identity

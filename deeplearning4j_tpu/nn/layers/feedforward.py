@@ -81,6 +81,15 @@ class OutputImpl(BaseDenseImpl):
     def has_loss(self) -> bool:
         return True
 
+    def init_params(self, key):
+        if getattr(self.conf, "tied_to", None):
+            # the container hands this head the leaf it names (transposed,
+            # as "W"): it owns nothing, so nothing is held twice
+            if self.conf.has_bias:
+                raise ValueError("a tied head owns no parameters: has_bias=False")
+            return {}
+        return super().init_params(key)
+
     def preout(self, params, x):
         # OUTPUT-HEAD override only (hidden dense layers keep their
         # policy dtype end to end): on half-precision operands the head
@@ -101,6 +110,9 @@ class OutputImpl(BaseDenseImpl):
             z = jnp.matmul(x, W, preferred_element_type=jnp.float32)
         else:
             z = x @ W
+        scale = getattr(self.conf, "logits_scale", 1.0)
+        if scale != 1.0:
+            z = z * scale
         return z + params["b"].astype(z.dtype) if "b" in params else z
 
     @property

@@ -52,6 +52,8 @@ class SequenceEmbeddingImpl(LayerImpl):
         W = init_weights(kw, (c.n_in, c.n_out), self.weight_init,
                          c.n_in, c.n_out, c.dist_mean, c.dist_std,
                          dist=c.dist)
+        if not c.positions:
+            return {"W": W}
         P = 0.01 * jax.random.normal(kp, (c.max_len, c.n_out), jnp.float32)
         return {"W": W, "P": P}
 
@@ -60,10 +62,14 @@ class SequenceEmbeddingImpl(LayerImpl):
         if idx.ndim == 3:  # one-hot input tolerated
             idx = jnp.argmax(idx, axis=-1)
         t = idx.shape[1]
-        if t > self.conf.max_len:
+        if self.conf.positions and t > self.conf.max_len:
             raise ValueError(f"sequence length {t} > max_len {self.conf.max_len}")
         with jax.named_scope("embed"):
-            z = qtake(params, "W", idx) + params["P"][:t][None]
+            z = qtake(params, "W", idx)
+            if self.conf.positions:
+                z = z + params["P"][:t][None]
+            if self.conf.output_multiplier != 1.0:
+                z = (z * self.conf.output_multiplier).astype(z.dtype)
         return self._slice_replicate(z), state
 
 

@@ -42,7 +42,9 @@ from deeplearning4j_tpu.nn.updater import (
     init_updater_state,
     normalize_gradient,
 )
-from deeplearning4j_tpu.monitor import H2D_BYTES_COUNTER, get_registry, span
+from deeplearning4j_tpu.monitor import (H2D_BYTES_COUNTER,
+                                        RECOMPUTED_BLOCKS_GAUGE, get_registry,
+                                        span)
 from deeplearning4j_tpu.nn.observed import SyncedStateAttr
 from deeplearning4j_tpu.optimize.deferred import (
     count_jit_cache_miss,
@@ -51,7 +53,7 @@ from deeplearning4j_tpu.optimize.deferred import (
     score_sink,
     set_host_step,
 )
-from deeplearning4j_tpu.util.dtypes import cast_floats, cast_like, resolve_compute_dtype
+from deeplearning4j_tpu.util.dtypes import cast_like, resolve_compute_dtype
 
 Params = Dict[str, Dict[str, jnp.ndarray]]
 
@@ -63,6 +65,14 @@ Params = Dict[str, Dict[str, jnp.ndarray]]
 STEP_SCOPES = ("grad_norm", "optimizer_update", "lm_head", "loss", "embed",
                "ln1", "qkv_proj", "attention", "attn_out_proj", "ln2",
                "mlp_fc", "mlp_proj", "fold_heads", "unfold_heads")
+#: the same for a model of the hybrid state-space family
+#: (nn/layers/hybrid.py): its blocks' parts in place of the GPT block's
+HYBRID_STEP_SCOPES = (
+    "grad_norm", "optimizer_update", "lm_head", "loss", "embed", "rms1",
+    "mamba_in_proj", "mamba_conv", "ssd_scan", "mamba_gate_norm",
+    "mamba_out_proj", "qkv_proj", "kv_repeat", "attention", "attn_out_proj",
+    "rms2", "mlp_gate_up", "mlp_down", "final_norm", "fold_heads",
+    "unfold_heads")
 
 
 class MultiLayerNetwork:
@@ -85,6 +95,10 @@ class MultiLayerNetwork:
         self.out = self.impls[-1]
         if not self.out.has_loss():
             raise ValueError("last layer must be an output/loss layer")
+        tied = getattr(self.out.conf, "tied_to", None)
+        if tied and tied not in [i.name for i in self.impls[:-1]]:
+            raise ValueError(f"the head is tied to {tied!r}, which is no "
+                             f"layer of this net")
         self.params: Optional[Params] = None
         self.states: Optional[Dict[str, Any]] = None
         self.opt_state: Optional[Dict[str, Any]] = None
@@ -143,6 +157,16 @@ class MultiLayerNetwork:
 
     # -------------------------------------------------------- functional core
 
+    def _params_of(self, params: Params, impl):
+        """The leaves ``impl`` reads: its own and, for a head tied to
+        another layer's leaf, that leaf, transposed, as its ``W``. The
+        tree holds the leaf once; its gradient is the sum of both uses."""
+        p = params[impl.name]
+        tied = getattr(impl.conf, "tied_to", None)
+        if tied:
+            p = {**p, "W": params[tied]["W"].T}
+        return p
+
     def _forward(self, params: Params, states, x, train: bool, rng, fmask):
         """All-layer forward; returns (activations per layer, new states)."""
         acts = []
@@ -154,18 +178,18 @@ class MultiLayerNetwork:
             pre = self.conf.input_preprocessors.get(i)
             if pre is not None:
                 x = pre(x)
-            p = params[impl.name]
+            p = self._params_of(params, impl)
             if self._cd is not None:
                 if i == n_last and impl.has_loss():
                     if "W" in p:
                         # head matmul on bf16 operands, f32 accumulation
                         # (preout's preferred_element_type): logits and
                         # the loss math stay f32 at full MXU rate
-                        p = cast_floats(p, self._cd)
+                        p = impl.cast_params(p, self._cd)
                     else:  # matmul-free heads (LossLayer): loss runs f32
                         x = x.astype(jnp.float32)
                 else:
-                    p = cast_floats(p, self._cd)
+                    p = impl.cast_params(p, self._cd)
             lrng = jax.random.fold_in(rng, i) if rng is not None else None
             x, ns = impl.forward(p, x, states[impl.name], train, lrng, mask=fmask)
             if self._cd is not None:
@@ -184,22 +208,30 @@ class MultiLayerNetwork:
             pre = self.conf.input_preprocessors.get(i)
             if pre is not None:
                 x = pre(x)
-            p = params[impl.name]
-            if self._cd is not None:
-                p = cast_floats(p, self._cd)
+
+            def layer(p, x, state, lrng, impl=impl):
+                if self._cd is not None:
+                    p = impl.cast_params(p, self._cd)
+                x, ns = impl.forward(p, x, state, train, lrng, mask=fmask)
+                if self._cd is not None:
+                    ns = cast_like(ns, state)
+                return x, ns
+
+            if train and self._recomputes(impl):
+                # the block's body runs again in the backward pass: what is
+                # kept is its input (and the float32 leaves, cast inside)
+                layer = jax.checkpoint(layer)
             lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            x, ns = impl.forward(p, x, states[impl.name], train, lrng, mask=fmask)
-            if self._cd is not None:
-                ns = cast_like(ns, states[impl.name])
-            new_states[impl.name] = ns
+            x, new_states[impl.name] = layer(
+                params[impl.name], x, states[impl.name], lrng)
         i_out = len(self.impls) - 1
         pre = self.conf.input_preprocessors.get(i_out)
         if pre is not None:
             x = pre(x)
-        p_out = params[self.out.name]
+        p_out = self._params_of(params, self.out)
         if self._cd is not None:
             if "W" in p_out:  # bf16 head matmul, f32 logits (preout)
-                p_out = cast_floats(p_out, self._cd)
+                p_out = self.out.cast_params(p_out, self._cd)
             else:
                 x = x.astype(jnp.float32)  # loss always f32
         lrng = jax.random.fold_in(rng, i_out) if rng is not None else None
@@ -214,8 +246,15 @@ class MultiLayerNetwork:
                 score = score + ns["__aux_loss__"].astype(score.dtype)
         return score, new_states
 
+    def _recomputes(self, impl) -> bool:
+        return bool(self.gc.recompute_blocks and impl.recomputable)
+
     def _make_train_step(self, has_fmask: bool, has_lmask: bool):
         """One fully-fused optimization iteration."""
+        get_registry().gauge(
+            RECOMPUTED_BLOCKS_GAUGE, "block layers whose bodies the train "
+            "step just built runs again in its backward pass").set(
+            sum(self._recomputes(impl) for impl in self.impls))
         gn_specs = []
         for impl in self.impls:
             nt = GradientNormalization(self.gc.resolve(impl.conf, "gradient_normalization"))

@@ -443,6 +443,8 @@ KNOWN_DL4J_METRICS = {
     "dl4j_jit_cache_miss_total",
     "dl4j_score_sync_total",
     "dl4j_flash_path_total",
+    "dl4j_ssd_path_total",
+    "dl4j_recomputed_blocks",
     # serving plane (parallel/inference.py ParallelInference)
     "dl4j_infer_requests_total",
     "dl4j_infer_batches_total",

@@ -1,11 +1,15 @@
 """Profile the GPT train step on the real chip and attribute MFU.
 
 Usage: python scripts/profile_gpt.py [--trace] [--d-model N] ...
+       python scripts/profile_gpt.py --cell <benchmark cell> [--seed N]
 Prints tokens/sec + MFU; with --trace, aggregates device op self-times
 from the captured trace by op group (the flash kernels,
 fusions, copies) — the BASELINE.md attribution workflow — then by the
 program's named scopes, and the device's idle time by the program's
-host spans (util/profiler.scope_seconds / gaps_by_host_span).
+host spans (util/profiler.scope_seconds / gaps_by_host_span). ``--cell`` traces a
+cell of ``BENCHMARK.json`` as its driver runs it (the driver's run object:
+its net, its weights from the seed, its staged tokens) and prints the same
+tables, for any family the benchmark has.
 """
 import argparse
 import collections
@@ -17,7 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from deeplearning4j_tpu.nn.multilayer import STEP_SCOPES
+from deeplearning4j_tpu.nn.multilayer import HYBRID_STEP_SCOPES, STEP_SCOPES
 from deeplearning4j_tpu.util import profiler
 from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 from deeplearning4j_tpu.util.device import device_peaks
@@ -41,13 +45,13 @@ def aggregate_trace(log_dir):
                   key=lambda t: -t[1])
 
 
-def print_scope_tables(log_dir, steps):
+def print_scope_tables(log_dir, steps, scopes=STEP_SCOPES):
     """Device self time by the program's named scopes (and which op
     groups each scope owns), then the device's idle time by what the
     host was doing (``dl4j/`` spans on the capture's host plane)."""
     profile = profiler.load_trace(log_dir)
     ops = profiler.op_names(log_dir)
-    rows = profiler.scoped_self_times(profile, STEP_SCOPES, ops)
+    rows = profiler.scoped_self_times(profile, scopes, ops)
     total = sum(ns for *_, ns in rows) or 1
     by_scope = collections.Counter()
     groups = collections.defaultdict(collections.Counter)
@@ -83,9 +87,43 @@ def print_scope_tables(log_dir, steps):
         print(f"  {key:13s} {1e3 * gaps[key + '_s'] / n:8.3f} ms a dispatch")
 
 
+def print_trace(log_dir, steps, scopes=STEP_SCOPES):
+    rows = aggregate_trace(log_dir)
+    total = sum(d for _, d, _ in rows)
+    print(f"\ndevice self-time total: {total/1e3:.1f} ms "
+          f"over {len(rows)} op groups")
+    print("top 20 op groups:")
+    for n, d, k in rows[:20]:
+        print(f"  {d/1e3:8.1f} ms  {100*d/total:5.1f}%  x{k:<5d} {n[:70]}")
+    print_scope_tables(log_dir, steps, scopes)
+
+
+def profile_cell(workload, seed, dispatches=3):
+    """Trace ``dispatches`` dispatches of a benchmark cell through its
+    driver's run object and print the tables."""
+    from benchmarks import run as bench_run
+
+    cell = bench_run.load_cell(
+        bench_run.load_json(bench_run.ROOT, "BENCHMARK.json"), workload)
+    driver = cell["driver"]
+    r = getattr(driver, "Run", None) or driver.TrainScanRun
+    r = r(cell["config"], cell["traffic"], cell["limits"], seed)
+    print("set-up:", r.setup())
+    log_dir = os.path.join("chiprun_out", "trace-" + workload)
+    with profiler.trace(log_dir):
+        for _ in range(dispatches):
+            r.dispatch()
+    hybrid = "layer_types" in cell["config"]
+    print_trace(log_dir, dispatches * r.k,
+                HYBRID_STEP_SCOPES if hybrid else STEP_SCOPES)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--trace", action="store_true")
+    ap.add_argument("--cell", help="a cell of BENCHMARK.json: trace it as "
+                    "its driver runs it, and nothing else")
+    ap.add_argument("--seed", type=int, default=2_200_000_011)
     ap.add_argument("--vocab", type=int, default=8192)
     ap.add_argument("--d-model", type=int, default=512)
     ap.add_argument("--layers", type=int, default=8)
@@ -97,6 +135,8 @@ def main():
     ap.add_argument("--epochs", type=int, default=12)
     args = ap.parse_args()
     enable_compile_cache()
+    if args.cell:
+        return profile_cell(args.cell, args.seed)
     peak = device_peaks().bf16_flops
 
     from deeplearning4j_tpu.datasets.dataset import DataSet
@@ -136,14 +176,7 @@ def main():
         with profiler.trace(log_dir):
             for _ in range(3):  # three, to hold the gaps between them
                 net.fit_scan(None, args.batch, epochs=1, staged=staged)
-        rows = aggregate_trace(log_dir)
-        total = sum(d for _, d, _ in rows)
-        print(f"\ndevice self-time total: {total/1e3:.1f} ms "
-              f"over {len(rows)} op groups")
-        print("top 20 op groups:")
-        for n, d, k in rows[:20]:
-            print(f"  {d/1e3:8.1f} ms  {100*d/total:5.1f}%  x{k:<5d} {n[:70]}")
-        print_scope_tables(log_dir, 3 * args.steps)
+        print_trace(log_dir, 3 * args.steps)
 
 
 if __name__ == "__main__":

@@ -2,3 +2,4 @@
 benchmark, in ``benchmarks/tests/``)."""
 from benchmarks.tests.test_benchmark_harness import *  # noqa: F401,F403
 from benchmarks.tests.test_layer_readers import *  # noqa: F401,F403
+from benchmarks.tests.test_hybrid_cell import *  # noqa: F401,F403
