@@ -795,16 +795,19 @@ def _sharded_trainer(s: Smoke, axes, devices) -> None:
 
     # does every chip compute the whole batch's attention? Read the
     # Mosaic calls' operand shapes off the partitioned module: batch
-    # (and, under tp, heads) must arrive divided.
+    # (and, under tp, heads) must arrive divided. An operand is
+    # [rows, seq, heads-in-a-row * head]: folded copies hold one head a
+    # row, the projections' own layout all of a batch row's
     text = _fit_step_text(net, x, y)
+    head = c["d_model"] // c["heads"]
     folded = set()
     for line in text.split("\n"):
         if 'custom_call_target="tpu_custom_call"' in line \
                 and "flash_fwd" in line:
-            folded.update(int(m) for m in re.findall(
-                r"bf16\[(\d+),%d,\d+\]" % c["seq"], line))
+            folded.update(int(n) * (int(w) // head) for n, w in re.findall(
+                r"bf16\[(\d+),%d,(\d+)\]" % c["seq"], line))
     per_device = c["batch"] * c["heads"] // len(devices)
-    s.log("four", f"train {tag}: flash_fwd operands fold batch*heads = "
+    s.log("four", f"train {tag}: flash_fwd operands hold batch*heads = "
                   f"{sorted(folded)} per device (whole problem "
                   f"{c['batch'] * c['heads']}, a quarter {per_device})")
     if s.on_chip:

@@ -110,7 +110,9 @@ FEED_PADDED_BATCHES_COUNTER = "dl4j_feed_padded_batches_total"
 JIT_CACHE_MISS_COUNTER = "dl4j_jit_cache_miss_total"
 SCORE_SYNC_COUNTER = "dl4j_score_sync_total"
 # ops/flash_attention.py: calls traced, labeled path="resident" (one
-# program a row, the sequence in VMEM) or "streamed" (blocks in the grid);
+# program a row, the sequence in VMEM, on folded [b*h, t, d] copies),
+# "resident_packed" (the same kernels on the projections' own [b, t, h*d]
+# layout: no split, fold or unfold copy) or "streamed" (blocks in the grid);
 # the choice is made from the shapes while tracing, so it is counted there
 FLASH_PATH_COUNTER = "dl4j_flash_path_total"
 # ops/ssd.py: ssd_scan calls traced, labeled path="kernel" (ssd_fwd / ssd_bwd)

@@ -23,7 +23,8 @@ from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.base import LayerImpl, register_impl
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.attention import scaled_dot_product_attention
-from deeplearning4j_tpu.ops.flash_attention import flash_attention
+from deeplearning4j_tpu.ops.flash_attention import (flash_attention,
+                                                    flash_attention_qkv)
 from deeplearning4j_tpu.parallel.mesh import (current_sequence_mesh,
                                               device_collective)
 from deeplearning4j_tpu.parallel.ring_attention import ring_attention
@@ -88,6 +89,25 @@ def dispatch_attention(q, k, v, causal: bool, mask=None, mesh=None):
     if mesh is not None and mesh.size > 1 and mask is None:
         return _flash_per_device(q, k, v, causal, mesh)
     return flash_attention(q, k, v, causal=causal, mask=mask)
+
+
+def dispatch_qkv_attention(qkv, heads: int, causal: bool, mask=None,
+                           mesh=None):
+    """``dispatch_attention`` for a block that holds q, k and v as one
+    fused projection [b, t, 3 * heads * d] and wants [b, t, heads * d] for
+    its output projection. The plain one-device call hands the fused array
+    to the flash kernels as it lies (no split, no head fold where the
+    shapes allow: ``flash_attention_qkv``); a key mask, a sequence mesh, a
+    placement mesh or ``xla_attention()`` take the three thirds through
+    ``dispatch_attention``."""
+    b, t, features = qkv.shape
+    if not _FORCE_XLA and mask is None and current_sequence_mesh() is None \
+            and (mesh is None or mesh.size == 1):
+        return flash_attention_qkv(qkv, heads, causal=causal)
+    q, k, v = (z.reshape(b, t, heads, -1)
+               for z in jnp.split(qkv, 3, axis=-1))
+    return dispatch_attention(q, k, v, causal=causal, mask=mask,
+                              mesh=mesh).reshape(b, t, features // 3)
 
 
 @register_impl(L.AttentionLayer)

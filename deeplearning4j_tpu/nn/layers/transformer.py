@@ -21,8 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf import layers as L
-from deeplearning4j_tpu.nn.layers.attention import (dispatch_attention,
-                                                    xla_attention)
+from deeplearning4j_tpu.nn.layers.attention import (
+    dispatch_attention, dispatch_qkv_attention, xla_attention)
 from deeplearning4j_tpu.nn.layers.base import (
     LayerImpl, apply_dropout, register_impl)
 from deeplearning4j_tpu.nn.layers.moe import (
@@ -119,7 +119,6 @@ class TransformerBlockImpl(LayerImpl):
         if x.ndim != 3:
             raise ValueError(f"TransformerBlock needs [b, t, d], got {x.shape}")
         b, t, d = x.shape
-        h_count, hd = c.num_heads, c.n_out // c.num_heads
         # static scope names: the device trace reads each part of the
         # block under them (util/profiler.scope_seconds); JAX adds
         # jvp(...) / transpose(jvp(...)) for forward and backward
@@ -128,20 +127,16 @@ class TransformerBlockImpl(LayerImpl):
         with jax.named_scope("qkv_proj"):
             qkv = qmatmul(h, params, "Wqkv")
         with jax.named_scope("attention"):
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            shape = lambda z: z.reshape(b, t, h_count, hd)
-            q, k, v = shape(q), shape(k), shape(v)
             if self._slice_mesh is not None:
                 # sliced serving: heads are sharded over tp — the Pallas
                 # flash kernel cannot see the mesh, so stay on the XLA
                 # formulation GSPMD partitions per-head
                 with xla_attention():
-                    o = dispatch_attention(q, k, v, causal=c.causal,
-                                           mask=mask)
+                    o = dispatch_qkv_attention(qkv, c.num_heads,
+                                               causal=c.causal, mask=mask)
             else:
-                o = dispatch_attention(q, k, v, causal=c.causal, mask=mask,
-                                       mesh=self._mesh)
-            o = o.reshape(b, t, d)
+                o = dispatch_qkv_attention(qkv, c.num_heads, causal=c.causal,
+                                           mask=mask, mesh=self._mesh)
         with jax.named_scope("attn_out_proj"):
             attn = qmatmul(self._slice_replicate(o), params, "Wo")
         if train and self.dropout_rate > 0.0 and rng is not None:

@@ -60,7 +60,8 @@ Params = Dict[str, Dict[str, jnp.ndarray]]
 #: the ``jax.named_scope`` names of the compiled train step, all static
 #: strings: the step's own (``_make_train_step``), the head's and the
 #: loss's (nn/layers/feedforward.py), the block's and the embedding's
-#: (nn/layers/transformer.py), the head fold (ops/flash_attention.py).
+#: (nn/layers/transformer.py), the head fold (ops/flash_attention.py: only
+#: where the shapes keep the flash kernels off the projections' layout).
 #: Readers of a device trace (util/profiler.scope_seconds) take this list
 STEP_SCOPES = ("grad_norm", "optimizer_update", "lm_head", "loss", "embed",
                "ln1", "qkv_proj", "attention", "attn_out_proj", "ln2",

@@ -7,26 +7,79 @@ dropped to cuDNN helpers (``CudnnConvolutionHelper.java:51``) for its
 hot ops, the TPU build drops to Pallas for its hottest op.
 
 Two sets of kernels, chosen from the shapes by ``flash_path`` (a pure
-function of ``(tq, tk, d, dtype)``; ``dl4j_flash_path_total{path=}``
+function of ``(tq, tk, d, dtype, heads)``; ``dl4j_flash_path_total{path=}``
 counts the choice once a traced call, and a device trace shows it as
 ``flash_dq_dkv`` against ``flash_dq`` + ``flash_dkv``):
 
 **Resident** — self-attention whose row fits VMEM (1k / head 64, 2k /
-head 128, every shorter length): one program a (batch, head) row, the
-row's whole q, k, v (and dO, lse, delta) in VMEM, the block loop as
-straight-line code in the body. Dead blocks are not in the loop, only
-diagonal blocks carry the iota mask, the forward takes a plain softmax
-over the keys a block of queries sees (no running max, no rescaling),
-and the backward is ONE kernel that makes the scores once for dq, dk
-and dv (5 products and 1 exp chain a block; the streamed pair makes 7
-and 2). Readings on a v5e (PR 28, device self time from a trace, ms
-a call of bh rows; ``PERF.md`` section 6 has the step's):
+head 128, every shorter length): one program a row, the row's whole q,
+k, v (and dO, o, lse) in VMEM, the block loop as straight-line code in
+the body. Dead blocks are not in the loop, only diagonal blocks carry
+the iota mask, the forward takes a plain softmax over the keys a block
+of queries sees (no running max, no rescaling), and the backward is ONE
+kernel that makes the scores once for dq, dk and dv (5 products and 1
+exp chain a block; the streamed pair makes 7 and 2) and delta =
+rowsum(dO·O) itself. The softmax scale is folded into q inside the body.
+
+  A row is a COLUMN BLOCK of [b, t, features] arrays, addressed by the
+  BlockSpecs (grid ``(b, column blocks)``). **Packed**
+  (``path="resident_packed"``, PR 30): the arrays are the projections'
+  own. Where the heads are whole 128-lane tiles of ``[b, t, h*d]`` (d a
+  multiple of 128, or d a divisor of it and ``h*d % 128 == 0``) a
+  program takes ``[1, t, 128]`` (one head of 128, or a PAIR of heads of
+  64 side by side; a d-wide slab of 64 is not a legal Mosaic block, a
+  pair is a whole tile) straight from the fused QKV projection's
+  ``[b, t, 3*h*d]`` output at three column offsets
+  (``flash_attention_qkv``), writes o as the ``[b, t, h*d]`` the output
+  projection contracts over, and hands dq, dk, dv back as the three
+  thirds of ONE ``[b, t, 3*h*d]`` array: no ``jnp.split``, no scaled
+  copy of q, no ``[b, t, h, d] -> [b*h, t, d]`` fold, no unfold and no
+  concatenation exist, forward or backward. Heads that share 128 lanes
+  are never sliced apart (``_own_lanes``): q (forward) or k and v
+  (backward) with the other head's lanes zeroed contract to this head's
+  scores exactly, products against the unmasked v, dO and q are kept in
+  this head's lanes by one select, and every load and store is a whole
+  tile wide; the MXU passes are the folded ones (a 64-deep contraction
+  or a 64-wide output fills half a 128 x 128 pass either way).
+  ``flash_attention(q, k, v)`` in the [b, t, h, d] convention reaches
+  the same kernels by a free reshape. **Folded** (``path="resident"``):
+  head counts that do not pair (h = 3 at d = 64) keep the
+  ``fold_heads`` / ``unfold_heads`` copies, and the same body runs on
+  the ``[b*h, t, d]`` array's one head.
+
+  Readings on a v5e (device self time from a trace, ms a call).
+  Kernels alone on folded rows (PR 28):
 
     bh x t x d        streamed fwd / dq+dkv   resident fwd / dq_dkv
     128 x 1024 x 64    0.455 / 1.404           0.302 / 0.734
     24 x 2048 x 128    0.302 / 0.756           0.188 / 0.419
     512 x 256 x 64     0.629 / 0.732           0.286 / 0.435
     1024 x 128 x 64    0.618 / 1.012           0.446 / 0.607
+
+  One attention sublayer, ``x @ Wqkv`` -> attention -> ``@ Wo``,
+  forward and backward, so that the copies round the kernels count
+  (PR 30, ``scripts/profile_flash.py``; kernels fwd / dq_dkv, then
+  the whole sublayer; "PR 28" is that commit's file behind the split):
+
+    b x h x t x d       PR 28, folded           folded, this body      packed
+    8 x 16 x 1024 x 64  0.303 / 0.734   2.646   0.302 / 0.737  2.575   0.337 / 0.747  2.081
+    32 x 16 x 256 x 64  0.299 / 0.448   2.371   0.297 / 0.439  2.211   0.265 / 0.456  1.722
+    2 x 12 x 2048 x 128 0.187 / 0.418   1.919   0.188 / 0.422  1.878   0.201 / 0.466  1.802
+
+  The pair program's kernels are 0 to 12% slower than the folded ones
+  (a select a head and a block; column blocks are fetched in 4 KB
+  runs) and 11% faster at 256, where half the programs is half the
+  per-program cost; what the sublayer gains is the ``copy`` (0.50,
+  0.49, 0.15 ms) and the ``fusion`` passes (the split, q x 1/sqrt(d),
+  delta) around them. Three [b, t, h*d] gradients and XLA's
+  concatenation (three ``dynamic-update-slice`` fusions) read the
+  backward kernel 0.706 / 0.350 / 0.448 ms and the whole step of
+  the three GPT cells 1.05 / 0.40 / 1.05 ms slower than the one
+  [b, t, 3*h*d] output in three grid steps (0.4-0.75 us a program),
+  which is what is built: a program computes in its first step,
+  writes dq, and hands dk and dv out of VMEM in two more; its
+  operands are asked for one step early, so their fetch runs under
+  the previous program's computation.
 
   The in-body block is 512 for both kernels. Block 256 read 0.695 ms
   in the head-64 backward (5% faster) and level elsewhere (0.423 at
@@ -42,7 +95,8 @@ a call of bh rows; ``PERF.md`` section 6 has the step's):
   it (0.333 against 0.302 ms, and 1.2–3.6 ms a step of ``reduce``).
   Both wrappers are ``jax.jit``s, so a model's layers share one traced
   and lowered body: unrolled bodies traced once a layer cost the first
-  dispatch 3.3 s at 2k x 18 layers on the chip's host.
+  dispatch 3.3 s at 2k x 18 layers on the chip's host. (PR 28's
+  readings, on folded rows.)
 
 **Streamed** — everything else (cross-length calls, lengths over the
 budget: 4k and up at head 128, the 16k / 32k long-context path), as
@@ -77,8 +131,9 @@ before PR 28:
   BASELINE.md "Flash-attention forward roofline". None of that was
   read at 1k or 2k, where this path no longer runs.
 
-Both paths: the softmax scale is folded into q ONCE in XLA before the
-kernel; the backward builds the score block TRANSPOSED ([keys,
+Both paths: the softmax scale is folded into q ONCE (the streamed path
+in XLA before the kernel, the resident one in the body, rounded the
+same); the backward builds the score block TRANSPOSED ([keys,
 queries]) so the per-query ``lse`` and ``delta = rowsum(dO·O)`` enter as
 [1, queries] row broadcasts with no relayouts; all matmuls run on the
 MXU in f32 accumulation (``preferred_element_type``) from native-bf16
@@ -401,20 +456,27 @@ _flash_streamed.defvjp(_flash_streamed_fwd, _flash_streamed_bwd)
 
 # ------------------------------------------------- sequence-resident path
 #
-# One program a (batch, head) row of a self-attention call (tq == tk): the
-# row's whole q, k, v (and dO, lse, delta in the backward) sit in VMEM and
-# the block loop is inside the kernel body. Dead blocks are not in the
-# loop, only the diagonal blocks carry the iota mask, and the backward
-# makes the scores once for dq, dk and dv together. Shares no kernel logic
-# with the streamed path: that one wants the block loop in the grid.
+# One program a row of a self-attention call (tq == tk): the row's whole q,
+# k, v (and dO, lse, delta in the backward) sit in VMEM and the block loop
+# is inside the kernel body. Dead blocks are not in the loop, only the
+# diagonal blocks carry the iota mask, and the backward makes the scores
+# once for dq, dk and dv together. Shares no kernel logic with the streamed
+# path: that one wants the block loop in the grid.
+#
+# A row is a column block of [b, t, features] arrays, which the BlockSpecs
+# address: ``heads * d`` columns from a column offset an operand,
+# ``_program_lanes`` of them a program. Packed, that is the layout the
+# projections make (whole 128-lane tiles: one head of 128 lanes or more, or
+# 128 // d narrower heads side by side); folded, it is the one head of a
+# [b*h, t, d] copy. One body serves both.
 
 #: the in-body block, both kernels (the module docstring has the chip's
 #: readings of 128, 256 and 512)
 _RESIDENT_BLOCK = 512
 #: what a row's operands, accumulators and block temporaries may take of
 #: VMEM for the resident kernels to be chosen: by ``_resident_bytes`` 2k /
-#: head 128 takes 16.3 MiB, 4k / head 128 26.5 MiB, 16k / head 128 88 MiB
-_RESIDENT_BUDGET = 24 * 2 ** 20
+#: head 128 takes 18.7 MiB, 4k / head 128 31.4 MiB, 16k / head 128 107 MiB
+_RESIDENT_BUDGET = 30 * 2 ** 20
 _SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
 
 
@@ -423,41 +485,112 @@ def _resident_block(t: int) -> int:
     the widest divisor of at least 128 (a narrower one would unroll into
     hundreds of blocks); 0 where there is none."""
     if t <= _RESIDENT_BLOCK:
-        return t
+        return t if t % 8 == 0 else 0
     b = _pick_block(t, _RESIDENT_BLOCK)
     return b if b >= 128 else 0
 
 
 def _resident_bytes(t: int, d: int, itemsize: int) -> int:
     """VMEM the resident backward (the larger of the two kernels) holds
-    for one row: inputs and outputs double-buffered, the float32
+    for one program: inputs and outputs double-buffered, the float32
     accumulators, the block temporaries. A head narrower than 128 lanes
-    is padded to them."""
+    is padded to them, or shares them with its neighbours."""
     lanes = -(-d // 128) * 128
-    io = 2 * (7 * t * lanes * itemsize      # q, k, v, dO; dq, dk, dv
-              + 2 * 8 * t * 4)              # lse, delta
-    acc = 3 * t * lanes * 4
+    io = 2 * (8 * t * lanes * itemsize      # q, k, v, dO, o; dq, dk, dv
+              + 8 * t * 4)                  # lse
+    held = (3 * t * lanes * itemsize        # q scaled; dk, dv awaiting a step
+            + 8 * t * 4                     # delta
+            + 3 * t * lanes * 4)            # dq, dk, dv in float32
     tmp = 6 * _resident_block(t) ** 2 * 4   # sT, pT, dPT, dsT and their casts
-    return io + acc + tmp
+    return io + held + tmp
 
 
-def flash_path(tq: int, tk: int, d: int, dtype) -> str:
+def _packs(heads: int, d: int) -> bool:
+    """Whether ``heads`` heads of width ``d`` side by side are whole
+    128-lane column blocks of a [b, t, heads * d] array."""
+    return d % 128 == 0 or (128 % d == 0 and (heads * d) % 128 == 0)
+
+
+def _program_lanes(heads: int, d: int) -> int:
+    """Columns one program holds: a head of a multiple of 128 lanes, 128
+    lanes of narrower heads, or the one head of a folded array."""
+    assert heads == 1 or _packs(heads, d), (heads, d)
+    return d if heads == 1 or d % 128 == 0 else 128
+
+
+def flash_path(tq: int, tk: int, d: int, dtype, heads: int = 0) -> str:
     """Which kernels ``flash_attention`` runs at these shapes, a pure
-    function of them: "resident" for self-attention lengths that split
-    into in-body blocks and whose row fits the VMEM budget, "streamed" for
-    everything else (cross-length calls, 16k and 32k), as before."""
+    function of them. The resident kernels for self-attention lengths that
+    split into in-body blocks and whose row fits the VMEM budget: on the
+    projections' own layout ("resident_packed") where ``heads`` heads of
+    ``d`` are whole 128-lane column blocks, on folded [b*h, t, d] copies
+    ("resident") where they are not or ``heads`` is not given. "streamed"
+    for everything else (cross-length calls, 16k and 32k), as before."""
     fits = tq == tk and _resident_block(tq) and _resident_bytes(
         tq, d, jnp.dtype(dtype).itemsize) <= _RESIDENT_BUDGET
-    return "resident" if fits else "streamed"
+    if not fits:
+        return "streamed"
+    return "resident_packed" if heads and _packs(heads, d) else "resident"
 
 
-def _row_params(t, d, dtype):
-    """Compiler parameters of a resident kernel: rows are independent, and
-    the scoped VMEM limit follows the same arithmetic as the rule."""
+def _resident_call(kernel, name, operands, cols, rows, outs, heads, d,
+                   scratch, interpret):
+    """One resident kernel over grid (b, column blocks): ``operands`` are
+    [b, t, features] arrays whose ``heads * d`` columns start at ``cols``,
+    ``rows`` [b, heads, 1, t] float32 arrays (lse), ``outs`` what it
+    returns: "slab" for a [b, t, heads * d] array, "rows" for such rows,
+    or "thirds" alone for ONE [b, t, 3 * heads * d] array whose three
+    thirds a program writes in three grid steps of their own."""
+    n, t = operands[0].shape[:2]
+    dtype = operands[0].dtype
+    lanes = _program_lanes(heads, d)
+    blocks = heads * d // lanes
+    thirds = outs == "thirds"
+
+    def program(i, j, *step):
+        """The program whose operands grid step (i, j[, step]) wants."""
+        if not step:
+            return i, j
+        # a program computes in its first step and hands a third out in
+        # each: its operands are asked for from the step after the program
+        # before computed, so their fetch runs under that computation and
+        # not in the two short steps that only copy
+        ahead = jnp.minimum(i * blocks + j + (step[0] > 0), n * blocks - 1)
+        return ahead // blocks, ahead % blocks
+
+    def slab(col):
+        def index(*ids):
+            i, j = program(*ids)
+            return i, 0, col // lanes + j
+        return pl.BlockSpec((1, t, lanes), index, **_VMEM)
+
+    rowspec = pl.BlockSpec((1, lanes // d, 1, t),
+                           lambda *ids: (*program(*ids), 0, 0), **_VMEM)
+    if thirds:
+        out_specs = pl.BlockSpec(
+            (1, t, lanes), lambda i, j, step: (i, 0, step * blocks + j),
+            **_VMEM)
+        out_shape = jax.ShapeDtypeStruct((n, t, 3 * heads * d), dtype)
+    else:
+        kinds = {"slab": (slab(0), jax.ShapeDtypeStruct((n, t, heads * d),
+                                                        dtype)),
+                 "rows": (rowspec, jax.ShapeDtypeStruct((n, heads, 1, t),
+                                                        jnp.float32))}
+        out_specs, out_shape = zip(*(kinds[out] for out in outs))
+    # programs are independent, and the scoped VMEM limit follows the same
+    # arithmetic as the rule
     need = 2 * _resident_bytes(t, d, jnp.dtype(dtype).itemsize)
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel",),
-        vmem_limit_bytes=max(need, _SCOPED_VMEM_DEFAULT))
+    return pl.pallas_call(
+        kernel,
+        grid=(n, blocks) + (3,) * thirds,
+        in_specs=[slab(col) for col in cols] + [rowspec] * len(rows),
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+            + ("arbitrary",) * thirds,
+            vmem_limit_bytes=max(need, _SCOPED_VMEM_DEFAULT)),
+        interpret=interpret, name=name,
+    )(*operands, *rows)
 
 
 _NT = (((1,), (1,)), ((), ()))   # a · bᵀ
@@ -477,135 +610,222 @@ def _diagonal_keep(block, q_dim):
     return qi >= ki
 
 
+def _own_lanes(lanes, d):
+    """For each head of a program the [1, lanes] mask of its lanes, None
+    for a head that has the program to itself. Heads that share 128 lanes
+    are never sliced apart: an operand with the other heads' lanes zeroed
+    contracts to this head's product exactly (the MXU pass is as deep as a
+    64-wide one), a product with such an operand on the right lands in this
+    head's lanes and leaves the others zero, and every load and store is a
+    whole tile wide."""
+    if lanes == d:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return [(lane >= a * d) & (lane < (a + 1) * d) for a in range(lanes // d)]
+
+
+def _only(own, x):
+    return x if own is None else jnp.where(own, x, jnp.zeros_like(x))
+
+
 def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                         *, causal, block):
-    t = q_ref.shape[1]
+                         *, scale, causal, block, d):
+    t, lanes = q_ref.shape[1:]
     for q0 in range(0, t, block):
         rows = slice(q0, q0 + block)
-        q = q_ref[0, rows, :]  # pre-scaled, as in the streamed forward
-        # causal: the keys before this block of queries need no mask, the
-        # diagonal block does; otherwise every key, unmasked
-        parts = []
-        if q0 or not causal:
-            cols = slice(0, q0 if causal else t)
-            parts.append((cols, _dot(q, k_ref[0, cols, :], _NT)))
-        if causal:
-            s = _dot(q, k_ref[0, rows, :], _NT)
-            parts.append((rows, jnp.where(_diagonal_keep(block, 0), s,
-                                          _NEG_INF)))
-        # the whole key row is here: a plain softmax, no running max
-        m = functools.reduce(jnp.maximum, [
-            jnp.max(s, axis=1, keepdims=True) for _, s in parts])
-        ps = [(cols, jnp.exp(s - m)) for cols, s in parts]
-        denom = jnp.maximum(
-            sum(jnp.sum(p, axis=1, keepdims=True) for _, p in ps), 1e-30)
-        acc = sum(_dot(p.astype(v_ref.dtype), v_ref[0, cols, :], _NN)
-                  for cols, p in ps)
-        o_ref[0, rows, :] = (acc / denom).astype(o_ref.dtype)
-        # as a lane-major row, the layout the backward reads: a [t, 1]
-        # column costs a 128-lane tile a value, in the kernel's store and
-        # again in the XLA pass that has to repack it (.T, not a reshape:
-        # Mosaic's relayout for that one read 0.06 ms a call slower)
-        lse_ref[0, :, rows] = (m + jnp.log(denom)).T
+        # the softmax scale folded into q once, rounded as a pass of XLA's
+        # before the kernel would round it
+        qs = (q_ref[0, rows, :] * scale).astype(q_ref.dtype)
+        out = None
+        for a, own in enumerate(_own_lanes(lanes, d)):
+            q = _only(own, qs)
+            # causal: the keys before this block of queries need no mask,
+            # the diagonal block does; otherwise every key, unmasked
+            parts = []
+            if q0 or not causal:
+                cols = slice(0, q0 if causal else t)
+                parts.append((cols, _dot(q, k_ref[0, cols, :], _NT)))
+            if causal:
+                s = _dot(q, k_ref[0, rows, :], _NT)
+                parts.append((rows, jnp.where(_diagonal_keep(block, 0), s,
+                                              _NEG_INF)))
+            # the whole key row is here: a plain softmax, no running max
+            m = functools.reduce(jnp.maximum, [
+                jnp.max(s, axis=1, keepdims=True) for _, s in parts])
+            ps = [(cols, jnp.exp(s - m)) for cols, s in parts]
+            denom = jnp.maximum(
+                sum(jnp.sum(p, axis=1, keepdims=True) for _, p in ps), 1e-30)
+            # against the unmasked v: this head's lanes are its output
+            acc = sum(_dot(p.astype(v_ref.dtype), v_ref[0, cols, :], _NN)
+                      for cols, p in ps)
+            out = acc / denom if out is None else jnp.where(
+                own, acc / denom, out)
+            # as a lane-major row, the layout the backward reads: a [t, 1]
+            # column costs a 128-lane tile a value, in the kernel's store
+            # and again in the XLA pass that has to repack it (.T, not a
+            # reshape: Mosaic's relayout for that one read 0.06 ms a call
+            # slower)
+            lse_ref[0, a, :, rows] = (m + jnp.log(denom)).T
+        o_ref[0, rows, :] = out.astype(o_ref.dtype)
 
 
 # jitted, both wrappers: every layer of a model calls with the same shapes,
 # so the unrolled body is traced and lowered once a program, not once a
 # layer; XLA inlines the calls
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _resident_fwd(q, k, v, causal: bool, block: int, interpret: bool):
-    """q,k,v: [bh, t, d] -> (o, lse[bh, 1, t])."""
-    bh, t, d = q.shape
-    q = (q * (1.0 / d ** 0.5)).astype(q.dtype)  # fold softmax scale once
-    spec = pl.BlockSpec((1, t, d), lambda b: (b, 0, 0), **_VMEM)
-    return pl.pallas_call(
-        functools.partial(_resident_fwd_kernel, causal=causal, block=block),
-        grid=(bh,),
-        in_specs=[spec, spec, spec],
-        out_specs=[spec,
-                   pl.BlockSpec((1, 1, t), lambda b: (b, 0, 0), **_VMEM)],
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)],
-        compiler_params=_row_params(t, d, q.dtype), interpret=interpret,
-        name="flash_fwd",
-    )(q, k, v)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _resident_fwd(q, k, v, cols, heads: int, d: int, causal: bool,
+                  block: int, interpret: bool):
+    """q, k, v: [b, t, features] arrays (one fused projection three times,
+    or three arrays) with ``heads`` heads of ``d`` from the column offsets
+    ``cols`` -> (o [b, t, heads * d], lse [b, heads, 1, t])."""
+    kernel = functools.partial(_resident_fwd_kernel, scale=1.0 / d ** 0.5,
+                               causal=causal, block=block, d=d)
+    return _resident_call(kernel, "flash_fwd", (q, k, v), cols, (),
+                          ("slab", "rows"), heads, d, (), interpret)
 
 
-def _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-                         dq_ref, dk_ref, dv_ref, dq_acc,
-                         *, scale, causal, block):
-    """dq, dk and dv of one row in one pass: per live block the scores
-    once, five products and one exp chain (the streamed pair makes seven
-    and two). Same algebra and precision as ``_bwd_block``."""
-    t, d = q_ref.shape[1:]
+def _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                         dq_ref, dk_ref, dv_ref, qs_ref, dlt_ref, dq_acc,
+                         *, scale, causal, block, d):
+    """dq, dk and dv of one program's heads in one pass: per live block the
+    scores once, five products and one exp chain (the streamed pair makes
+    seven and two). Same algebra and precision as ``_bwd_block``."""
+    t, lanes = q_ref.shape[1:]
+    heads = _own_lanes(lanes, d)
+    # q with the scale folded in, rounded as the forward rounds it: every
+    # key block reads it again
+    qs_ref[:] = (q_ref[0] * scale).astype(qs_ref.dtype)
     dq_acc[:] = jnp.zeros_like(dq_acc)
+    # delta = rowsum(dO ∘ O) a head, as the [1, t] row lse is: made here, a
+    # pass of XLA's over dO and O would have to transpose its sums
+    for q0 in range(0, t, block):
+        rows = slice(q0, q0 + block)
+        prod = do_ref[0, rows, :].astype(jnp.float32) \
+            * o_ref[0, rows, :].astype(jnp.float32)
+        for a, own in enumerate(heads):
+            dlt_ref[a, :, rows] = jnp.sum(_only(own, prod), axis=1,
+                                          keepdims=True).T
     for k0 in range(0, t, block):
         keys = slice(k0, k0 + block)
-        k, v = k_ref[0, keys, :], v_ref[0, keys, :]
-        dk = dv = jnp.zeros((block, d), jnp.float32)
-        # causal: the diagonal block, then the queries after it
-        for q0 in range(k0 if causal else 0, t, block):
-            rows = slice(q0, q0 + block)
-            qs, do = q_ref[0, rows, :], do_ref[0, rows, :]
-            sT = _dot(k, qs, _NT)                    # [keys, queries]
-            if causal and q0 == k0:
-                sT = jnp.where(_diagonal_keep(block, 1), sT, _NEG_INF)
-            # lse and delta are [1, t] rows: they broadcast over the keys
-            pT = jnp.exp(sT - lse_ref[0, :, rows])
-            dsT = pT * (_dot(v, do, _NT) - dlt_ref[0, :, rows])
-            pT, dsT = pT.astype(do.dtype), dsT.astype(qs.dtype)
-            dv += _dot(pT, do, _NN)
-            dk += _dot(dsT, qs, _NN)
-            dq_acc[rows, :] += _dot(dsT, k, _TN)
-        dk_ref[0, keys, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, keys, :] = dv.astype(dv_ref.dtype)
+        dk_out = dv_out = None
+        for a, own in enumerate(heads):
+            # this head's k and v alone: the scores and dP contract over
+            # its lanes, and dq lands in them
+            k, v = _only(own, k_ref[0, keys, :]), _only(own, v_ref[0, keys, :])
+            dk = dv = jnp.zeros((block, lanes), jnp.float32)
+            # causal: the diagonal block, then the queries after it
+            for q0 in range(k0 if causal else 0, t, block):
+                rows = slice(q0, q0 + block)
+                qs, do = qs_ref[rows, :], do_ref[0, rows, :]
+                sT = _dot(k, qs, _NT)                    # [keys, queries]
+                if causal and q0 == k0:
+                    sT = jnp.where(_diagonal_keep(block, 1), sT, _NEG_INF)
+                # lse and delta are [1, t] rows: they broadcast over the keys
+                pT = jnp.exp(sT - lse_ref[0, a, :, rows])
+                dsT = pT * (_dot(v, do, _NT) - dlt_ref[a, :, rows])
+                pT, dsT = pT.astype(do.dtype), dsT.astype(qs.dtype)
+                # against the unmasked dO and q: this head's lanes are its
+                # dv and dk
+                dv += _dot(pT, do, _NN)
+                dk += _dot(dsT, qs, _NN)
+                dq_acc[rows, :] += _dot(dsT, k, _TN)
+            dk_out = dk if dk_out is None else jnp.where(own, dk, dk_out)
+            dv_out = dv if dv_out is None else jnp.where(own, dv, dv_out)
+        dk_ref[0, keys, :] = dk_out.astype(dk_ref.dtype)
+        dv_ref[0, keys, :] = dv_out.astype(dv_ref.dtype)
     dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
-def _resident_bwd(q, k, v, o, lse, g, causal: bool, block: int,
-                  interpret: bool):
-    bh, t, d = q.shape
-    scale = 1.0 / (d ** 0.5)
-    q = (q * scale).astype(q.dtype)  # pre-scale once; dq re-scales at the end
-    # delta = rowsum(dO ∘ O) in one fused XLA pass, as a [bh, 1, t] row
-    # like lse
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).reshape(bh, 1, t)
-    spec = pl.BlockSpec((1, t, d), lambda b: (b, 0, 0), **_VMEM)
-    rowspec = pl.BlockSpec((1, 1, t), lambda b: (b, 0, 0), **_VMEM)
-    return pl.pallas_call(
-        functools.partial(_resident_bwd_kernel, scale=scale, causal=causal,
-                          block=block),
-        grid=(bh,),
-        in_specs=[spec, spec, spec, spec, rowspec, rowspec],
-        out_specs=[spec, spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), x.dtype)
-                   for x in (q, k, v)],
-        scratch_shapes=[_scratch((t, d))],
-        compiler_params=_row_params(t, d, q.dtype), interpret=interpret,
-        name="flash_dq_dkv",
-    )(q, k, v, g, lse, delta)
+def _resident_bwd_thirds_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                                out_ref, dk_ref, dv_ref, *scratch, **static):
+    """The same pass with dq, dk and dv as the three thirds of one
+    [b, t, 3 * heads * d] array, the layout the fused projection's backward
+    contracts over: computed in the program's first grid step, dq to the
+    output and dk and dv to VMEM, which the next two steps hand out."""
+    step = pl.program_id(2)
+
+    @pl.when(step == 0)
+    def _compute():
+        _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                             out_ref, dk_ref, dv_ref, *scratch, **static)
+
+    @pl.when(step == 1)
+    def _dk():
+        out_ref[:] = dk_ref[:]
+
+    @pl.when(step == 2)
+    def _dv():
+        out_ref[:] = dv_ref[:]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_resident(q, k, v, causal, interpret):
-    return _flash_resident_fwd(q, k, v, causal, interpret)[0]
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _resident_bwd(q, k, v, o, lse, g, cols, heads: int, causal: bool,
+                  block: int, interpret: bool, thirds: bool = False):
+    """-> (dq, dk, dv), each [b, t, heads * d], or with ``thirds`` the one
+    [b, t, 3 * heads * d] array that holds them side by side."""
+    t, d = o.shape[1], o.shape[2] // heads
+    lanes = _program_lanes(heads, d)
+    static = dict(scale=1.0 / d ** 0.5, causal=causal, block=block, d=d)
+    scratch = (pltpu.VMEM((t, lanes), q.dtype), _scratch((lanes // d, 1, t)),
+               _scratch((t, lanes)))
+    if thirds:
+        kernel = functools.partial(_resident_bwd_thirds_kernel, **static)
+        scratch = (pltpu.VMEM((1, t, lanes), q.dtype),) * 2 + scratch
+    else:
+        kernel = functools.partial(_resident_bwd_kernel, **static)
+    return _resident_call(
+        kernel, "flash_dq_dkv", (q, k, v, g, o), cols + (0, 0), (lse,),
+        "thirds" if thirds else ("slab",) * 3, heads, d, scratch, interpret)
 
 
-def _flash_resident_fwd(q, k, v, causal, interpret):
-    o, lse = _resident_fwd(q, k, v, causal, _resident_block(q.shape[1]),
-                           interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_resident(q, k, v, heads, causal, interpret):
+    """q, k, v: [b, t, heads * d] each (heads = 1: folded [b*h, t, d])."""
+    return _flash_resident_fwd(q, k, v, heads, causal, interpret)[0]
+
+
+def _flash_resident_fwd(q, k, v, heads, causal, interpret):
+    o, lse = _resident_fwd(q, k, v, (0, 0, 0), heads, q.shape[2] // heads,
+                           causal, _resident_block(q.shape[1]), interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_resident_bwd(causal, interpret, res, g):
+def _flash_resident_bwd(heads, causal, interpret, res, g):
     q, k, v, o, lse = res
-    return _resident_bwd(q, k, v, o, lse, g, causal,
-                         _resident_block(q.shape[1]), interpret)
+    return tuple(_resident_bwd(q, k, v, o, lse, g, (0, 0, 0), heads, causal,
+                               _resident_block(q.shape[1]), interpret))
 
 
 _flash_resident.defvjp(_flash_resident_fwd, _flash_resident_bwd)
+
+
+def _qkv_cols(qkv):
+    width = qkv.shape[2] // 3
+    return (0, width, 2 * width)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _flash_resident_qkv(qkv, heads, causal, interpret):
+    """qkv: a fused projection's [b, t, 3 * heads * d] output, read where it
+    lies: q, k and v are its three column offsets, and no split exists."""
+    return _flash_resident_qkv_fwd(qkv, heads, causal, interpret)[0]
+
+
+def _flash_resident_qkv_fwd(qkv, heads, causal, interpret):
+    o, lse = _resident_fwd(qkv, qkv, qkv, _qkv_cols(qkv), heads,
+                           qkv.shape[2] // (3 * heads), causal,
+                           _resident_block(qkv.shape[1]), interpret)
+    return o, (qkv, o, lse)
+
+
+def _flash_resident_qkv_bwd(heads, causal, interpret, res, g):
+    qkv, o, lse = res
+    return (_resident_bwd(qkv, qkv, qkv, o, lse, g, _qkv_cols(qkv), heads,
+                          causal, _resident_block(qkv.shape[1]), interpret,
+                          True),)
+
+
+_flash_resident_qkv.defvjp(_flash_resident_qkv_fwd, _flash_resident_qkv_bwd)
 
 
 def flash_attention(
@@ -643,19 +863,51 @@ def flash_attention(
         return scaled_dot_product_attention(q, k, v, causal=causal, mask=mask)
     if interpret is None:
         interpret = pallas_interpret()
+    path = flash_path(tq, tk, d, q.dtype, heads=h)
+    _count_path(path)
+    if path == "resident_packed":
+        # the projections' layout is the kernels': free reshapes, no copy
+        pack = lambda z: z.reshape(b, tq, h * d)
+        return _flash_resident(pack(q), pack(k), pack(v), h, causal,
+                               interpret).reshape(b, tq, h, d)
     fold = lambda z: z.transpose(0, 2, 1, 3).reshape(b * h, z.shape[1], d)
     # scoped so that the [b, t, h, d] <-> [b*h, t, d] copies have an
     # owner in the device trace, apart from the kernels
     with jax.named_scope("fold_heads"):
         q, k, v = fold(q), fold(k), fold(v)
-    path = flash_path(tq, tk, d, q.dtype)
     if path == "resident":
-        o = _flash_resident(q, k, v, causal, interpret)
+        o = _flash_resident(q, k, v, 1, causal, interpret)
     else:
         o = _flash_streamed(q, k, v, causal, bq, bk, interpret)
+    with jax.named_scope("unfold_heads"):
+        return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+
+
+def _count_path(path: str) -> None:
     # decided while tracing, so counted there: nothing in the compiled step
     get_registry().counter(
         FLASH_PATH_COUNTER, "flash_attention calls traced, by the kernels "
         "their shapes chose", path=path).inc()
-    with jax.named_scope("unfold_heads"):
-        return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+
+
+def flash_attention_qkv(
+    qkv: jnp.ndarray,  # [b, t, 3 * heads * d]: q, k, v side by side
+    heads: int,
+    causal: bool = False,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """Self-attention straight from a fused projection's output, to the
+    [b, t, heads * d] array the output projection contracts over. Where the
+    shapes choose the packed resident kernels (``flash_path``) they read q,
+    k and v where they lie and no split, fold or unfold copy is made;
+    everywhere else this is ``flash_attention`` of the three thirds."""
+    b, t, features = qkv.shape
+    d = features // (3 * heads)
+    if flash_path(t, t, d, qkv.dtype, heads=heads) == "resident_packed":
+        _count_path("resident_packed")
+        if interpret is None:
+            interpret = pallas_interpret()
+        return _flash_resident_qkv(qkv, heads, causal, interpret)
+    q, k, v = (z.reshape(b, t, heads, d) for z in jnp.split(qkv, 3, axis=-1))
+    return flash_attention(q, k, v, causal=causal,
+                           interpret=interpret).reshape(b, t, heads * d)

@@ -21,6 +21,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
+from deeplearning4j_tpu.monitor import (FLASH_PATH_COUNTER, SSD_PATH_COUNTER,
+                                        get_registry)
 from deeplearning4j_tpu.nn.multilayer import HYBRID_STEP_SCOPES, STEP_SCOPES
 from deeplearning4j_tpu.util import profiler
 from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
@@ -109,6 +111,11 @@ def profile_cell(workload, seed, dispatches=3):
     r = getattr(driver, "Run", None) or driver.TrainScanRun
     r = r(cell["config"], cell["traffic"], cell["limits"], seed)
     print("set-up:", r.setup())
+    # one tick a traced call, by the path its shapes chose
+    print("kernels chosen while tracing:", {
+        f"{name}{dict(labels)}": metric.value
+        for name in (FLASH_PATH_COUNTER, SSD_PATH_COUNTER)
+        for labels, metric in get_registry().family(name).items()})
     log_dir = os.path.join("chiprun_out", "trace-" + workload)
     with profiler.trace(log_dir):
         for _ in range(dispatches):

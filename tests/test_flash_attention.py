@@ -5,8 +5,11 @@ oracle (same doctrine as ring attention); the kernels must match it in
 forward AND gradients, causal and not, square and cross-length. There
 are two sets of kernels, chosen from the shapes: ``resident`` (one
 program a row, the block loop in the body) and ``streamed`` (the block
-loop in the grid). The cases name their path and reach it through the
-two private entry points; the public function's choice is tested apart.
+loop in the grid); the resident ones read folded [b*h, t, d] copies
+(``resident``) or the projections' own [b, t, h*d] layout
+(``resident_packed``: heads as 128-lane column blocks, two heads of 64 a
+program). The cases name their path and reach it through the private
+entry points; the public function's choice is tested apart.
 """
 
 import importlib
@@ -24,13 +27,19 @@ from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
 # the module itself: ``ops/__init__`` re-exports the function under its name
 FA = importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
-PATHS = ("resident", "streamed")
+PATHS = ("resident", "resident_packed", "streamed")
 
 
 def _qkv(rng, b=2, tq=128, tk=128, h=2, d=64):
     mk = lambda t: jnp.asarray(
         rng.standard_normal((b, t, h, d)), jnp.float32)
     return mk(tq), mk(tk), mk(tk)
+
+
+def _pairs(path, h, d):
+    """The head count a case runs with: the packed layout wants whole
+    128-lane column blocks, so narrow heads come in pairs or fours."""
+    return max(h, 128 // d) if path == "resident_packed" else h
 
 
 def _via(path, causal=False, block_q=None, block_k=None):
@@ -40,8 +49,13 @@ def _via(path, causal=False, block_q=None, block_k=None):
         b, tq, h, d = q.shape
         fold = lambda z: z.transpose(0, 2, 1, 3).reshape(
             b * h, z.shape[1], d)
+        if path == "resident_packed":
+            assert FA.flash_path(tq, tq, d, q.dtype, heads=h) == path
+            pack = lambda z: z.reshape(b, tq, h * d)
+            return FA._flash_resident(pack(q), pack(k), pack(v), h, causal,
+                                      True).reshape(b, tq, h, d)
         if path == "resident":
-            o = FA._flash_resident(fold(q), fold(k), fold(v), causal, True)
+            o = FA._flash_resident(fold(q), fold(k), fold(v), 1, causal, True)
         else:
             bq = FA._pick_block(tq, block_q or 1024)
             bk = FA._pick_block(k.shape[1], block_k or 1024)
@@ -121,7 +135,7 @@ def test_backward_with_oversized_caller_blocks(rng):
 @pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("causal", [False, True])
 def test_gradients_match_oracle(rng, causal, path):
-    q, k, v = _qkv(rng, b=1, tq=64, tk=64, h=1, d=32)
+    q, k, v = _qkv(rng, b=1, tq=64, tk=64, h=_pairs(path, 1, 32), d=32)
     gf = _grads(_via(path, causal), q, k, v)
     gr = _grads(lambda q, k, v: scaled_dot_product_attention(
         q, k, v, causal=causal), q, k, v)
@@ -148,28 +162,85 @@ def test_causal_gradients_over_several_blocks(rng, small_blocks, path, d):
                                    rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("heads", [1, 2])
 @pytest.mark.parametrize("causal", [False, True])
-def test_paths_agree(rng, small_blocks, causal):
-    """The two sets of kernels on the same folded inputs: o, lse, dq, dk,
-    dv to float32 tolerance (the sums run in another order)."""
+def test_paths_agree(rng, small_blocks, causal, heads):
+    """The two sets of kernels on the same inputs: o, lse, dq, dk, dv to
+    float32 tolerance (the sums run in another order). ``heads`` = 1 hands
+    the resident body the folded rows, 2 the same rows packed two to a
+    128-lane column block."""
     t, d = 4 * small_blocks, 64
-    q, k, v, g = (jnp.asarray(rng.standard_normal((3, t, d)), jnp.float32)
+    q, k, v, g = (jnp.asarray(rng.standard_normal((4, t, d)), jnp.float32)
                   for _ in range(4))
-    o_r, lse_r = FA._resident_fwd(q, k, v, causal, small_blocks, True)
+    # [b*h, t, d] -> [b, t, h*d] and back
+    pack = lambda z: z.reshape(-1, heads, t, d).transpose(0, 2, 1, 3).reshape(
+        -1, t, heads * d)
+    fold = lambda z: z.reshape(-1, t, heads, d).transpose(0, 2, 1, 3).reshape(
+        -1, t, d)
     o_s, lse_s = FA._flash_fwd_impl(q, k, v, causal, small_blocks,
                                     small_blocks, True)
-    # the same numbers, the resident one as the row the backward reads
-    assert lse_r.shape == (3, 1, t) and lse_s.shape == (3, t, 1)
-    np.testing.assert_allclose(np.asarray(o_r), np.asarray(o_s),
+    o_r, lse_r = FA._resident_fwd(pack(q), pack(k), pack(v), (0, 0, 0), heads,
+                                  d, causal, small_blocks, True)
+    # the same numbers, the resident one as the rows the backward reads
+    assert lse_r.shape == (4 // heads, heads, 1, t) and lse_s.shape == (4, t, 1)
+    np.testing.assert_allclose(np.asarray(fold(o_r)), np.asarray(o_s),
                                rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(lse_r)[:, 0], np.asarray(lse_s)[..., 0],
+    np.testing.assert_allclose(np.asarray(lse_r).reshape(4, t),
+                               np.asarray(lse_s)[..., 0],
                                rtol=2e-5, atol=2e-5)
-    got = FA._resident_bwd(q, k, v, o_s, lse_r, g, causal, small_blocks, True)
+    got = FA._resident_bwd(pack(q), pack(k), pack(v), pack(o_s), lse_r,
+                           pack(g), (0, 0, 0), heads, causal, small_blocks,
+                           True)
     want = FA._flash_bwd_impl(q, k, v, o_s, lse_s, g, causal, small_blocks,
                               small_blocks, True)
     for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+        np.testing.assert_allclose(np.asarray(fold(a)), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+def _fused(q, k, v):
+    b, t, h, d = q.shape
+    return jnp.concatenate([z.reshape(b, t, h * d) for z in (q, k, v)], -1)
+
+
+@pytest.mark.parametrize("h,d", [(2, 64), (4, 64), (1, 128), (2, 128),
+                                 (4, 32), (3, 64)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_qkv_entry_matches_split_and_fold(rng, small_blocks, causal,
+                                                h, d):
+    """``flash_attention_qkv`` on the projection's own [b, t, 3*h*d] array
+    (q, k, v read at their column offsets, dq, dk, dv written at them)
+    against ``jnp.split`` + the folded path: the same o and the same dqkv,
+    over several in-body blocks. Three heads of 64 do not pair: there the
+    entry IS the split and the fold."""
+    t = 2 * small_blocks
+    q, k, v = _qkv(rng, b=2, tq=t, tk=t, h=h, d=d)
+    packed = FA.flash_path(t, t, d, q.dtype, heads=h) == "resident_packed"
+    assert packed == (h != 3)
+    attend = lambda qkv: FA.flash_attention_qkv(qkv, h, causal=causal)
+
+    def folded(qkv):
+        q, k, v = (z.reshape(2, t, h, d) for z in jnp.split(qkv, 3, axis=-1))
+        return _via("resident", causal)(q, k, v).reshape(2, t, h * d)
+    qkv = _fused(q, k, v)
+    np.testing.assert_allclose(np.asarray(attend(qkv)),
+                               np.asarray(folded(qkv)), rtol=2e-5, atol=2e-5)
+    grad = lambda f: jax.grad(lambda z: jnp.sum(f(z) ** 2))(qkv)
+    np.testing.assert_allclose(np.asarray(grad(attend)),
+                               np.asarray(grad(folded)), rtol=2e-4, atol=2e-4)
+    want = scaled_dot_product_attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(attend(qkv)).reshape(want.shape),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def test_fused_qkv_entry_in_bf16(rng):
+    q, k, v = _qkv(rng, h=4)
+    got = FA.flash_attention_qkv(_fused(q, k, v).astype(jnp.bfloat16), 4,
+                                 causal=True)
+    assert got.dtype == jnp.bfloat16 and got.shape == (2, 128, 256)
+    want = scaled_dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(got, np.float32).reshape(want.shape),
+                               np.asarray(want), rtol=3e-2, atol=3e-2)
 
 
 @pytest.mark.parametrize("path", PATHS)
@@ -221,7 +292,7 @@ def test_jit_and_under_vmap(rng):
     (4096, 4096, 128, jnp.bfloat16, "streamed"),   # over the VMEM budget
     (16384, 16384, 128, jnp.bfloat16, "streamed"),
     (32768, 32768, 128, jnp.bfloat16, "streamed"),
-    (2048, 2048, 128, jnp.float32, "resident"),    # 23.3 MiB of the 24
+    (2048, 2048, 128, jnp.float32, "resident"),    # 28.2 MiB of the 30
     (3072, 3072, 128, jnp.float32, "streamed"),
     (64, 256, 64, jnp.bfloat16, "streamed"),       # tq < tk, the serving tail
     (1032, 1032, 64, jnp.bfloat16, "streamed"),    # 8 x 129: no block >= 128
@@ -229,6 +300,27 @@ def test_jit_and_under_vmap(rng):
 def test_path_is_a_pure_function_of_shapes(tq, tk, d, dtype, want):
     assert FA.flash_path(tq, tk, d, dtype) == want
     assert FA.flash_path(tq, tk, d, jnp.dtype(dtype)) == want  # and again
+
+
+@pytest.mark.parametrize("t,tk,h,d,want", [
+    (1024, 1024, 16, 64, "resident_packed"),  # gpt2-medium.pretrain-1k
+    (256, 256, 16, 64, "resident_packed"),    # gpt2-medium.finetune-256
+    (2048, 2048, 12, 128, "resident_packed"),  # cerebras-gpt-590m...-2k
+    (1024, 1024, 8, 32, "resident_packed"),   # four heads a program
+    (1024, 1024, 2, 256, "resident_packed"),  # a head of two tiles
+    (1024, 1024, 3, 64, "resident"),          # three heads do not pair
+    (1024, 1024, 1, 64, "resident"),
+    (1024, 1024, 4, 96, "resident"),          # 96 divides no tile
+    (1024, 1024, 0, 64, "resident"),          # folded rows: no head count
+    (64, 256, 16, 64, "streamed"),            # cross-length
+    (4096, 4096, 32, 64, "streamed"),         # granite...-4k's one layer
+    (16384, 16384, 8, 128, "streamed"),
+])
+def test_packed_layout_is_chosen_from_the_shapes(t, tk, h, d, want):
+    """The projections' own layout where the heads are whole 128-lane
+    column blocks of it, the folded copies where they are not, and nothing
+    new for what the streamed kernels serve."""
+    assert FA.flash_path(t, tk, d, jnp.bfloat16, heads=h) == want
 
 
 @pytest.fixture
@@ -239,14 +331,21 @@ def registry():
     set_registry(previous)
 
 
-@pytest.mark.parametrize("tq,tk,path", [(64, 64, "resident"),
-                                        (64, 256, "streamed")])
-def test_counter_ticks_once_a_traced_call(rng, registry, tq, tk, path):
+@pytest.mark.parametrize("tq,tk,h,path", [(64, 64, 1, "resident"),
+                                          (64, 64, 4, "resident_packed"),
+                                          (64, 64, 12, "fused"),
+                                          (64, 256, 1, "streamed")])
+def test_counter_ticks_once_a_traced_call(rng, registry, tq, tk, h, path):
     """The choice is made while tracing: one tick a trace with the path's
-    label, none for a call the compiled program serves."""
-    q, k, v = _qkv(rng, b=1, tq=tq, tk=tk, h=1, d=32)
+    label, none for a call the compiled program serves. The fused entry
+    counts as the public one does."""
+    q, k, v = _qkv(rng, b=1, tq=tq, tk=tk, h=h, d=32)
     count = lambda p: registry.counter(FLASH_PATH_COUNTER, path=p).value
     f = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
+    if path == "fused":
+        path = "resident_packed"
+        f = jax.jit(lambda q, k, v: FA.flash_attention_qkv(
+            _fused(q, k, v), h, causal=True))
     f(q, k, v)
     assert count(path) == 1
     f(q, k, v)  # no new trace
@@ -271,3 +370,55 @@ def test_long_context_lowers_to_the_streamed_kernels():
     reached before the resident kernels existed; a cell's shape does not."""
     assert _kernel_names(16384, 128) == ["flash_dkv", "flash_dq", "flash_fwd"]
     assert _kernel_names(1024, 64) == ["flash_dq_dkv", "flash_fwd"]
+
+
+@pytest.mark.parametrize("b,h,t,d", [
+    (8, 16, 1024, 64),    # gpt2-medium.pretrain-1k
+    (32, 16, 256, 64),    # gpt2-medium.finetune-256
+    (2, 12, 2048, 128),   # cerebras-gpt-590m.pretrain-2k
+])
+def test_gpt_block_step_hands_the_projections_to_the_kernels(
+        monkeypatch, b, h, t, d):
+    """The training step of one GPT block at a cell's shapes, lowered for
+    the TPU: ``qkv_proj``'s product goes into ``flash_fwd`` as it is, three
+    times; the kernel's o goes into ``attn_out_proj``'s product; the
+    backward kernel takes ``attn_out_proj``'s gradient and its one
+    [b, t, 3*h*d] result goes into ``qkv_proj``'s two gradient products. No
+    slice, no concatenation, and no transpose but of the weight gradients
+    (two-dimensional) stands anywhere in the step."""
+    from deeplearning4j_tpu.models.zoo.transformer import gpt
+
+    monkeypatch.setattr(FA, "pallas_interpret", lambda: False)
+    block = gpt(vocab_size=64, d_model=h * d, n_layers=1, num_heads=h,
+                max_len=t).impls[1]
+    bf16 = lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16)
+    params = jax.tree.map(bf16, jax.eval_shape(block.init_params,
+                                               jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16)
+
+    def loss(params, x):
+        out, _ = block.forward(params, x, {}, True)
+        return jnp.sum(out.astype(jnp.float32))
+    with jax.enable_x64(False):  # the suite's x64 is not the chip's setting
+        text = jax.jit(jax.grad(loss, (0, 1))).trace(params, x).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
+        "flash_dq_dkv", "flash_fwd"]
+    assert "stablehlo.slice" not in text
+    assert "stablehlo.concatenate" not in text
+    for shape in re.findall(r"stablehlo\.transpose .*?\(tensor<([\dx]+)x\w+>\)",
+                            text):
+        assert shape.count("x") == 1, shape  # a weight gradient's
+    made = dict(re.findall(r"(%\d+)(?::\d+)? = (?:stablehlo\.|call @)(\w+)",
+                           text))
+    fwd = re.search(r"(%\d+):2 = call @_resident_fwd\((%\d+), (%\d+), "
+                    r"(%\d+)\)", text)
+    o, *qkv = fwd.groups()
+    assert len(set(qkv)) == 1 and made[qkv[0]] == "dot_general"
+    assert f"stablehlo.dot_general {o}#0, " in text
+    bwd = re.search(r"(%\d+) = call @_resident_bwd\((%\d+), (%\d+), (%\d+), "
+                    + f"{o}#0, {o}#1, " + r"(%\d+)\)", text)
+    dqkv, *operands, g = bwd.groups()
+    assert operands == qkv and made[g] == "dot_general"
+    users = re.findall(r"stablehlo\.(\w+) [^\n=]*" + dqkv + r"\b", text)
+    assert users == ["dot_general", "dot_general"], users
