@@ -34,8 +34,7 @@ import traceback
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 
-#: the full-width configuration (bench.py gpt_large: the one shape of this
-#: model the chip has measured) and the CPU rehearsal's stand-in
+#: the full-width configuration and the CPU rehearsal's stand-in
 FULL = dict(
     vocab=8192, d_model=1024, n_layers=16, heads=8, seq=2048, batch=8,
     learning_rate=1e-4, scan_steps=48, new_tokens=64,

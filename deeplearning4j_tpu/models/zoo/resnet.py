@@ -15,8 +15,6 @@ bottlenecks — the variant every modern benchmark uses), stages
 
 from __future__ import annotations
 
-import numpy as np
-
 from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.graph import ElementWiseVertex
 from deeplearning4j_tpu.nn.conf.layers import (
@@ -139,39 +137,3 @@ def resnet50_train_flops_per_example(image_size: int = 224) -> float:
             prev_c = out_c
     macs += prev_c * 1000
     return 3.0 * 2.0 * macs
-
-
-def resnet50_benchmark(peak_flops: float, batch: int = 128,
-                       image_size: int = 224, steps: int = 8,
-                       num_classes: int = 1000) -> dict:
-    """Train-step throughput on synthetic ImageNet-shaped data; returns
-    the bench.py sub-benchmark dict."""
-    import time
-
-    from deeplearning4j_tpu.datasets.dataset import MultiDataSet
-
-    net = resnet50(num_classes=num_classes)
-    rng = np.random.default_rng(0)
-    x = rng.standard_normal((batch * steps, image_size, image_size, 3)).astype(np.float32)
-    y = np.eye(num_classes, dtype=np.float32)[rng.integers(0, num_classes, batch * steps)]
-    mds = MultiDataSet([x], [y])
-
-    staged = net.stage_scan(mds, batch)  # one host→device transfer
-    # 12 epochs x 8 steps ≈ 4.7s device per dispatch; best of 2 timed
-    # dispatches
-    epochs = 12
-    # warm up the SAME epochs-baked program the timed run uses
-    net.fit_scan(None, batch, epochs=epochs, staged=staged)
-    dt = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        scores = net.fit_scan(None, batch, epochs=epochs, staged=staged)
-        dt = min(dt, time.perf_counter() - t0)
-
-    n_examples = epochs * steps * batch
-    eps = n_examples / dt
-    mfu = eps * resnet50_train_flops_per_example(image_size) / peak_flops
-    assert np.isfinite(np.asarray(scores)).all()
-    return {"metric": "resnet50_train_examples_per_sec_per_chip",
-            "value": round(eps, 1), "unit": "examples/sec/chip",
-            "mfu": round(mfu, 4), "vs_baseline": round(mfu / 0.30, 4)}

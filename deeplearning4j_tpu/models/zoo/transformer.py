@@ -176,42 +176,3 @@ def gpt_train_flops_per_token(vocab_size: int, d_model: int, n_layers: int,
     head = d_model * vocab_size
     macs = n_layers * (per_layer + attn) + head + d_model  # + embed gather
     return 6.0 * macs
-
-
-def gpt_benchmark(peak_flops: float, vocab_size: int = 8192,
-                  d_model: int = 512, n_layers: int = 8, seq_len: int = 1024,
-                  batch: int = 16, steps: int = 4) -> dict:
-    """Train-step throughput on synthetic token streams."""
-    import time
-
-    from deeplearning4j_tpu.datasets.dataset import DataSet
-
-    net = gpt(vocab_size=vocab_size, d_model=d_model, n_layers=n_layers,
-              max_len=seq_len).init()
-    rng = np.random.default_rng(0)
-    ids = rng.integers(0, vocab_size, (batch * steps, seq_len))
-    x = ids.astype(np.float32)
-    # sparse int labels: no [n, t, vocab] one-hot staging (ops/losses.py)
-    y = np.roll(ids, -1, axis=1).astype(np.float32)
-    data = DataSet(x, y)
-
-    staged = net.stage_scan(data, batch)
-    # 12 epochs of in-program steps per timed dispatch
-    epochs = 12
-    # warm up the SAME epochs-baked program the timed run uses; best of
-    # 2 timed dispatches
-    net.fit_scan(None, batch, epochs=epochs, staged=staged)
-    dt = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        scores = net.fit_scan(None, batch, epochs=epochs, staged=staged)
-        dt = min(dt, time.perf_counter() - t0)
-
-    tokens = epochs * steps * batch * seq_len
-    tps = tokens / dt
-    mfu = tps * gpt_train_flops_per_token(
-        vocab_size, d_model, n_layers, seq_len) / peak_flops
-    assert np.isfinite(np.asarray(scores)).all()
-    return {"metric": "gpt_train_tokens_per_sec_per_chip",
-            "value": round(tps, 1), "unit": "tokens/sec/chip",
-            "mfu": round(mfu, 4), "vs_baseline": round(mfu / 0.30, 4)}

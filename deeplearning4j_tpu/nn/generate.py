@@ -30,8 +30,7 @@ ComputationGraphs through the identical machinery.
 
 ``generate_eager`` is the per-token host-loop reference — one dispatch
 per token, same math and same per-row PRNG fold indices, so fused and
-eager agree token-for-token (the correctness oracle and the bench.py
-``gpt_decode``/``lstm_decode`` comparison baseline).
+eager agree token-for-token (the correctness oracle).
 """
 
 from __future__ import annotations
@@ -1167,7 +1166,7 @@ def generate_eager(net, prompt_ids, max_new_tokens: int, *,
                    seed: int = 0) -> np.ndarray:
     """Per-token host-loop reference for :func:`generate` — identical
     math and PRNG schedule, one dispatch per token. The correctness
-    oracle and the ``bench.py`` fused-vs-eager comparison baseline."""
+    oracle."""
     gen, prompt, ids, lengths, max_new = _prep(net, prompt_ids,
                                                max_new_tokens)
     toks = gen.run_eager(net.params, ids, lengths, max_new,

@@ -15,6 +15,7 @@ rule in ``multilayer.py``.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict
 
 import jax
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.attention import (
-    dispatch_attention, dispatch_qkv_attention, xla_attention)
+    dispatch_qkv_attention, xla_attention)
 from deeplearning4j_tpu.nn.layers.base import (
     LayerImpl, apply_dropout, register_impl)
 from deeplearning4j_tpu.nn.layers.moe import (
@@ -38,6 +39,18 @@ def _layer_norm(x, gamma, beta, eps=1e-5):
     var = jnp.var(xf, axis=-1, keepdims=True)
     out = (xf - mean) * jax.lax.rsqrt(var + eps) * gamma + beta
     return out.astype(x.dtype)
+
+
+def _attend_cached(q, k, v, live):
+    """Masked softmax attention of queries ``q`` [b, t, h, hd] over cached
+    keys and values [b, n, h, hd]; ``live`` [b or 1, t, n] says which
+    slots each query may see. → [b, t, h, hd]."""
+    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k.astype(q.dtype)) * scale
+    s = jnp.where(live[:, None], s,
+                  jnp.asarray(jnp.finfo(s.dtype).min, s.dtype))
+    w = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(q.dtype))
 
 
 @register_impl(L.SequenceEmbeddingLayer)
@@ -115,10 +128,22 @@ class TransformerBlockImpl(LayerImpl):
         return {}
 
     def forward(self, params, x, state, train, rng=None, mask=None):
-        c = self.conf
         if x.ndim != 3:
             raise ValueError(f"TransformerBlock needs [b, t, d], got {x.shape}")
-        b, t, d = x.shape
+        out, new_state, _ = self._block(
+            params, x, lambda qkv: self._attend_sequence(qkv, mask),
+            state=state, train=train, rng=rng, mask=mask,
+            capacity_factor=self.conf.capacity_factor)
+        return out, new_state
+
+    def _block(self, params, x, attend, *, state, train, rng, mask,
+               capacity_factor):
+        """The block's wiring, written once, over ``x`` of [..., d]:
+        x + Wo·attend(Wqkv·LN(x)), then x + FFN(LN(x)). ``attend`` maps
+        the fused projection [..., 3d] to (o [..., d], new KV store) and is
+        all that ``forward``, ``prefill``, ``prefill_paged`` and
+        ``decode_step`` differ in. Returns (out, new state, new store)."""
+        d = x.shape[-1]
         # static scope names: the device trace reads each part of the
         # block under them (util/profiler.scope_seconds); JAX adds
         # jvp(...) / transpose(jvp(...)) for forward and backward
@@ -127,16 +152,7 @@ class TransformerBlockImpl(LayerImpl):
         with jax.named_scope("qkv_proj"):
             qkv = qmatmul(h, params, "Wqkv")
         with jax.named_scope("attention"):
-            if self._slice_mesh is not None:
-                # sliced serving: heads are sharded over tp — the Pallas
-                # flash kernel cannot see the mesh, so stay on the XLA
-                # formulation GSPMD partitions per-head
-                with xla_attention():
-                    o = dispatch_qkv_attention(qkv, c.num_heads,
-                                               causal=c.causal, mask=mask)
-            else:
-                o = dispatch_qkv_attention(qkv, c.num_heads, causal=c.causal,
-                                           mask=mask, mesh=self._mesh)
+            o, store = attend(qkv)
         with jax.named_scope("attn_out_proj"):
             attn = qmatmul(self._slice_replicate(o), params, "Wo")
         if train and self.dropout_rate > 0.0 and rng is not None:
@@ -150,15 +166,26 @@ class TransformerBlockImpl(LayerImpl):
             h2 = _layer_norm(x, params["ln2_g"], params["ln2_b"])
         mlp, new_state = self._ffn(params, h2.reshape(-1, d), state,
                                    mask=mask,
-                                   capacity_factor=c.capacity_factor)
-        mlp = mlp.reshape(b, t, d)
+                                   capacity_factor=capacity_factor)
+        mlp = mlp.reshape(x.shape)
         if train and self.dropout_rate > 0.0 and rng is not None:
             mlp = apply_dropout(mlp, self.dropout_rate,
                                 jax.random.fold_in(rng, 2))
         out = self._slice_replicate(x + mlp)
         if mask is not None:
             out = out * mask[:, :, None].astype(out.dtype)
-        return out, new_state
+        return out, new_state, store
+
+    def _serve(self, params, x, attend):
+        """``_block`` as every serving entry point runs it: inference,
+        maskless, and MoE routed NO-DROP (capacity = ceil(cf·n/E) >= n
+        when cf = E) — the training-time capacity heuristic over b·t
+        tokens has no stepwise equivalent, and dropping tokens at
+        inference is never what serving wants. Returns (out, store)."""
+        out, _, store = self._block(
+            params, x, attend, state={}, train=False, rng=None, mask=None,
+            capacity_factor=float(max(1, self.conf.num_experts)))
+        return out, store
 
     def _ffn(self, params, h2, state, mask=None, capacity_factor=None):
         """Post-LN2 feed-forward over flattened tokens [n, d]: dense
@@ -200,32 +227,13 @@ class TransformerBlockImpl(LayerImpl):
         Right-padded prompt rows are safe: a padded position's garbage
         K/V slot is only ever attended to after a decode step has
         overwritten it (decode writes slot ``pos`` before reading)."""
-        c = self.conf
-        b, t, d = x.shape
-        h_count, hd = c.num_heads, c.n_out // c.num_heads
-        h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
-        qkv = qmatmul(h, params, "Wqkv")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = lambda z: z.reshape(b, t, h_count, hd)
-        q, k, v = shape(q), shape(k), shape(v)
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
-        if self._slice_mesh is not None:
-            with xla_attention():
-                o = dispatch_attention(q, k, v, causal=c.causal, mask=None)
-        else:
-            o = dispatch_attention(q, k, v, causal=c.causal, mask=None,
-                                   mesh=self._mesh)
-        x = self._slice_replicate(
-            x + qmatmul(self._slice_replicate(o.reshape(b, t, d)),
-                        params, "Wo"))
-        h2 = _layer_norm(x, params["ln2_g"], params["ln2_b"])
-        mlp, _ = self._ffn(params, h2.reshape(-1, d), {},
-                           capacity_factor=float(max(1, c.num_experts)))
-        return self._slice_replicate(x + mlp.reshape(b, t, d)), \
-            {"k": ck, "v": cv}
+        def attend(qkv):
+            _, k, v = self._heads(qkv)
+            write = lambda buf, z: jax.lax.dynamic_update_slice_in_dim(
+                buf, z.astype(buf.dtype), 0, axis=1)
+            o, _ = self._attend_sequence(qkv, None)
+            return o, {"k": write(cache["k"], k), "v": write(cache["v"], v)}
+        return self._serve(params, x, attend)
 
     def prefill_paged(self, params, x, pool, table, pos, write_ok):
         """Chunked (tail) prefill straight through the paged pool — the
@@ -239,63 +247,10 @@ class TransformerBlockImpl(LayerImpl):
         discipline). Tail K/V scatters into the row's table blocks
         FIRST, then attention gathers the whole table back — so tail
         self-attention sees its own fresh K/V and the cached prefix in
-        one causal pass. Gathered positions past each query's ``pos``
-        (stale partial-block content, trash padding) are causally
-        masked, numerically inert exactly like the dense path's padded
-        tail. Returns ([b, t, d] out, new pool {"k", "v"})."""
-        c = self.conf
-        b, t, d = x.shape
-        h_count, hd = c.num_heads, c.n_out // c.num_heads
-        h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
-        qkv = qmatmul(h, params, "Wqkv")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = lambda z: z.reshape(b, t, h_count, hd)
-        q, k, v = shape(q), shape(k), shape(v)
-        kp, vp = pool["k"], pool["v"]        # [NB, bs, h, hd] shared pool
-        bs = kp.shape[1]
-        mb = table.shape[1]
-        blk = jnp.take_along_axis(table, pos // bs, axis=1)     # [b, t]
-        off = pos % bs
-        blk = jnp.where(write_ok, blk, 0)    # padding → trash block
-        off = jnp.where(write_ok, off, 0)
-        new_pool = dict(pool)
-        if "k_scale" in pool:
-            # quantized pool (nn/quantize.py): per-(position, head)
-            # scales over head_dim — quantize on scatter here, dequant
-            # on gather below, attention math unchanged
-            kq, ksc = kv_quantize(k, kp.dtype)
-            vq, vsc = kv_quantize(v, vp.dtype)
-            kp = kp.at[blk, off].set(kq)
-            vp = vp.at[blk, off].set(vq)
-            new_pool["k_scale"] = pool["k_scale"].at[blk, off].set(ksc)
-            new_pool["v_scale"] = pool["v_scale"].at[blk, off].set(vsc)
-        else:
-            kp = kp.at[blk, off].set(k.astype(kp.dtype))
-            vp = vp.at[blk, off].set(v.astype(vp.dtype))
-        new_pool["k"], new_pool["v"] = kp, vp
-        kg = jnp.take(kp, table, axis=0).reshape(b, mb * bs, *kp.shape[2:])
-        vg = jnp.take(vp, table, axis=0).reshape(b, mb * bs, *vp.shape[2:])
-        if "k_scale" in pool:
-            ksg = jnp.take(new_pool["k_scale"], table, axis=0).reshape(
-                b, mb * bs, h_count)
-            vsg = jnp.take(new_pool["v_scale"], table, axis=0).reshape(
-                b, mb * bs, h_count)
-            kg = kv_dequantize(kg, ksg, q.dtype)
-            vg = kv_dequantize(vg, vsg, q.dtype)
-        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, q.dtype))
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kg.astype(q.dtype)) * scale
-        live = jnp.arange(mb * bs)[None, None, :] <= pos[:, :, None]
-        s = jnp.where(live[:, None], s,
-                      jnp.asarray(jnp.finfo(s.dtype).min, s.dtype))
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", w, vg.astype(q.dtype))
-        x = self._slice_replicate(
-            x + qmatmul(self._slice_replicate(o.reshape(b, t, d)),
-                        params, "Wo"))
-        h2 = _layer_norm(x, params["ln2_g"], params["ln2_b"])
-        mlp, _ = self._ffn(params, h2.reshape(-1, d), {},
-                           capacity_factor=float(max(1, c.num_experts)))
-        return self._slice_replicate(x + mlp.reshape(b, t, d)), new_pool
+        one causal pass (``_attend_paged``). Returns ([b, t, d] out,
+        new pool {"k", "v"})."""
+        return self._serve(params, x, lambda qkv: self._attend_paged(
+            qkv, pool, table, pos, write_ok))
 
     def decode_step(self, params, x_t, cache, pos, write_mask=None):
         """One-token forward [b, d] with cached keys/values; ``pos`` is
@@ -304,9 +259,7 @@ class TransformerBlockImpl(LayerImpl):
         path; the K/V write becomes a per-row one-hot scatter). Returns
         (y_t [b, d], new cache). Dense blocks match ``forward`` exactly
         at every prefix position (tested); MoE blocks route NO-DROP at
-        decode time (capacity = batch) — the training-time capacity
-        heuristic over b*t tokens has no stepwise equivalent, and
-        dropping tokens at inference is never what serving wants.
+        decode time (capacity = batch, ``_serve``).
 
         **Paged mode** (the vLLM PagedAttention layout, nn/kvpool.py):
         when ``cache`` carries a ``"table"`` entry, ``cache["k"]`` /
@@ -318,115 +271,109 @@ class TransformerBlockImpl(LayerImpl):
         ``write_mask`` [b] bool redirects masked rows' writes to the
         reserved trash block 0, so retired rows / batch-slot padding /
         warmup dispatches can never scribble over a live sequence's
-        blocks. ``pos`` must be a [b] vector in paged mode."""
+        blocks. ``pos`` must be a [b] vector in paged mode: the step is
+        ``prefill_paged``'s attention at ``t = 1``."""
+        if "table" not in cache:
+            return self._serve(params, x_t, lambda qkv: self._attend_cache(
+                qkv, cache, pos))
+
+        def attend(qkv):
+            o, new_cache = self._attend_paged(
+                qkv[:, None], cache, cache["table"], pos[:, None],
+                None if write_mask is None else write_mask[:, None])
+            return o[:, 0], new_cache
+        return self._serve(params, x_t, attend)
+
+    # --------------------------------- attention, by where K/V are kept
+
+    def _heads(self, qkv):
+        """The fused projection [..., 3d] as q, k, v of [..., h, hd]."""
+        return tuple(z.reshape(*z.shape[:-1], self.conf.num_heads, -1)
+                     for z in jnp.split(qkv, 3, axis=-1))
+
+    def _attend_sequence(self, qkv, mask):
+        """A whole sequence [b, t, 3d] over itself, nothing read from a
+        store and none made: the flash/ring dispatch on the fused
+        projection → ([b, t, d], None)."""
         c = self.conf
-        b, d = x_t.shape
-        h_count, hd = c.num_heads, c.n_out // c.num_heads
-        h = _layer_norm(x_t, params["ln1_g"], params["ln1_b"])
-        qkv = qmatmul(h, params, "Wqkv")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = lambda z: z.reshape(b, h_count, hd)
-        q, k, v = shape(q), shape(k), shape(v)
-        if "table" in cache:
-            return self._decode_step_paged(params, x_t, cache, pos,
-                                           q, k, v, write_mask)
+        # sliced serving: heads are sharded over tp — the Pallas flash
+        # kernel cannot see the mesh, so stay on the XLA formulation
+        # GSPMD partitions per-head
+        force_xla = xla_attention() if self._slice_mesh is not None \
+            else contextlib.nullcontext()
+        with force_xla:
+            return dispatch_qkv_attention(
+                qkv, c.num_heads, causal=c.causal, mask=mask,
+                mesh=self._mesh), None
+
+    def _attend_cache(self, qkv, cache, pos):
+        """One token a row [b, 3d] against the dense cache
+        [b, L, h, hd]: write slot ``pos`` FIRST (scalar: one
+        dynamic_update_slice; [b] vector: a per-row one-hot write), then
+        attend over the slots <= ``pos`` → ([b, d], new cache)."""
+        q, k, v = (z[:, None] for z in self._heads(qkv))
         slots = jnp.arange(cache["k"].shape[1])
         if jnp.ndim(pos) == 0:
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k[:, None].astype(cache["k"].dtype), pos, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v[:, None].astype(cache["v"].dtype), pos, axis=1)
-            # causal: only positions <= pos are live
-            live = (slots <= pos)[None, :]
+            write = lambda buf, z: jax.lax.dynamic_update_slice_in_dim(
+                buf, z.astype(buf.dtype), pos, axis=1)
+            live = (slots <= pos)[None, :]       # causal, whole batch
         else:
             sel = (slots[None, :] == pos[:, None])[:, :, None, None]
-            ck = jnp.where(sel, k[:, None].astype(cache["k"].dtype),
-                           cache["k"])
-            cv = jnp.where(sel, v[:, None].astype(cache["v"].dtype),
-                           cache["v"])
-            live = slots[None, :] <= pos[:, None]  # [b, L] per-row causal
-        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, q.dtype))
-        s = jnp.einsum("bhd,bkhd->bhk", q, ck.astype(q.dtype)) * scale
-        s = jnp.where(live[:, None, :], s,
-                      jnp.asarray(jnp.finfo(s.dtype).min, s.dtype))
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhk,bkhd->bhd", w, cv.astype(q.dtype))
-        x_t = self._slice_replicate(
-            x_t + qmatmul(self._slice_replicate(o.reshape(b, d)),
-                          params, "Wo"))
+            write = lambda buf, z: jnp.where(sel, z.astype(buf.dtype), buf)
+            live = slots[None, :] <= pos[:, None]       # [b, L] per row
+        ck, cv = write(cache["k"], k), write(cache["v"], v)
+        o = _attend_cached(q, ck, cv, live[:, None])
+        return o.reshape(qkv.shape[0], -1), {"k": ck, "v": cv}
 
-        h2 = _layer_norm(x_t, params["ln2_g"], params["ln2_b"])
-        # no-drop capacity: capacity = ceil(cf*b/E) >= b when cf = E
-        mlp, _ = self._ffn(params, h2, {},
-                           capacity_factor=float(max(1, c.num_experts)))
-        return self._slice_replicate(x_t + mlp), {"k": ck, "v": cv}
-
-    def _decode_step_paged(self, params, x_t, cache, pos, q, k, v,
-                           write_mask):
-        """Gather/scatter attention over a block table (decode_step's
-        paged-pool branch — q/k/v already projected): scatter this
-        token's K/V into its row's (block, offset) pool slot, gather
+    def _attend_paged(self, qkv, pool, table, pos, write_ok):
+        """Gather/scatter attention over a block table, for ``t`` tokens
+        a row ([b, t, 3d], ``pos`` and ``write_ok`` [b, t]; ``write_ok``
+        None means all true): scatter each token's K/V into its row's
+        (block, offset) slot of the SHARED pool [NB, bs, h, hd], gather
         the row's blocks back as a contiguous [b, MB*bs] view, and run
-        the same masked softmax attention as the dense branch. Gathered
-        positions past ``pos`` (including every trash/garbage block the
-        table pads with) are causally masked, so pool garbage is
-        numerically inert exactly like the dense path's padded tail.
+        the dense cache's masked softmax over it. Positions whose
+        ``write_ok`` is false write the trash block 0 — never a live
+        sequence. Gathered positions past each query's ``pos`` (stale
+        partial-block content, and every trash/garbage block the table
+        pads with) are causally masked, so pool garbage is numerically
+        inert exactly like the dense path's padded tail.
 
         A QUANTIZED pool (``"k_scale"``/``"v_scale"`` entries — the
-        nn/kvpool.py int8/fp8 variant) quantizes the incoming token's
-        K/V per head on the scatter and dequantizes the gathered view
-        before the softmax; everything else — table discipline, trash
-        redirect, causal mask — is identical, and the scale arrays ride
-        the same (block, offset) addressing as the values."""
-        c = self.conf
-        b, d = x_t.shape
-        kp, vp = cache["k"], cache["v"]      # [NB, bs, h, hd] shared pool
-        table = cache["table"]               # [b, MB] int32 block ids
-        bs = kp.shape[1]
-        mb = table.shape[1]
-        blk_of = pos // bs
+        nn/kvpool.py int8/fp8 variant) quantizes incoming K/V per
+        (position, head) over head_dim on the scatter and dequantizes
+        the gathered view before the softmax; everything else — table
+        discipline, trash redirect, causal mask — is identical, and the
+        scale arrays ride the same (block, offset) addressing as the
+        values. Returns ([b, t, d], ``pool`` with its buffers renewed)."""
+        q, k, v = self._heads(qkv)
+        b, t = pos.shape
+        bs = pool["k"].shape[1]
+        blk = jnp.take_along_axis(table, pos // bs, axis=1)
         off = pos % bs
-        blk = jnp.take_along_axis(table, blk_of[:, None], axis=1)[:, 0]
-        if write_mask is not None:
-            # masked rows write the trash block — never a live sequence
-            blk = jnp.where(write_mask, blk, 0)
-            off = jnp.where(write_mask, off, 0)
-        new_cache = dict(cache)
-        if "k_scale" in cache:
-            kq, ksc = kv_quantize(k, kp.dtype)
-            vq, vsc = kv_quantize(v, vp.dtype)
-            kp = kp.at[blk, off].set(kq)
-            vp = vp.at[blk, off].set(vq)
-            new_cache["k_scale"] = cache["k_scale"].at[blk, off].set(ksc)
-            new_cache["v_scale"] = cache["v_scale"].at[blk, off].set(vsc)
-        else:
-            kp = kp.at[blk, off].set(k.astype(kp.dtype))
-            vp = vp.at[blk, off].set(v.astype(vp.dtype))
-        new_cache["k"], new_cache["v"] = kp, vp
-        # gather the row's cache back into causal order: [b, MB*bs, h, hd]
-        kg = jnp.take(kp, table, axis=0).reshape(b, mb * bs, *kp.shape[2:])
-        vg = jnp.take(vp, table, axis=0).reshape(b, mb * bs, *vp.shape[2:])
-        if "k_scale" in cache:
-            h_count = c.num_heads
-            ksg = jnp.take(new_cache["k_scale"], table, axis=0).reshape(
-                b, mb * bs, h_count)
-            vsg = jnp.take(new_cache["v_scale"], table, axis=0).reshape(
-                b, mb * bs, h_count)
-            kg = kv_dequantize(kg, ksg, q.dtype)
-            vg = kv_dequantize(vg, vsg, q.dtype)
-        hd = c.n_out // c.num_heads
-        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, q.dtype))
-        s = jnp.einsum("bhd,bkhd->bhk", q, kg.astype(q.dtype)) * scale
-        live = jnp.arange(mb * bs)[None, :] <= pos[:, None]
-        s = jnp.where(live[:, None, :], s,
-                      jnp.asarray(jnp.finfo(s.dtype).min, s.dtype))
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhk,bkhd->bhd", w, vg.astype(q.dtype))
-        x_t = self._slice_replicate(
-            x_t + qmatmul(self._slice_replicate(o.reshape(b, d)),
-                          params, "Wo"))
+        if write_ok is not None:
+            blk = jnp.where(write_ok, blk, 0)
+            off = jnp.where(write_ok, off, 0)
+        quantized = "k_scale" in pool
+        new_pool = dict(pool)
 
-        h2 = _layer_norm(x_t, params["ln2_g"], params["ln2_b"])
-        mlp, _ = self._ffn(params, h2, {},
-                           capacity_factor=float(max(1, c.num_experts)))
-        return self._slice_replicate(x_t + mlp), new_cache
+        def through_pool(name, z):
+            buf = pool[name]
+            if quantized:
+                z, sc = kv_quantize(z, buf.dtype)
+                new_pool[name + "_scale"] = \
+                    pool[name + "_scale"].at[blk, off].set(sc)
+            new_pool[name] = buf.at[blk, off].set(z.astype(buf.dtype))
+            # the row's cache back in causal order: [b, MB*bs, h, hd]
+            g = jnp.take(new_pool[name], table, axis=0).reshape(
+                b, -1, *buf.shape[2:])
+            if quantized:
+                scales = jnp.take(new_pool[name + "_scale"], table,
+                                  axis=0).reshape(b, -1, buf.shape[2])
+                g = kv_dequantize(g, scales, q.dtype)
+            return g
+
+        live = jnp.arange(table.shape[1] * bs)[None, None, :] \
+            <= pos[:, :, None]
+        o = _attend_cached(q, through_pool("k", k), through_pool("v", v),
+                           live)
+        return o.reshape(b, t, -1), new_pool

@@ -35,8 +35,7 @@ is the weights half of that arc (nn/kvpool.py carries the KV half):
   next-token cross-entropy delta vs the fp32 net on a fixed seeded
   workload, with pass/fail thresholds. ``make_quality_gate`` adapts it
   to the ``ModelRegistry.deploy(quality_gate=...)`` seam so a
-  quantized canary is arbitrated by measured quality, and
-  ``bench.py quantized_serving`` reports the same numbers.
+  quantized canary is arbitrated by measured quality.
 
 Numeric contract (MIGRATION.md "Quantized serving"): the quantized
 lane is EXACT versus itself — greedy tokens are bitwise-reproducible
@@ -336,9 +335,9 @@ def _xent(logits: np.ndarray, targets: np.ndarray) -> float:
 
 def gate_workload(vocab: int, rows: int = 8, length: int = 24,
                   seed: int = 0) -> np.ndarray:
-    """The FIXED seeded token workload both the canary gate and
-    ``bench.py quantized_serving`` score on: same seed ⇒ same ids ⇒
-    the gate verdict is a pure function of the two nets."""
+    """The FIXED seeded token workload the canary gate scores on: same
+    seed ⇒ same ids ⇒ the gate verdict is a pure function of the two
+    nets."""
     rng = np.random.default_rng(seed)
     return rng.integers(1, vocab, (rows, length)).astype(np.int32)
 

@@ -50,8 +50,8 @@ class TpuPodProvisioner:
     """Builds (and optionally executes) the gcloud command plan."""
 
     #: artifact members shipped to every host (ClusterSetup rsync role)
-    ARTIFACT_MEMBERS = ("deeplearning4j_tpu", "tests", "bench.py",
-                        "pyproject.toml")
+    ARTIFACT_MEMBERS = ("deeplearning4j_tpu", "tests", "benchmarks",
+                        "BENCHMARK.json", "pyproject.toml")
 
     def __init__(self, spec: TpuPodSpec):
         self.spec = spec

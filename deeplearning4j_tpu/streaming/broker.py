@@ -13,8 +13,7 @@ The server runs a ``selectors``-based reactor by default: one event
 loop owns every connection, the topic queues, and the long-poll parking
 lot, so the data plane needs no server-side locks at all and scales to
 thousands of idle long-pollers without a thread each. The pre-reactor
-thread-per-connection server is kept behind ``reactor=False`` as the
-measured baseline for ``bench.py router_saturation``.
+thread-per-connection server is kept behind ``reactor=False``.
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """Persistent XLA compile cache placement + compile accounting.
 
-One helper every entry point calls (``chip_smoke.py``, ``bench.py``,
-``scripts/profile_*.py``, ``tests/conftest.py``) so the cache directory
-is decided in exactly one place:
+One helper every entry point calls (``chip_smoke.py``,
+``benchmarks/drivers/``, ``scripts/profile_*.py``, ``tests/conftest.py``)
+so the cache directory is decided in exactly one place:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself; the
   helper touches no directory setting and only lowers the size/time

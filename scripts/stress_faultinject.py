@@ -664,26 +664,13 @@ def analysis_section() -> List[str]:
     return [f"analysis: {f.render()}" for f in report.new]
 
 
-def bench_trend_section() -> List[str]:
-    """SECTION 0b — the perf-trend gate's logic contract: the
-    gate-logic fixture of ``scripts/bench_trend.py`` still flags an
-    injected regression and passes a flat series. No bench run, so
-    quick_check stays seconds."""
-    from scripts.bench_trend import DEFAULT_WINDOW, _fixture_check
-    return [f"bench_trend: {p}" for p in _fixture_check(DEFAULT_WINDOW)]
-
-
 def quick_check(seeds=(0, 1, 2), runs_per_seed: int = 2) -> List[str]:
-    """Section 0 (static analysis, fail fast), section 0b (bench-trend
-    schema gate), then replay the injector battery ``runs_per_seed``
-    times per seed; returns violations ([] = clean + deterministic).
-    Tier-1 runs this."""
+    """Section 0 (static analysis, fail fast), then replay the injector
+    battery ``runs_per_seed`` times per seed; returns violations ([] =
+    clean + deterministic). Tier-1 runs this."""
     problems: List[str] = list(analysis_section())
     if problems:
         return problems  # fail fast: no chaos phase on a dirty tree
-    problems.extend(bench_trend_section())
-    if problems:
-        return problems
     for seed in seeds:
         logs = [_scenario_log(int(seed)) for _ in range(runs_per_seed)]
         for i, log in enumerate(logs[1:], 2):

@@ -91,12 +91,6 @@ def test_peak_table_rejects_an_unknown_device_kind():
     assert v5e.source
 
 
-def test_bench_has_no_cpu_fallback():
-    import bench
-    with pytest.raises(KeyError, match="no published peaks"):
-        bench.main()
-
-
 # ------------------------------------------------------- trace / dry run
 
 def test_profiler_trace_raises_when_it_cannot_trace(tmp_path):

@@ -5,9 +5,8 @@ sequences admitted mid-stream, preempted + resumed, and served across
 a PR-7 canary cutover (the session keeps its version); the
 deterministic lowest-priority/youngest-first preemption order under a
 tiny pool; the zero-steady-state-compile assertion via
-``dl4j_jit_cache_miss_total``; paged-vs-dense decode_step parity; pool
-accounting (free returns to total after drain, typed exhaustion,
-bounded-queue shedding); the kill-mid-burst recovery contract; and the
+``dl4j_jit_cache_miss_total``; pool accounting (free returns to total
+after drain, typed exhaustion, bounded-queue shedding); the kill-mid-burst recovery contract; and the
 ``stats()`` / ``/healthz/ready`` scheduler-warmup gate + the
 ``dl4j_kvpool_*`` / ``dl4j_sched_*`` schema pinning.
 """
@@ -18,7 +17,6 @@ import urllib.error
 import urllib.request
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -68,41 +66,6 @@ def _drive(sched, futures, max_steps=200):
     raise AssertionError(
         f"schedule did not converge in {max_steps} steps; "
         f"events={list(sched.events)}")
-
-
-# ------------------------------------------------- paged decode_step
-
-def test_paged_decode_step_matches_dense(rng):
-    """The block-table gather/scatter branch must reproduce the dense
-    decode_step at every position: same token, same cache values, just
-    paged through the shared pool."""
-    net = _tiny_gpt()
-    blk = net.impls[1]
-    params = net.params[blk.name]
-    b, d, bs, mb, nb_pool = 2, 16, 4, 3, 8
-    dense = blk.init_cache(b, mb * bs)
-    kp = {"k": jnp.zeros((nb_pool, bs, 2, 8)),
-          "v": jnp.zeros((nb_pool, bs, 2, 8))}
-    # distinct blocks per row, allocated out of order on purpose
-    table = jnp.asarray([[3, 1, 5], [2, 6, 4]], jnp.int32)
-    pos = np.zeros(b, np.int32)
-    for step in range(7):
-        x = jnp.asarray(rng.standard_normal((b, d)), jnp.float32)
-        pv = jnp.asarray(pos)
-        y_dense, dense = blk.decode_step(params, x, dense, pv)
-        y_paged, paged = blk.decode_step(
-            params, x, {"k": kp["k"], "v": kp["v"], "table": table}, pv,
-            write_mask=jnp.ones(b, bool))
-        kp = {"k": paged["k"], "v": paged["v"]}
-        np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_paged),
-                                   rtol=1e-5, atol=1e-5)
-        pos += 1
-    # the paged pool holds exactly the dense cache's rows, block-permuted
-    for row in range(b):
-        gathered = np.asarray(kp["k"])[np.asarray(table)[row]].reshape(
-            mb * bs, 2, 8)
-        np.testing.assert_allclose(
-            gathered[:7], np.asarray(dense["k"])[row, :7], rtol=0, atol=0)
 
 
 def test_kvpool_accounting():

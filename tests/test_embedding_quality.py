@@ -3,10 +3,9 @@
 
 The device SGNS replaces the reference's sequential per-pair updates
 (``SkipGram.java:204``) with batched scatter-adds capped per row
-(``engine._ROW_UPDATE_CAP``). Throughput is anchored in bench.py; this
-file anchors *embedding quality* on a corpus with planted class
-structure AND a 30%-frequency head word that exceeds the cap ~20x per
-batch, two ways:
+(``engine._ROW_UPDATE_CAP``). This file anchors *embedding quality*
+on a corpus with planted class structure AND a 30%-frequency head word
+that exceeds the cap ~20x per batch, two ways:
 
 1. cap-on vs cap-off at identical settings — isolates the cap itself.
    Measured here (2026-07-30, CPU mesh, purity@3): cap=64 -> 0.256,
@@ -20,10 +19,10 @@ batch, two ways:
    the batch-64 host, a step-starvation effect of large-batch SGD that
    has nothing to do with capping (device batch=512 at the same epoch
    count moves 0.256 -> only 0.336, while 4x epochs reaches 0.95).
-   The user-facing contract is quality per WALL-CLOCK: bench.py
-   measures the device engine ~15x the host throughput, so the gate
-   grants the device 4x the epochs (still >=3x faster end-to-end) and
-   requires it to match-or-beat host quality.
+   The user-facing contract is quality per WALL-CLOCK: the device
+   engine was measured at ~15x the host throughput (2026-07-30, before
+   the chip: BASELINE.md), so the gate grants the device 4x the epochs
+   and requires it to match-or-beat host quality.
 """
 
 import jax

@@ -366,8 +366,8 @@ def _kernel_names(t, d, heads=2):
 
 
 def test_long_context_lowers_to_the_streamed_kernels():
-    """16k / head 128 (bench.py's long-context path) reaches the code it
-    reached before the resident kernels existed; a cell's shape does not."""
+    """16k / head 128 reaches the code it reached before the resident
+    kernels existed; a cell's shape does not."""
     assert _kernel_names(16384, 128) == ["flash_dkv", "flash_dq", "flash_fwd"]
     assert _kernel_names(1024, 64) == ["flash_dq_dkv", "flash_fwd"]
 

@@ -231,27 +231,25 @@ def test_command_line_interface(registry, tmp_path, capsys):
 # -------------------------------------------------------------- overhead
 
 def test_monitoring_overhead_under_5_percent(registry):
-    """The acceptance bar: spans around a step-loop-scale workload (~2ms
-    per step, the test_host_baseline per-batch scale) must cost <5%."""
-    def work():
-        time.sleep(0.002)
-
-    n = 60
-    t0 = time.perf_counter()
-    for _ in range(n):
-        work()
-    bare = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
+    """The acceptance bar: a span around a step-loop-scale step (~2ms,
+    the test_host_baseline per-batch scale) must cost <5% of it. What is
+    bounded is what the span itself executes: this thread's CPU time over
+    a fixed count of spans, on the thread's own clock. (The difference of
+    two wall-clock loops of sleeps, which this test took before, measured
+    the scheduler under six xdist workers.)"""
+    step_ms, n = 2.0, 2000
+    for i in range(100):  # first use: the histogram child, the annotation
+        with monitor.span("device_step", iteration=i):
+            pass
+    t0 = time.thread_time()
     for i in range(n):
         with monitor.span("device_step", iteration=i):
-            work()
-    instrumented = time.perf_counter() - t0
-    # generous sleep jitter guard: the *absolute* span cost is what we
-    # actually bound — a few µs per span against a 2ms step
-    per_span_ms = max(0.0, instrumented - bare) / n * 1e3
-    assert per_span_ms < 0.1, f"span overhead {per_span_ms:.4f}ms"
-    assert instrumented < bare * 1.05 + 0.05
+            pass
+    per_span_ms = (time.thread_time() - t0) / n * 1e3
+    assert per_span_ms < 0.05 * step_ms, f"span overhead {per_span_ms:.4f}ms"
+    # and every one of them was recorded
+    hist = registry.get(monitor.PHASE_HISTOGRAM, phase="device_step")
+    assert hist.count == n + 100
 
 
 def test_training_stats_shares_monitor_clock(tmp_path):

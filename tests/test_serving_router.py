@@ -902,11 +902,13 @@ def test_stream_survives_stalled_endpoint_timeout(rng, fresh_registry):
 
     g = gpt(vocab_size=11, d_model=16, n_layers=2, num_heads=2, max_len=64,
             compute_dtype="float32", learning_rate=0.01).init()
-    router = InferenceRouter(per_try_timeout_s=3.0, eject_backoff_s=0.1,
+    # no fixed reply budget while engines compile: _scale_timeouts sets
+    # the one the stall is detected by, from a warm dispatch on this box
+    router = InferenceRouter(per_try_timeout_s=60.0, eject_backoff_s=0.1,
                              max_attempts=4)
     gate = _Gate()
     fleet = _mk_gpt_fleet(g, router, n=2, hooks=[gate],
-                          request_timeout_s=3.0)
+                          request_timeout_s=60.0)
     try:
         prompt = rng.integers(0, 11, (1, 5))
         want = generate_eager(g, prompt, 16)
@@ -960,11 +962,14 @@ def test_mid_generation_kill_restarted_stream_matches_eager(rng,
 
     g = gpt(vocab_size=11, d_model=16, n_layers=2, num_heads=2, max_len=64,
             compute_dtype="float32", learning_rate=0.01).init()
-    router = InferenceRouter(per_try_timeout_s=1.5, eject_backoff_s=0.1,
+    # no fixed reply budget while engines compile (a cold dispatch
+    # under six xdist workers outlasts 1.5 s): _scale_timeouts sets the
+    # one the kill is detected by, from a warm dispatch on this box
+    router = InferenceRouter(per_try_timeout_s=60.0, eject_backoff_s=0.1,
                              max_attempts=4)
     gate = _Gate()
     fleet = _mk_gpt_fleet(g, router, n=2, hooks=[gate],
-                          request_timeout_s=1.5)
+                          request_timeout_s=60.0)
     try:
         prompt = rng.integers(0, 11, (1, 5))
         want = generate_eager(g, prompt, 16)

@@ -5,6 +5,7 @@ single-config single-chip vs DP×SP sequence-parallel equivalence.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -175,6 +176,196 @@ def test_generate_moe_and_sampling(rng):
     assert (g4 >= 0).all() and (g4 < 11).all()
     with pytest.raises(ValueError, match="max_len"):
         generate(net, prompt, max_new_tokens=100)
+
+
+# ------------------------------------------ one block body, every entry point
+#
+# TransformerBlockImpl writes its wiring once (``_block``) and its entry
+# points differ in where K/V are kept. The matrix holds each of them to
+# ``forward`` on the same tokens, at block level.
+
+B, T, D, HEADS, HD = 2, 7, 16, 2, 8
+BS, MB, NB = 4, 3, 8                    # pool: block size, blocks a row, blocks
+TABLE = [[3, 1, 5], [2, 6, 4]]          # distinct blocks, out of order on purpose
+LAG = 2                                 # row 1 runs this many positions behind
+POOLS = {"float": None, "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
+PAGED = ("decode_paged", "prefill_paged")
+ENTRIES = ("prefill", "decode_scalar", "decode_vector") + PAGED
+#: |hidden - forward's|: the cached entry points run a plain softmax where
+#: ``forward`` runs the flash kernels; a quantized pool adds its rounding
+#: (the bounds tests/test_continuous.py and tests/test_quantize.py held)
+TOL = {"float": 1e-5, "int8": 0.12, "fp8": 0.12}
+
+
+def _block(kind):
+    # capacity_factor = E: forward routes no-drop too, as serving always does
+    moe = dict(num_experts=4, capacity_factor=4.0) if kind == "moe" else {}
+    net = gpt(vocab_size=11, d_model=D, n_layers=1, num_heads=HEADS,
+              max_len=16, compute_dtype="float32", seed=3, **moe).init()
+    blk = net.impls[1]
+    return blk, net.params[blk.name]
+
+
+def _tokens():
+    """The matrix's [B, T, D] inputs, the same in every case. Top-1 routing
+    is a step function of the hidden state, so a quantized pool's rounding
+    may carry a token that sits on a boundary to another expert (the rng
+    fixture's first draw has one): this seed's tokens sit clear of them."""
+    return jnp.asarray(np.random.default_rng(0).standard_normal((B, T, D)),
+                       jnp.float32)
+
+
+def _pool(kind, rng=None):
+    """A layer's share of the paged pool; seeded garbage when ``rng``."""
+    dt = POOLS[kind]
+    shape = (NB, BS, HEADS, HD)
+    fill = (lambda s: rng.standard_normal(s)) if rng is not None else np.zeros
+    if dt is None:
+        return {n: jnp.asarray(fill(shape), jnp.float32) for n in "kv"}
+    pool = {n: jnp.asarray(fill(shape), jnp.float32).astype(dt) for n in "kv"}
+    pool.update({n + "_scale": jnp.asarray(np.abs(fill(shape[:3])),
+                                           jnp.float32) for n in "kv"})
+    return pool
+
+
+def _paged_step(blk, params, x_t, pool, pos, write_mask=None):
+    y, cache = blk.decode_step(params, x_t, {**pool, "table": jnp.asarray(
+        TABLE, jnp.int32)}, jnp.asarray(pos, jnp.int32), write_mask)
+    return y, {n: cache[n] for n in pool}
+
+
+def _hidden(entry, blk, params, x, pool_kind):
+    """``entry`` driven over all of ``x`` [B, T, D] → (the hidden state it
+    gave at every position [B, T, D], the K/V store it left)."""
+    if entry == "prefill":
+        return blk.prefill(params, x, blk.init_cache(B, MB * BS))
+    if entry == "prefill_paged":
+        table = jnp.asarray(TABLE, jnp.int32)
+        # chunks of 4, 3 and 1 tokens: row 1's first ends in a padding
+        # position, so it runs one behind and row 0 sits the last one out
+        first = jnp.asarray([[0, 1, 2, 3], [0, 1, 2, 3]], jnp.int32)
+        ok = jnp.asarray([[True] * 4, [True, True, True, False]])
+        ya, pool = blk.prefill_paged(params, x[:, :4], _pool(pool_kind),
+                                     table, first, ok)
+        rest = jnp.asarray([[4, 5, 6], [3, 4, 5]], jnp.int32)
+        tail = jnp.stack([x[0, 4:7], x[1, 3:6]])
+        yb, pool = blk.prefill_paged(params, tail, pool, table, rest,
+                                     jnp.ones((B, 3), bool))
+        last, pool = blk.prefill_paged(
+            params, x[:, 6:], pool, table, jnp.asarray([[6], [6]], jnp.int32),
+            jnp.asarray([[False], [True]]))
+        row1 = jnp.concatenate([ya[1, :3], yb[1], last[1]])
+        return jnp.stack([jnp.concatenate([ya[0], yb[0]]), row1]), pool
+    # one token a step; but for a scalar position, row 1 lags behind row 0
+    # (it writes position 0 again until its turn: the same K/V)
+    lag = 0 if entry == "decode_scalar" else LAG
+    store = _pool(pool_kind) if entry == "decode_paged" \
+        else blk.init_cache(B, MB * BS)
+    out = np.zeros((B, T, D), np.float32)
+    for step in range(T + lag):
+        pos = [min(step, T - 1), min(max(step - lag, 0), T - 1)]
+        x_t = jnp.stack([x[0, pos[0]], x[1, pos[1]]])
+        if entry == "decode_paged":
+            # both spellings of "every row writes"
+            mask = None if step % 2 else jnp.ones(B, bool)
+            y, store = _paged_step(blk, params, x_t, store, pos, mask)
+        else:
+            at = step if entry == "decode_scalar" else jnp.asarray(pos)
+            y, store = blk.decode_step(params, x_t, store, at)
+        out[0, pos[0]], out[1, pos[1]] = np.asarray(y)
+    return out, store
+
+
+def _gathered(pool, name):
+    """Each row's blocks of a float pool in causal order [B, MB*BS, h, hd]."""
+    return np.asarray(pool[name])[np.asarray(TABLE)].reshape(
+        B, MB * BS, HEADS, HD)
+
+
+@pytest.mark.parametrize("entry,kind,pool_kind", [
+    (e, k, p) for e in ENTRIES for k in ("dense", "moe")
+    for p in (POOLS if e in PAGED else ("float",))])
+def test_entry_point_matches_forward(entry, kind, pool_kind):
+    """The hidden state at every prefix position is ``forward``'s on the
+    same tokens, wherever the entry point keeps K and V."""
+    blk, params = _block(kind)
+    x = _tokens()
+    want = np.asarray(blk.forward(params, x, blk.init_state(), False)[0])
+    got, store = _hidden(entry, blk, params, x, pool_kind)
+    if entry == "prefill":
+        # the same attention call and the same FFN: to the bit
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert not np.asarray(store["k"])[:, T:].any()
+    else:
+        np.testing.assert_allclose(np.asarray(got), want, rtol=TOL[pool_kind],
+                                   atol=TOL[pool_kind])
+    if pool_kind != "float":
+        # the scatter's quantization is a pure function of what is written:
+        # a replay leaves the same pool and the same hidden state, to the bit
+        again, store2 = _hidden(entry, blk, params, x, pool_kind)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
+        for name in store:
+            np.testing.assert_array_equal(np.asarray(store[name]),
+                                          np.asarray(store2[name]))
+    elif entry in PAGED:
+        # the pool holds exactly the dense cache's rows, block-permuted
+        _, dense = _hidden("decode_vector", blk, params, x, "float")
+        for name in "kv":
+            np.testing.assert_allclose(
+                _gathered(store, name)[:, :T], np.asarray(dense[name])[:, :T],
+                rtol=0, atol=0 if entry == "decode_paged" else 1e-6)
+
+
+@pytest.mark.parametrize("entry", PAGED)
+@pytest.mark.parametrize("pool_kind", POOLS)
+def test_masked_rows_write_the_trash_block_only(rng, entry, pool_kind):
+    """A position whose write is masked lands in block 0 and nowhere else:
+    every other slot of the pool, its scales too, keeps its bits, and the
+    unmasked positions' slots are written."""
+    blk, params = _block("dense")
+    before = _pool(pool_kind, rng)
+    table = jnp.asarray(TABLE, jnp.int32)
+    if entry == "decode_paged":
+        x = jnp.asarray(rng.standard_normal((B, D)), jnp.float32)
+        _, after = _paged_step(blk, params, x, before, [5, 6],
+                               jnp.asarray([True, False]))
+        written = {(TABLE[0][5 // BS], 5 % BS)}
+    else:
+        x = jnp.asarray(rng.standard_normal((B, 3, D)), jnp.float32)
+        pos = jnp.asarray([[4, 5, 6], [9, 10, 11]], jnp.int32)
+        ok = jnp.asarray([[True, True, False], [False, False, False]])
+        _, after = blk.prefill_paged(params, x, before, table, pos, ok)
+        written = {(TABLE[0][1], 0), (TABLE[0][1], 1)}
+    assert set(after) == set(before)
+    for name in before:
+        old = np.asarray(before[name]).astype(np.float32)
+        new = np.asarray(after[name]).astype(np.float32)
+        changed = {(int(blk_), int(off)) for blk_, off in
+                   zip(*np.nonzero((old != new).reshape(NB, BS, -1).any(-1)))}
+        assert written <= changed, name
+        assert changed - written <= {(0, 0)}, name
+
+
+@pytest.mark.parametrize("kind", ("dense", "moe"))
+@pytest.mark.parametrize("pool_kind", POOLS)
+def test_paged_decode_step_is_prefill_paged_at_one_token(rng, kind, pool_kind):
+    """``decode_step`` over a block table and ``prefill_paged`` run one
+    function: at ``t = 1`` they leave the same pool, to the bit, and give
+    the same hidden state."""
+    blk, params = _block(kind)
+    table = jnp.asarray(TABLE, jnp.int32)
+    step_pool = tail_pool = _pool(pool_kind)
+    for pos in ([0, 0], [1, 0], [2, 1], [3, 2]):
+        x = jnp.asarray(rng.standard_normal((B, D)), jnp.float32)
+        mask = jnp.asarray([True, pos[1] != 0 or pos[0] == 0])
+        y, step_pool = _paged_step(blk, params, x, step_pool, pos, mask)
+        y2, tail_pool = blk.prefill_paged(
+            params, x[:, None], tail_pool, table,
+            jnp.asarray(pos, jnp.int32)[:, None], mask[:, None])
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y2[:, 0]))
+        for name in step_pool:
+            np.testing.assert_array_equal(np.asarray(step_pool[name]),
+                                          np.asarray(tail_pool[name]))
 
 
 def test_embedding_rejects_overlong(rng):

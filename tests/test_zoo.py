@@ -2,8 +2,8 @@
 
 Parity: the reference's ResNet-50-class capability is "ComputationGraph
 + conv helpers" (``ComputationGraph.java:677``,
-``CudnnConvolutionHelper.java:51``). The full 50-layer graph is
-exercised on the TPU by bench.py; here a 1/1/1/1-stage bottleneck
+``CudnnConvolutionHelper.java:51``). No benchmark cell runs the full
+50-layer graph (PERF.md §3); here a 1/1/1/1-stage bottleneck
 variant proves the block wiring (projection shortcuts, zero-init last
 BN, strided 3x3) on the CPU mesh cheaply.
 """
