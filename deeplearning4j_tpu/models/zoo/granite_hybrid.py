@@ -7,7 +7,13 @@ tied to the embedding with its logits divided by ``logits_scaling``.
 
 Training only: the blocks have no cache (``nn/layers/hybrid.py``). Block
 bodies are recomputed in the backward pass unless ``recompute_blocks=False``:
-a Mamba-2 block keeps about 150 KB a token otherwise.
+a Mamba-2 block keeps about 150 KB a token otherwise. Recomputed, a block
+keeps its input (``2 * d`` bytes a token in bfloat16: 4 KB at d 2048) and
+the gated MLP's wide product ``h @ W_gate_up`` (``4 * f`` bytes: 32 KB at f
+8192), the dearest value of the body to make again; the attention block also
+keeps its flash kernel's output and lse (``2 * d`` bytes and 4 bytes a head).
+Everything else runs again: the Mamba-2 in-projection, the convolution, the
+scan, the gate and the norms.
 """
 
 from __future__ import annotations

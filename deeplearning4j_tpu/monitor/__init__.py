@@ -121,6 +121,10 @@ SSD_PATH_COUNTER = "dl4j_ssd_path_total"
 # nn/multilayer.py: blocks whose bodies the train step just built recomputes
 # in its backward pass (conf.recompute_blocks); 0 for a model that keeps them
 RECOMPUTED_BLOCKS_GAUGE = "dl4j_recomputed_blocks"
+# nn/multilayer.py: named values those blocks keep beside their inputs
+# (LayerImpl.kept_names, summed over the recomputed blocks); 0 where every
+# recomputed body runs again whole
+RECOMPUTE_KEPT_VALUES_GAUGE = "dl4j_recompute_kept_values"
 
 # Serving plane (parallel/inference.py ParallelInference — the
 # micro-batching engine behind StreamingInference): request/batch

@@ -90,7 +90,12 @@ class NeuralNetConfiguration:
     # run each block layer's forward again in the backward pass of the
     # train step instead of keeping its activations (jax.checkpoint around
     # the layers whose impl says ``recomputable``): a model whose
-    # activations would not fit beside its training state
+    # activations would not fit beside its training state. Kept are the
+    # block's input (2 * d_model bytes a token in bfloat16) and the values
+    # the impl names (``kept_names``), which the second run does not make
+    # again: for the hybrid family's blocks the gated MLP's wide product,
+    # 4 * ffn_hidden bytes a token a block, and for its attention block the
+    # flash kernel's output and lse, 2 * d_model bytes and 4 bytes a head
     recompute_blocks: bool = False
 
     def updater_config_for(self, layer: L.Layer) -> UpdaterConfig:

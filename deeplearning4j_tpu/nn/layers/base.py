@@ -98,6 +98,11 @@ class LayerImpl:
     #: (``NeuralNetConfiguration.recompute_blocks``)
     recomputable = False
 
+    #: the ``jax.ad_checkpoint.checkpoint_name``s of the values of its
+    #: forward that a recomputed block keeps beside its input, so that the
+    #: second run does not make them again; empty: the input alone
+    kept_names = ()
+
     def cast_params(self, params, dtype):
         """The layer's parameters as its forward takes them under a
         half-precision compute policy: every float leaf cast, unless the

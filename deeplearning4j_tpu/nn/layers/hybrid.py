@@ -17,13 +17,19 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.attention import dispatch_attention
 from deeplearning4j_tpu.nn.layers.base import LayerImpl, register_impl
 from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.ops.flash_attention import FLASH_RESIDUAL_NAMES
 from deeplearning4j_tpu.ops.ssd import ssd_scan
 from deeplearning4j_tpu.util.dtypes import cast_floats
+
+
+#: the ``checkpoint_name`` of the gated MLP's wide product ``h @ W_gate_up``
+GATE_UP_PRODUCT = "mlp_gate_up_product"
 
 
 class TrainingOnlyError(NotImplementedError):
@@ -52,6 +58,10 @@ class GatedDecoderImpl(LayerImpl):
 
     #: the container may recompute this layer's forward in the backward pass
     recomputable = True
+    #: and then keeps the gated MLP's wide product, the dearest value of the
+    #: body to make again: 4 * ffn_hidden bytes a token in bfloat16 beside
+    #: the 2 * d_model of the block's input
+    kept_names = (GATE_UP_PRODUCT,)
 
     def _matrix(self, key, shape):
         c = self.conf
@@ -86,7 +96,8 @@ class GatedDecoderImpl(LayerImpl):
         with jax.named_scope("rms2"):
             h = rms_norm(x, params["rms2_g"], c.rms_eps)
         with jax.named_scope("mlp_gate_up"):
-            a, b = jnp.split(h @ params["W_gate_up"], 2, axis=-1)
+            a, b = jnp.split(checkpoint_name(h @ params["W_gate_up"],
+                                             GATE_UP_PRODUCT), 2, axis=-1)
             h = jax.nn.silu(a) * b
         with jax.named_scope("mlp_down"):
             h = h @ params["W_down"]
@@ -178,6 +189,10 @@ class Mamba2BlockImpl(GatedDecoderImpl):
 
 @register_impl(L.GroupedQueryBlock)
 class GroupedQueryBlockImpl(GatedDecoderImpl):
+    #: and what the flash kernels' backward reads of their forward: the
+    #: output (2 * d_model bytes a token) and the lse (4 bytes a head)
+    kept_names = GatedDecoderImpl.kept_names + FLASH_RESIDUAL_NAMES
+
     def _mixer_params(self, key):
         c = self.conf
         if c.n_out % c.num_heads or c.num_heads % c.num_kv_heads:

@@ -43,6 +43,7 @@ from deeplearning4j_tpu.nn.updater import (
     normalize_gradient,
 )
 from deeplearning4j_tpu.monitor import (H2D_BYTES_COUNTER,
+                                        RECOMPUTE_KEPT_VALUES_GAUGE,
                                         RECOMPUTED_BLOCKS_GAUGE, get_registry,
                                         span)
 from deeplearning4j_tpu.nn.observed import SyncedStateAttr
@@ -220,8 +221,12 @@ class MultiLayerNetwork:
 
             if train and self._recomputes(impl):
                 # the block's body runs again in the backward pass: what is
-                # kept is its input (and the float32 leaves, cast inside)
-                layer = jax.checkpoint(layer)
+                # kept is its input (and the float32 leaves, cast inside) and
+                # the values the block names, which the second run then
+                # does not make again
+                layer = jax.checkpoint(layer, policy=(
+                    jax.checkpoint_policies.save_only_these_names(
+                        *impl.kept_names) if impl.kept_names else None))
             lrng = jax.random.fold_in(rng, i) if rng is not None else None
             x, new_states[impl.name] = layer(
                 params[impl.name], x, states[impl.name], lrng)
@@ -252,10 +257,15 @@ class MultiLayerNetwork:
 
     def _make_train_step(self, has_fmask: bool, has_lmask: bool):
         """One fully-fused optimization iteration."""
+        recomputed = [impl for impl in self.impls if self._recomputes(impl)]
         get_registry().gauge(
             RECOMPUTED_BLOCKS_GAUGE, "block layers whose bodies the train "
             "step just built runs again in its backward pass").set(
-            sum(self._recomputes(impl) for impl in self.impls))
+            len(recomputed))
+        get_registry().gauge(
+            RECOMPUTE_KEPT_VALUES_GAUGE, "named values those blocks keep "
+            "beside their inputs, which their second run does not make "
+            "again").set(sum(len(impl.kept_names) for impl in recomputed))
         gn_specs = []
         for impl in self.impls:
             nt = GradientNormalization(self.gc.resolve(impl.conf, "gradient_normalization"))

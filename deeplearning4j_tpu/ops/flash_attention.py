@@ -151,6 +151,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -434,8 +435,21 @@ def _flash_streamed(q, k, v, causal, block_q, block_k, interpret):
     return o
 
 
+#: the names (``jax.ad_checkpoint.checkpoint_name``) of what a forward rule
+#: hands its backward beside q, k and v: a ``jax.checkpoint`` whose policy
+#: saves them does not run the forward kernel again. Named inside the rules:
+#: the backward reads the rule's residuals, not the caller's copy of ``o``
+FLASH_RESIDUAL_NAMES = ("flash_o", "flash_lse")
+
+
+def _named_residuals(o, lse):
+    return tuple(checkpoint_name(z, name)
+                 for z, name in zip((o, lse), FLASH_RESIDUAL_NAMES))
+
+
 def _flash_streamed_fwd(q, k, v, causal, block_q, block_k, interpret):
-    o, lse = _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret)
+    o, lse = _named_residuals(
+        *_flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret))
     return o, (q, k, v, o, lse)
 
 
@@ -785,8 +799,9 @@ def _flash_resident(q, k, v, heads, causal, interpret):
 
 
 def _flash_resident_fwd(q, k, v, heads, causal, interpret):
-    o, lse = _resident_fwd(q, k, v, (0, 0, 0), heads, q.shape[2] // heads,
-                           causal, _resident_block(q.shape[1]), interpret)
+    o, lse = _named_residuals(*_resident_fwd(
+        q, k, v, (0, 0, 0), heads, q.shape[2] // heads, causal,
+        _resident_block(q.shape[1]), interpret))
     return o, (q, k, v, o, lse)
 
 
@@ -812,6 +827,8 @@ def _flash_resident_qkv(qkv, heads, causal, interpret):
 
 
 def _flash_resident_qkv_fwd(qkv, heads, causal, interpret):
+    # o and lse are not named here: no recomputable block calls this rule,
+    # and a name renumbers the private functions of the lowered GPT step
     o, lse = _resident_fwd(qkv, qkv, qkv, _qkv_cols(qkv), heads,
                            qkv.shape[2] // (3 * heads), causal,
                            _resident_block(qkv.shape[1]), interpret)

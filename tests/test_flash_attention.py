@@ -162,6 +162,42 @@ def test_causal_gradients_over_several_blocks(rng, small_blocks, path, d):
                                    rtol=2e-4, atol=2e-4)
 
 
+def _kernel_calls(jaxpr, name):
+    """How many ``pallas_call``s named ``name`` a jaxpr holds, at any depth."""
+    from jax._src import core
+    return sum(
+        name == eqn.params["name"] if eqn.primitive.name == "pallas_call"
+        else sum(_kernel_calls(sub, name)
+                 for sub in core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_checkpoint_that_keeps_the_named_residuals_runs_no_second_forward(
+        rng, path):
+    """Under a ``jax.checkpoint`` whose policy saves ``FLASH_RESIDUAL_NAMES``
+    the backward pass reads the kept o and lse: the gradients are those of
+    the call without a checkpoint, and the forward kernel is in the gradient's
+    program once, where a plain checkpoint puts it twice."""
+    q, k, v = _qkv(rng, b=1, tq=64, tk=64, h=_pairs(path, 1, 32), d=32)
+    attend = _via(path, True)
+    keeps = jax.checkpoint(attend, policy=jax.checkpoint_policies
+                           .save_only_these_names(*FA.FLASH_RESIDUAL_NAMES))
+    for got, want in zip(_grads(keeps, q, k, v), _grads(attend, q, k, v)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    backward = lambda f: jax.make_jaxpr(
+        lambda *a: _grads(f, *a))(q, k, v).jaxpr
+    assert _kernel_calls(backward(attend), "flash_fwd") == 1
+    assert _kernel_calls(backward(keeps), "flash_fwd") == 1
+    assert _kernel_calls(backward(jax.checkpoint(attend)), "flash_fwd") == 2
+    # outside a rule a name keeps nothing: the backward reads the rule's own
+    # residuals, not the caller's copy of the output
+    outside = jax.checkpoint(
+        lambda *a: jax.ad_checkpoint.checkpoint_name(attend(*a), "o"),
+        policy=jax.checkpoint_policies.save_only_these_names("o"))
+    assert _kernel_calls(backward(outside), "flash_fwd") == 2
+
+
 @pytest.mark.parametrize("heads", [1, 2])
 @pytest.mark.parametrize("causal", [False, True])
 def test_paths_agree(rng, small_blocks, causal, heads):

@@ -5,6 +5,8 @@ the whole tiny model against the plain reference
 (``benchmarks/reference/granite_hybrid_plain.py``); the tied head's one leaf;
 recomputed block bodies; the keys that the GPT family's classes gained."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +18,7 @@ from deeplearning4j_tpu import monitor
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.models.zoo.granite_hybrid import granite_hybrid
 from deeplearning4j_tpu.models.zoo.transformer import gpt
+from deeplearning4j_tpu.nn.layers import hybrid
 from deeplearning4j_tpu.nn.layers.hybrid import TrainingOnlyError
 from deeplearning4j_tpu.nn.multilayer import HYBRID_STEP_SCOPES
 from deeplearning4j_tpu.ops import ssd
@@ -242,18 +245,53 @@ def _fit(net, steps=3):
     return net.fit_scan(None, 2, staged=staged)
 
 
-def test_recomputed_block_bodies_change_no_loss_and_no_update():
-    on, off = _net(recompute_blocks=True), _net(recompute_blocks=False)
-    _with_reference_weights(on)
-    _with_reference_weights(off)
-    np.testing.assert_allclose(_fit(on), _fit(off), rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(on.params), jax.tree.leaves(off.params)):
+def _keep_nothing(patch):
+    """The recomputable blocks as they were before they named anything: the
+    block's input is all that a recomputed body keeps."""
+    patch.setattr(hybrid.GatedDecoderImpl, "kept_names", ())
+    patch.setattr(hybrid.GroupedQueryBlockImpl, "kept_names", ())
+
+
+#: program -> (losses of three steps, the parameters after them)
+_THREE_STEPS = {}
+
+
+def _three_steps(program):
+    if program not in _THREE_STEPS:
+        with pytest.MonkeyPatch.context() as patch:
+            if program == "nothing kept":
+                _keep_nothing(patch)
+            net = _net(recompute_blocks=program != "recomputation off")
+            _with_reference_weights(net)
+            _THREE_STEPS[program] = (_fit(net), jax.tree.leaves(net.params))
+    return _THREE_STEPS[program]
+
+
+@pytest.mark.parametrize("one,other", [
+    ("the names kept", "recomputation off"),
+    ("nothing kept", "recomputation off"),
+    ("the names kept", "nothing kept")])
+def test_recomputed_block_bodies_change_no_loss_and_no_update(one, other):
+    """Three programs, one arithmetic: what a recomputed body keeps is what
+    its second run would have made."""
+    (losses, params), (want_losses, want) = (_three_steps(one),
+                                             _three_steps(other))
+    assert len(losses) == 3
+    np.testing.assert_allclose(losses, want_losses, rtol=1e-6)
+    for a, b in zip(params, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+
+
+def test_the_gauges_say_what_the_step_recomputes_and_keeps():
     reg = monitor.get_registry()
+    value = lambda name: reg.get(name).value
     _fit(_net(recompute_blocks=True).init(), 1)
-    assert reg.get(monitor.RECOMPUTED_BLOCKS_GAUGE).value == 3
+    assert value(monitor.RECOMPUTED_BLOCKS_GAUGE) == 3
+    # the gate/up product of each block and the attention kernel's o and lse
+    assert value(monitor.RECOMPUTE_KEPT_VALUES_GAUGE) == 3 + 2
     _fit(_net(recompute_blocks=False).init(), 1)
-    assert reg.get(monitor.RECOMPUTED_BLOCKS_GAUGE).value == 0
+    assert value(monitor.RECOMPUTED_BLOCKS_GAUGE) == 0
+    assert value(monitor.RECOMPUTE_KEPT_VALUES_GAUGE) == 0
 
 
 def _lowered(net, steps=2, debug_info=True):
@@ -273,6 +311,30 @@ def test_recomputation_is_in_the_hybrid_step_and_names_its_parts():
             continue  # no normalization here; attention at 16 takes XLA's form
         assert f"{scope}" in text, scope
     assert "checkpoint" not in _lowered(_net(recompute_blocks=False).init())
+
+
+def _forward_products(text, d, width):
+    """How often a lowered step contracts [.., d] with a [d, width] matrix:
+    the shape of a projection's forward alone (its two gradients contract
+    over the tokens and over ``width``)."""
+    return len(re.findall(
+        rf"\(tensor<\d+x\d+x{d}x\w+>, tensor<{d}x{width}x\w+>\) -> ", text))
+
+
+def test_a_recomputed_body_makes_the_gate_up_product_once(monkeypatch):
+    """The second forward of a block no longer holds ``h @ W_gate_up``; the
+    Mamba-2 in-projection, which is not kept, is still made twice."""
+    d, f = TINY["hidden_size"], TINY["shared_intermediate_size"]
+    inner = TINY["mamba_n_heads"] * TINY["mamba_d_head"]
+    w_in = 2 * inner + 2 * TINY["mamba_d_state"] + TINY["mamba_n_heads"]
+    kinds = TINY["layer_types"]
+    text = _lowered(_net("bfloat16").init())
+    assert _forward_products(text, d, 2 * f) == len(kinds)
+    assert _forward_products(text, d, w_in) == 2 * kinds.count("mamba")
+    _keep_nothing(monkeypatch)
+    text = _lowered(_net("bfloat16").init())
+    assert _forward_products(text, d, 2 * f) == 2 * len(kinds)
+    assert _forward_products(text, d, w_in) == 2 * kinds.count("mamba")
 
 
 def test_gpt_step_is_the_same_program_without_the_new_seams(monkeypatch):
@@ -299,6 +361,25 @@ def test_gpt_step_is_the_same_program_without_the_new_seams(monkeypatch):
     net = make()
     net.gc.recompute_blocks = True
     assert _lowered(net, debug_info=False) == with_seams
+
+
+def test_a_recomputable_layer_that_names_nothing_is_checkpointed_plainly(
+        monkeypatch):
+    """No names, no policy: the step lowers to what the plain
+    ``jax.checkpoint(layer)`` round each block gave before blocks could name
+    values."""
+    _keep_nothing(monkeypatch)
+    with_seam = _lowered(_net("bfloat16").init(), debug_info=False)
+    policies = []
+    checkpoint = jax.checkpoint
+
+    def plain_checkpoint(fun, policy=None):
+        policies.append(policy)
+        return checkpoint(fun)
+
+    monkeypatch.setattr(jax, "checkpoint", plain_checkpoint)
+    assert _lowered(_net("bfloat16").init(), debug_info=False) == with_seam
+    assert policies == [None] * len(TINY["layer_types"])
 
 
 def test_gpt_family_keys_default_to_what_was():
