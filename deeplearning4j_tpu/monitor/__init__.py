@@ -125,6 +125,12 @@ RECOMPUTED_BLOCKS_GAUGE = "dl4j_recomputed_blocks"
 # (LayerImpl.kept_names, summed over the recomputed blocks); 0 where every
 # recomputed body runs again whole
 RECOMPUTE_KEPT_VALUES_GAUGE = "dl4j_recompute_kept_values"
+# nn/multilayer.py: times the train step just built runs its repeated span of
+# layers (MultiLayerConfiguration.repeat_span) on the same leaves; 0: no span
+SPAN_PASSES_GAUGE = "dl4j_span_passes"
+# nn/multilayer.py: applications of block layers (LayerImpl.recomputable) in
+# that step; a block of a repeated span counts once a pass
+BLOCK_APPLICATIONS_GAUGE = "dl4j_block_applications"
 
 # Serving plane (parallel/inference.py ParallelInference — the
 # micro-batching engine behind StreamingInference): request/batch

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -174,6 +174,13 @@ class MultiLayerConfiguration:
     tbptt_fwd_length: int = 20
     tbptt_back_length: int = 20
     input_type: Optional[InputType] = None
+    # a span of consecutive layers ``[first, end)`` that runs
+    # ``repeat_count`` times a forward pass, every pass on the same leaves
+    # (a leaf's gradient is the sum over its uses); it ends at the output
+    # layer, which is handed the span's output after the last pass, or
+    # after every pass if it scores them all (``ExitGateOutputLayer``)
+    repeat_span: Optional[Tuple[int, int]] = None
+    repeat_count: int = 1
 
     def to_json(self) -> str:
         d = {
@@ -187,6 +194,9 @@ class MultiLayerConfiguration:
             "tbptt_back_length": self.tbptt_back_length,
             "input_type": self.input_type.to_dict() if self.input_type else None,
         }
+        if self.repeat_span is not None:
+            d["repeat_span"] = list(self.repeat_span)
+            d["repeat_count"] = self.repeat_count
         return json.dumps(d, indent=2)
 
     @staticmethod
@@ -203,6 +213,8 @@ class MultiLayerConfiguration:
             tbptt_fwd_length=d.get("tbptt_fwd_length", 20),
             tbptt_back_length=d.get("tbptt_back_length", 20),
             input_type=InputType.from_dict(d["input_type"]) if d.get("input_type") else None,
+            repeat_span=tuple(d["repeat_span"]) if d.get("repeat_span") else None,
+            repeat_count=d.get("repeat_count", 1),
         )
 
     def to_yaml(self) -> str:
@@ -231,6 +243,8 @@ class ListBuilder:
         self._backprop_type = BackpropType.STANDARD
         self._tbptt_fwd = 20
         self._tbptt_back = 20
+        self._repeat_span: Optional[Tuple[int, int]] = None
+        self._repeat_count = 1
 
     def layer(self, index_or_layer, maybe_layer: Optional[L.Layer] = None) -> "ListBuilder":
         layer = maybe_layer if maybe_layer is not None else index_or_layer
@@ -265,6 +279,12 @@ class ListBuilder:
         self._tbptt_back = n
         return self
 
+    def repeat_span(self, first: int, end: int, times: int) -> "ListBuilder":
+        """Run layers ``first .. end - 1`` ``times`` times a forward pass,
+        on the same parameters; ``end`` is the output layer's index."""
+        self._repeat_span, self._repeat_count = (int(first), int(end)), int(times)
+        return self
+
     def build(self) -> MultiLayerConfiguration:
         import copy
 
@@ -280,6 +300,8 @@ class ListBuilder:
             tbptt_fwd_length=self._tbptt_fwd,
             tbptt_back_length=self._tbptt_back,
             input_type=self._input_type,
+            repeat_span=self._repeat_span,
+            repeat_count=self._repeat_count,
         )
         if self._input_type is not None:
             _auto_wire(mlc)

@@ -122,6 +122,23 @@ class RnnOutputLayer(FeedForwardLayer):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class ExitGateOutputLayer(RnnOutputLayer):
+    """The head of a net whose repeated span
+    (``MultiLayerConfiguration.repeat_span``) hands it the span's output
+    after every pass: the same ``W`` scores each of the ``R`` outputs, and a
+    learned gate ``lambda_s = sigmoid(h_s w_gate + b_gate)`` gives every
+    token a distribution over the pass at which to exit, ``p_s = lambda_s
+    prod_{j<s} (1 - lambda_j)`` with the last pass taking what is left. The
+    score is the mean over tokens of ``sum_s p_s CE_s - entropy_weight *
+    H(p)``; ``output()`` gives the last pass's prediction (its activation
+    over its logits, as every head's). With one pass it is the plain
+    softmax head."""
+
+    entropy_weight: float = 0.05
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class LossLayer(Layer):
     """``nn/conf/layers/LossLayer.java`` — loss without params (identity
     or activation-only forward)."""
@@ -286,6 +303,13 @@ class GatedDecoderBlock(FeedForwardLayer):
     ffn_hidden: int = 0
     rms_eps: float = 1e-5
     residual_multiplier: float = 1.0
+    # a second RMSNorm on each branch's OUTPUT, before the residual add (the
+    # sandwich block: ``x + norm(mixer(norm(x)))``); two more gains a block
+    branch_norms: bool = False
+    # what a recomputed body keeps beside its input (``checkpoint_name``s of
+    # nn/layers/hybrid.py); None: what the block's class keeps. A block that
+    # runs several times a step holds each kept value once an application
+    kept_values: Optional[Tuple[str, ...]] = None
 
 
 @register_layer
@@ -310,13 +334,16 @@ class Mamba2Block(GatedDecoderBlock):
 @dataclasses.dataclass(frozen=True)
 class GroupedQueryBlock(GatedDecoderBlock):
     """Causal self-attention with ``num_kv_heads`` key/value heads under
-    ``num_heads`` query heads, no positions and no biases, and the gated
-    MLP. Scores are scaled by ``attention_multiplier`` (``None``: the
-    usual 1/sqrt(head width)). n_in == n_out == d_model."""
+    ``num_heads`` query heads, no biases, and the gated MLP. Scores are
+    scaled by ``attention_multiplier`` (``None``: the usual 1/sqrt(head
+    width)). ``rope_theta`` turns q and k by rotary positions of that base
+    over the whole head (``ops/attention.rotary``); ``None``: no positions.
+    n_in == n_out == d_model."""
 
     num_heads: int = 8
     num_kv_heads: int = 8
     attention_multiplier: Optional[float] = None
+    rope_theta: Optional[float] = None
 
 
 @register_layer

@@ -103,6 +103,11 @@ class LayerImpl:
     #: second run does not make them again; empty: the input alone
     kept_names = ()
 
+    #: True for an output layer whose ``score`` takes the output of a
+    #: repeated span (``MultiLayerConfiguration.repeat_span``) after EVERY
+    #: pass, as a list; any other head is handed the last pass's
+    scores_every_pass = False
+
     def cast_params(self, params, dtype):
         """The layer's parameters as its forward takes them under a
         half-precision compute policy: every float leaf cast, unless the

@@ -19,7 +19,8 @@ from deeplearning4j_tpu.nn.layers.base import LayerImpl, register_impl, apply_dr
 from deeplearning4j_tpu.nn.quantize import is_quantized, qmatmul, qtake
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import Activation, activate
-from deeplearning4j_tpu.ops.losses import LossFunction, compute_loss
+from deeplearning4j_tpu.ops.losses import (LossFunction, _masked_mean,
+                                           compute_loss)
 
 
 def _fused_logits_pair(activation: str, loss_function: str) -> bool:
@@ -138,6 +139,74 @@ class RnnOutputImpl(OutputImpl):
     """Per-timestep output over [b, t, f] inputs
     (``nn/layers/recurrent/RnnOutputLayer.java``); the label mask is
     [b, t]. The dense transform broadcasts over the time axis."""
+
+
+@register_impl(L.ExitGateOutputLayer)
+class ExitGateOutputImpl(RnnOutputImpl):
+    """The head over the ``R`` pass outputs of a repeated span: ``score``
+    takes them as a sequence (one array: one pass), ``forward`` the last
+    one. Logits, the per-token cross-entropies, the gate and the exit
+    distribution are float32 whatever the compute dtype."""
+
+    #: the container hands ``score`` the span's output after every pass
+    scores_every_pass = True
+
+    def init_params(self, key):
+        params = super().init_params(key)
+        c = self.conf
+        params["w_gate"] = init_weights(
+            jax.random.fold_in(key, 1), (c.n_in, 1), self.weight_init, c.n_in,
+            1, c.dist_mean, c.dist_std, dist=c.dist)
+        params["b_gate"] = jnp.zeros((1,), jnp.float32)
+        return params
+
+    def cast_params(self, params, dtype):
+        # the gate's bias is added to a float32 product
+        return {**super().cast_params(params, dtype),
+                "b_gate": params["b_gate"]}
+
+    def _token_losses(self, params, x, ids):
+        """Cross-entropy of every token [b, t] from the float32 logits."""
+        with jax.named_scope("lm_head"):
+            z = self.preout(params, x)
+        with jax.named_scope("loss"):
+            # flattened before the gather, as ops/losses.compute_loss does
+            z2 = z.reshape(-1, z.shape[-1]).astype(jnp.float32)
+            tgt = jnp.take_along_axis(z2, ids.reshape(-1, 1), axis=1)[:, 0]
+            return (jax.scipy.special.logsumexp(z2, axis=-1)
+                    - tgt).reshape(ids.shape)
+
+    def _gate_logit(self, params, x):
+        with jax.named_scope("exit_gate"):
+            z = jnp.matmul(x, params["w_gate"],
+                           preferred_element_type=jnp.float32)[..., 0]
+            return z + params["b_gate"].astype(jnp.float32)
+
+    def score(self, params, x, labels, state, train, rng=None, mask=None):
+        if not _fused_logits_pair(self.activation, self.loss_function) \
+                or labels.ndim != 2:
+            raise ValueError(
+                "ExitGateOutputLayer scores softmax + mcxent over sparse "
+                "token ids [b, t]")
+        passes = list(x) if isinstance(x, (list, tuple)) else [x]
+        ids = labels.astype(jnp.int32)
+        ces = jnp.stack([self._token_losses(params, h, ids) for h in passes])
+        with jax.named_scope("exit_loss"):
+            # log p_s = log lambda_s + sum_{j<s} log(1 - lambda_j); the last
+            # pass takes what is left, so its own gate is never read
+            stay = jnp.zeros(ids.shape, jnp.float32)
+            log_p = []
+            for h in passes[:-1]:
+                g = self._gate_logit(params, h)
+                log_p.append(jax.nn.log_sigmoid(g) + stay)
+                stay = stay + jax.nn.log_sigmoid(-g)
+            log_p = jnp.stack(log_p + [stay])
+            p = jnp.exp(log_p)
+            per_token = jnp.sum(p * (ces + self.conf.entropy_weight * log_p),
+                                axis=0)
+            if mask is None:
+                return jnp.mean(per_token)
+            return _masked_mean(per_token, mask)
 
 
 @register_impl(L.LossLayer)

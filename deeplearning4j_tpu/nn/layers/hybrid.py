@@ -3,7 +3,10 @@ grouped-query attention block, which share their RMSNorm, their gated MLP and
 their scaled residual branches, and the stand-alone RMSNorm before the head.
 
 No reference counterpart. Both blocks run ``x + m * mixer(norm(x))`` then
-``x + m * mlp(norm(x))``; the mixers differ. Norms, the gate and every decay
+``x + m * mlp(norm(x))``; the mixers differ. With ``branch_norms`` each
+branch's output takes a norm of its own before the add (the sandwich block),
+and with ``rope_theta`` the attention block turns q and k by rotary
+positions. Norms, the gate, the rotation and every decay
 run in float32 under a bfloat16 compute policy, as LayerNorm does in
 ``transformer.py``. Training only: ``init_cache`` / ``prefill`` /
 ``decode_step`` raise :class:`TrainingOnlyError`; a cache that holds a
@@ -23,6 +26,7 @@ from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.attention import dispatch_attention
 from deeplearning4j_tpu.nn.layers.base import LayerImpl, register_impl
 from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.ops.attention import rotary
 from deeplearning4j_tpu.ops.flash_attention import FLASH_RESIDUAL_NAMES
 from deeplearning4j_tpu.ops.ssd import ssd_scan
 from deeplearning4j_tpu.util.dtypes import cast_floats
@@ -62,6 +66,18 @@ class GatedDecoderImpl(LayerImpl):
     #: body to make again: 4 * ffn_hidden bytes a token in bfloat16 beside
     #: the 2 * d_model of the block's input
     kept_names = (GATE_UP_PRODUCT,)
+    #: what ``kept_values`` of the configuration may name in its place
+    KEEPABLE = (GATE_UP_PRODUCT,)
+
+    def __init__(self, global_conf, conf, name):
+        super().__init__(global_conf, conf, name)
+        if conf.kept_values is not None:
+            unknown = sorted(set(conf.kept_values) - set(self.KEEPABLE))
+            if unknown:
+                raise ValueError(
+                    f"{type(conf).__name__} ({name}) cannot keep {unknown}: "
+                    f"it names {list(self.KEEPABLE)}")
+            self.kept_names = tuple(conf.kept_values)
 
     def _matrix(self, key, shape):
         c = self.conf
@@ -83,6 +99,9 @@ class GatedDecoderImpl(LayerImpl):
             "W_gate_up": self._matrix(k_up, (d, 2 * f)),
             "W_down": self._matrix(k_down, (f, d)),
         })
+        if c.branch_norms:
+            params.update({"mixer_norm_g": jnp.ones((d,), jnp.float32),
+                           "mlp_norm_g": jnp.ones((d,), jnp.float32)})
         return params
 
     def forward(self, params, x, state, train, rng=None, mask=None):
@@ -92,7 +111,11 @@ class GatedDecoderImpl(LayerImpl):
         m = c.residual_multiplier
         with jax.named_scope("rms1"):
             h = rms_norm(x, params["rms1_g"], c.rms_eps)
-        x = x + (m * self._mixer(params, h, mask)).astype(x.dtype)
+        h = self._mixer(params, h, mask)
+        if c.branch_norms:
+            with jax.named_scope("mixer_out_norm"):
+                h = rms_norm(h, params["mixer_norm_g"], c.rms_eps)
+        x = x + (m * h).astype(x.dtype)
         with jax.named_scope("rms2"):
             h = rms_norm(x, params["rms2_g"], c.rms_eps)
         with jax.named_scope("mlp_gate_up"):
@@ -101,6 +124,9 @@ class GatedDecoderImpl(LayerImpl):
             h = jax.nn.silu(a) * b
         with jax.named_scope("mlp_down"):
             h = h @ params["W_down"]
+        if c.branch_norms:
+            with jax.named_scope("mlp_out_norm"):
+                h = rms_norm(h, params["mlp_norm_g"], c.rms_eps)
         out = x + (m * h).astype(x.dtype)
         if mask is not None:
             out = out * mask[:, :, None].astype(out.dtype)
@@ -192,6 +218,7 @@ class GroupedQueryBlockImpl(GatedDecoderImpl):
     #: and what the flash kernels' backward reads of their forward: the
     #: output (2 * d_model bytes a token) and the lse (4 bytes a head)
     kept_names = GatedDecoderImpl.kept_names + FLASH_RESIDUAL_NAMES
+    KEEPABLE = GatedDecoderImpl.KEEPABLE + FLASH_RESIDUAL_NAMES
 
     def _mixer_params(self, key):
         c = self.conf
@@ -220,6 +247,11 @@ class GroupedQueryBlockImpl(GatedDecoderImpl):
             q = q.reshape(b, t, heads, hd)
             if mult is not None:
                 q = (q * (mult * math.sqrt(hd))).astype(q.dtype)
+            if c.rope_theta is not None:
+                with jax.named_scope("rope"):
+                    q = rotary(q, c.rope_theta)
+                    k = rotary(k.reshape(b, t, kv, hd),
+                               c.rope_theta).reshape(b, t, kv * hd)
             with jax.named_scope("kv_repeat"):
                 # query head i reads key/value head i // (heads / kv)
                 rep = lambda z: jnp.repeat(z.reshape(b, t, kv, hd),

@@ -16,6 +16,7 @@ what checkpointing and the distributed parameter plane use.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
@@ -42,10 +43,11 @@ from deeplearning4j_tpu.nn.updater import (
     init_updater_state,
     normalize_gradient,
 )
-from deeplearning4j_tpu.monitor import (H2D_BYTES_COUNTER,
+from deeplearning4j_tpu.monitor import (BLOCK_APPLICATIONS_GAUGE,
+                                        H2D_BYTES_COUNTER,
                                         RECOMPUTE_KEPT_VALUES_GAUGE,
-                                        RECOMPUTED_BLOCKS_GAUGE, get_registry,
-                                        span)
+                                        RECOMPUTED_BLOCKS_GAUGE,
+                                        SPAN_PASSES_GAUGE, get_registry, span)
 from deeplearning4j_tpu.nn.observed import SyncedStateAttr
 from deeplearning4j_tpu.optimize.deferred import (
     count_jit_cache_miss,
@@ -75,6 +77,15 @@ HYBRID_STEP_SCOPES = (
     "mamba_out_proj", "qkv_proj", "kv_repeat", "attention", "attn_out_proj",
     "rms2", "mlp_gate_up", "mlp_down", "final_norm", "fold_heads",
     "unfold_heads")
+#: and for a looped language model (models/zoo/looped_lm.py): the attention
+#: block with rotary positions and a norm on each branch's output, the final
+#: norm inside the repeated span, the exit head's gate and its loss; each
+#: pass of the span also stands under a static ``pass<s>``
+LOOPED_STEP_SCOPES = (
+    "grad_norm", "optimizer_update", "lm_head", "loss", "exit_gate",
+    "exit_loss", "embed", "rms1", "qkv_proj", "rope", "kv_repeat",
+    "attention", "attn_out_proj", "mixer_out_norm", "rms2", "mlp_gate_up",
+    "mlp_down", "mlp_out_norm", "final_norm", "fold_heads", "unfold_heads")
 
 
 class MultiLayerNetwork:
@@ -101,6 +112,22 @@ class MultiLayerNetwork:
         if tied and tied not in [i.name for i in self.impls[:-1]]:
             raise ValueError(f"the head is tied to {tied!r}, which is no "
                              f"layer of this net")
+        # the layers before the head as they are applied: (index, pass). A
+        # repeated span runs ``repeat_count`` times on the same leaves and
+        # ends at the head, which is handed its output after every pass
+        n_last = len(self.impls) - 1
+        first, end = conf.repeat_span or (n_last, n_last)
+        self._span_passes = conf.repeat_count if conf.repeat_span else 0
+        if conf.repeat_span and not 0 <= first < end == n_last:
+            raise ValueError(
+                f"repeat_span {conf.repeat_span} must hold at least one "
+                f"layer and end at the head (layer {n_last})")
+        if conf.repeat_span and conf.repeat_count < 1:
+            raise ValueError("a repeated span runs at least once")
+        self._applications = [(i, 0) for i in range(first)] + [
+            (i, s) for s in range(max(1, self._span_passes))
+            for i in range(first, end)]
+        self._span_end = end if conf.repeat_span else None
         self.params: Optional[Params] = None
         self.states: Optional[Dict[str, Any]] = None
         self.opt_state: Optional[Dict[str, Any]] = None
@@ -170,13 +197,15 @@ class MultiLayerNetwork:
         return p
 
     def _forward(self, params: Params, states, x, train: bool, rng, fmask):
-        """All-layer forward; returns (activations per layer, new states)."""
-        acts = []
-        new_states = {}
+        """All-layer forward; returns (activations per layer, new states).
+        A layer of a repeated span reports its last pass."""
         n_last = len(self.impls) - 1
+        acts = [None] * (n_last + 1)
+        new_states = {}
         if self._cd is not None and self.impls[0].cast_input:
             x = x.astype(self._cd)
-        for i, impl in enumerate(self.impls):
+        for i, s in self._applications + [(n_last, 0)]:
+            impl = self.impls[i]
             pre = self.conf.input_preprocessors.get(i)
             if pre is not None:
                 x = pre(x)
@@ -192,13 +221,22 @@ class MultiLayerNetwork:
                         x = x.astype(jnp.float32)
                 else:
                     p = impl.cast_params(p, self._cd)
-            lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            x, ns = impl.forward(p, x, states[impl.name], train, lrng, mask=fmask)
+            x, ns = impl.forward(p, x, states[impl.name], train,
+                                 self._layer_rng(rng, i, s), mask=fmask)
             if self._cd is not None:
                 ns = cast_like(ns, states[impl.name])
             new_states[impl.name] = ns
-            acts.append(x)
+            acts[i] = x
         return acts, new_states
+
+    @staticmethod
+    def _layer_rng(rng, i: int, s: int):
+        """Layer ``i``'s key in pass ``s`` of its span: every pass draws its
+        own dropout."""
+        if rng is None:
+            return None
+        lrng = jax.random.fold_in(rng, i)
+        return jax.random.fold_in(lrng, s) if s else lrng
 
     def _score_fn(self, params: Params, states, x, y, train: bool, rng, fmask, lmask):
         """Data loss (output layer) + L1/L2 penalties — the quantity
@@ -206,42 +244,61 @@ class MultiLayerNetwork:
         new_states = {}
         if self._cd is not None and self.impls[0].cast_input:
             x = x.astype(self._cd)
-        for i, impl in enumerate(self.impls[:-1]):
+        passes = []  # a repeated span's output after each pass
+        layers = {}  # index -> the layer's call, made once: a span reuses it
+        for i, s in self._applications:
+            impl = self.impls[i]
             pre = self.conf.input_preprocessors.get(i)
             if pre is not None:
                 x = pre(x)
+            if i not in layers:
 
-            def layer(p, x, state, lrng, impl=impl):
-                if self._cd is not None:
-                    p = impl.cast_params(p, self._cd)
-                x, ns = impl.forward(p, x, state, train, lrng, mask=fmask)
-                if self._cd is not None:
-                    ns = cast_like(ns, state)
-                return x, ns
+                def layer(p, x, state, lrng, impl=impl):
+                    if self._cd is not None:
+                        p = impl.cast_params(p, self._cd)
+                    x, ns = impl.forward(p, x, state, train, lrng, mask=fmask)
+                    if self._cd is not None:
+                        ns = cast_like(ns, state)
+                    return x, ns
 
-            if train and self._recomputes(impl):
-                # the block's body runs again in the backward pass: what is
-                # kept is its input (and the float32 leaves, cast inside) and
-                # the values the block names, which the second run then
-                # does not make again
-                layer = jax.checkpoint(layer, policy=(
-                    jax.checkpoint_policies.save_only_these_names(
-                        *impl.kept_names) if impl.kept_names else None))
-            lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            x, new_states[impl.name] = layer(
-                params[impl.name], x, states[impl.name], lrng)
+                if train and self._recomputes(impl):
+                    # the block's body runs again in the backward pass: what
+                    # is kept is its input (and the float32 leaves, cast
+                    # inside) and the values the block names, which the
+                    # second run then does not make again
+                    layer = jax.checkpoint(layer, policy=(
+                        jax.checkpoint_policies.save_only_these_names(
+                            *impl.kept_names) if impl.kept_names else None))
+                if self._span_passes > 1:
+                    # traced and lowered once, applied once a pass
+                    layer = jax.jit(layer)
+                layers[i] = layer
+            layer = layers[i]
+            # the passes are unrolled, so each stands under a static name
+            scope = (jax.named_scope(f"pass{s}") if self._span_end
+                     else contextlib.nullcontext())
+            with scope:
+                x, new_states[impl.name] = layer(
+                    params[impl.name], x, states[impl.name],
+                    self._layer_rng(rng, i, s))
+            if i + 1 == self._span_end:
+                passes.append(x)
         i_out = len(self.impls) - 1
+        # a head that scores every pass of a repeated span takes them all
+        every = bool(passes) and self.out.scores_every_pass
+        heads_in = passes if every else [x]
         pre = self.conf.input_preprocessors.get(i_out)
         if pre is not None:
-            x = pre(x)
+            heads_in = [pre(h) for h in heads_in]
         p_out = self._params_of(params, self.out)
         if self._cd is not None:
             if "W" in p_out:  # bf16 head matmul, f32 logits (preout)
                 p_out = self.out.cast_params(p_out, self._cd)
             else:
-                x = x.astype(jnp.float32)  # loss always f32
+                heads_in = [h.astype(jnp.float32) for h in heads_in]  # loss always f32
         lrng = jax.random.fold_in(rng, i_out) if rng is not None else None
-        score = self.out.score(p_out, x, y, states[self.out.name], train, lrng, mask=lmask)
+        score = self.out.score(p_out, heads_in if every else heads_in[0], y,
+                               states[self.out.name], train, lrng, mask=lmask)
         new_states[self.out.name] = states[self.out.name]
         for impl in self.impls:
             score = score + impl.regularization_penalty(params[impl.name]).astype(score.dtype)
@@ -258,6 +315,7 @@ class MultiLayerNetwork:
     def _make_train_step(self, has_fmask: bool, has_lmask: bool):
         """One fully-fused optimization iteration."""
         recomputed = [impl for impl in self.impls if self._recomputes(impl)]
+        applied = [self.impls[i] for i, _ in self._applications]
         get_registry().gauge(
             RECOMPUTED_BLOCKS_GAUGE, "block layers whose bodies the train "
             "step just built runs again in its backward pass").set(
@@ -265,7 +323,17 @@ class MultiLayerNetwork:
         get_registry().gauge(
             RECOMPUTE_KEPT_VALUES_GAUGE, "named values those blocks keep "
             "beside their inputs, which their second run does not make "
-            "again").set(sum(len(impl.kept_names) for impl in recomputed))
+            "again, one an application of the block").set(
+            sum(len(impl.kept_names) for impl in applied
+                if self._recomputes(impl)))
+        get_registry().gauge(
+            SPAN_PASSES_GAUGE, "times the train step just built runs its "
+            "repeated span of layers on the same leaves; 0: no span").set(
+            self._span_passes)
+        get_registry().gauge(
+            BLOCK_APPLICATIONS_GAUGE, "applications of block layers in that "
+            "step: a block of a repeated span counts once a pass").set(
+            sum(impl.recomputable for impl in applied))
         gn_specs = []
         for impl in self.impls:
             nt = GradientNormalization(self.gc.resolve(impl.conf, "gradient_normalization"))

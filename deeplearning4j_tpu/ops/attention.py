@@ -12,6 +12,7 @@ Shapes follow [batch, time, heads, head_dim] throughout.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -36,6 +37,28 @@ def scaled_dot_product_attention(
         scores = jnp.where(mask[:, None, None, :] > 0, scores, neg)
     w = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
+
+
+def rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary positions on ``x`` [b, t, h, d], rotate-half over the whole
+    head: lane ``i`` of the first half pairs with lane ``i + d/2`` of the
+    second, and the pair at position ``p`` turns by ``p * theta ** (-2i/d)``.
+    Angles, sines and the rotation itself are float32 whatever ``x`` is (at
+    position 65k a bfloat16 angle is off by whole turns); the result takes
+    ``x``'s dtype. Token ``p`` of the row stands at position ``p``. Applied
+    to q and to k, it makes their scores depend on ``i - j`` alone."""
+    t, d = x.shape[1], x.shape[-1]
+    if d % 2:
+        raise ValueError(f"rotary positions need an even head width, got {d}")
+    half = d // 2
+    freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                   * (-2.0 * math.log(theta) / d))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def multi_head_attention(

@@ -446,6 +446,8 @@ KNOWN_DL4J_METRICS = {
     "dl4j_ssd_path_total",
     "dl4j_recomputed_blocks",
     "dl4j_recompute_kept_values",
+    "dl4j_span_passes",
+    "dl4j_block_applications",
     # serving plane (parallel/inference.py ParallelInference)
     "dl4j_infer_requests_total",
     "dl4j_infer_batches_total",

@@ -23,7 +23,8 @@ import numpy as np
 
 from deeplearning4j_tpu.monitor import (FLASH_PATH_COUNTER, SSD_PATH_COUNTER,
                                         get_registry)
-from deeplearning4j_tpu.nn.multilayer import HYBRID_STEP_SCOPES, STEP_SCOPES
+from deeplearning4j_tpu.nn.multilayer import (HYBRID_STEP_SCOPES,
+                                              LOOPED_STEP_SCOPES, STEP_SCOPES)
 from deeplearning4j_tpu.util import profiler
 from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 from deeplearning4j_tpu.util.device import device_peaks
@@ -120,9 +121,11 @@ def profile_cell(workload, seed, dispatches=3):
     with profiler.trace(log_dir):
         for _ in range(dispatches):
             r.dispatch()
-    hybrid = "layer_types" in cell["config"]
-    print_trace(log_dir, dispatches * r.k,
-                HYBRID_STEP_SCOPES if hybrid else STEP_SCOPES)
+    # the family by the key only its configurations have
+    scopes = (LOOPED_STEP_SCOPES if "total_ut_steps" in cell["config"]
+              else HYBRID_STEP_SCOPES if "layer_types" in cell["config"]
+              else STEP_SCOPES)
+    print_trace(log_dir, dispatches * r.k, scopes)
 
 
 def main():
