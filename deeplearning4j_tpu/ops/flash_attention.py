@@ -11,15 +11,19 @@ function of ``(tq, tk, d, dtype, heads)``; ``dl4j_flash_path_total{path=}``
 counts the choice once a traced call, and a device trace shows it as
 ``flash_dq_dkv`` against ``flash_dq`` + ``flash_dkv``):
 
-**Resident** — self-attention whose row fits VMEM (1k / head 64, 2k /
-head 128, every shorter length): one program a row, the row's whole q,
-k, v (and dO, o, lse) in VMEM, the block loop as straight-line code in
-the body. Dead blocks are not in the loop, only diagonal blocks carry
-the iota mask, the forward takes a plain softmax over the keys a block
-of queries sees (no running max, no rescaling), and the backward is ONE
-kernel that makes the scores once for dq, dk and dv (5 products and 1
-exp chain a block; the streamed pair makes 7 and 2) and delta =
-rowsum(dO·O) itself. The softmax scale is folded into q inside the body.
+**Resident** — self-attention whose row fits VMEM (up to 4k at 128 lanes
+a program: 1k / head 64, 2k and 4k / head 128, 4k / a pair of heads of 64,
+every shorter length): one program a row, the row's whole q,
+k, v (and dO, o, lse) in VMEM, the block loop in the body. Dead blocks
+are not in the loop, only diagonal blocks carry the iota mask, and the
+backward is ONE kernel that makes the scores once for dq, dk and dv (5
+products and 1 exp chain a block; the streamed pair makes 7 and 2) and
+delta = rowsum(dO·O) itself. The softmax scale is folded into q inside
+the body. Up to ``_UNROLLED_ROWS`` (2k) the loop is straight-line code
+and the forward takes a plain softmax over the keys a block of queries
+sees (no running max, no rescaling); longer rows (4k) run the same
+programs with the loops as ``fori_loop``s over dynamic slices and a
+running max in the forward ("looped": see the 4k readings below).
 
   A row is a COLUMN BLOCK of [b, t, features] arrays, addressed by the
   BlockSpecs (grid ``(b, column blocks)``). **Packed**
@@ -55,6 +59,8 @@ rowsum(dO·O) itself. The softmax scale is folded into q inside the body.
     24 x 2048 x 128    0.302 / 0.756           0.188 / 0.419
     512 x 256 x 64     0.629 / 0.732           0.286 / 0.435
     1024 x 128 x 64    0.618 / 1.012           0.446 / 0.607
+    32 x 4096 x 128    1.397 / 4.373           0.877 / 2.323   (PR 34,
+    64 x 4096 x 64     2.998 / 8.723           1.734 / 4.693    unrolled)
 
   One attention sublayer, ``x @ Wqkv`` -> attention -> ``@ Wo``,
   forward and backward, so that the copies round the kernels count
@@ -65,6 +71,40 @@ rowsum(dO·O) itself. The softmax scale is folded into q inside the body.
     8 x 16 x 1024 x 64  0.303 / 0.734   2.646   0.302 / 0.737  2.575   0.337 / 0.747  2.081
     32 x 16 x 256 x 64  0.299 / 0.448   2.371   0.297 / 0.439  2.211   0.265 / 0.456  1.722
     2 x 12 x 2048 x 128 0.187 / 0.418   1.919   0.188 / 0.422  1.878   0.201 / 0.466  1.802
+
+  The 4k rows with the UNROLLED body (PR 34; the parent's column is the
+  streamed kernels with their folds, which these shapes ran until then):
+
+    b x h x t x d       streamed, folded        folded, this body      packed
+    2 x 16 x 4096 x 128 1.397 / 4.373  10.746   0.877 / 2.323  7.905   0.877 / 3.028  7.897
+    2 x 32 x 4096 x 64  2.998 / 8.723  17.625   1.734 / 4.693 11.873   2.026 / 5.143 11.161
+
+  Those are the UNROLLED bodies at 4k (36 live blocks of 512), and they
+  are not what runs: Mosaic unrolls every array operation into vector
+  registers, so a kernel's code grows with t squared whatever the block:
+  0.72 + 1.10 MB and 8 + 11 s of compiling a call site at 4k / head 128
+  (1.45 + 2.25 MB, 24 + 28 s for a pair of 64) against 0.24 + 0.32 MB
+  and 1.7 + 2.6 s at 2k. A step that calls them 32 times (a looped
+  model: 8 blocks x 4 passes) grew from 130 to 190 MiB as an executable
+  and compiled 13-17 s longer, and the chip machines' compile cache
+  holds 192 MiB: no run of it was ever warm. So rows over
+  ``_UNROLLED_ROWS`` loop over their blocks (0.08 + 0.10 MB, under a
+  second to compile, the step's executable 140 MiB). Kernels alone,
+  three [b, t, h*d] gradients (what ``flash_attention(q, k, v)`` runs:
+  the looped and the hybrid cell's calls), fwd / dq_dkv:
+
+    b x h x t x d        streamed        unrolled        looped
+    2 x 16 x 4096 x 128  1.397 / 4.373   0.865 / 2.303   1.268 / 2.508
+    2 x 32 x 4096 x 64   2.998 / 8.723   2.024 / 4.304   2.520 / 4.927
+
+  The looped forward keeps a third of what the unrolled one wins (an
+  iteration's MXU and VPU phases do not overlap across the loop's
+  back-edge; asking for the next block's scores before this block's
+  softmax, the arrays carried through the loop, read 1.71 / 3.33 ms:
+  worse), the looped backward nine tenths. Looped at block 1024 read
+  1.233 / 2.471 ms (level), at 256 2.358 / 3.924. The one [b, t, 3*h*d]
+  gradient in three grid steps reads 2.603 ms looped at 4k / head 128
+  (3.028 unrolled) against 2.508 for three arrays.
 
   The pair program's kernels are 0 to 12% slower than the folded ones
   (a select a head and a block; column blocks are fetched in 4 KB
@@ -86,10 +126,14 @@ rowsum(dO·O) itself. The softmax scale is folded into q inside the body.
   2k / 128; forwards 0.324 and 0.187 against 0.333 and 0.189 before
   the lse became a row), block 128 three times slower; it unrolls
   into 10 blocks at 1k and 36 at 2k where 512 makes 3 and 10, and is
-  left to a later PR. The same body rolled into ``fori_loop``s over
-  blocks (dynamic slices, two bodies in all) read 16–22% slower than
-  the unrolled one (0.850 against 0.732 and 0.509 against 0.418 ms at
-  block 512). The forward writes lse as the [1, t] row the backward
+  left to a later PR. Unrolled at 4k (36 live blocks at block 512) the
+  forward read 0.865 ms at block 512 against 0.995 at 1024, the backward
+  2.400, 2.303 and 2.527 ms at 256, 512 and 1024 (head 128; a pair of
+  64: 2.024 / 2.224 and 4.503, 4.304, 4.782): 512 stays (PR 34). The
+  same body rolled into ``fori_loop``s over blocks (dynamic slices, two
+  bodies in all) read 16–22% slower than the unrolled one (0.850 against
+  0.732 and 0.509 against 0.418 ms at block 512), which is why rows up
+  to 2k stay unrolled. The forward writes lse as the [1, t] row the backward
   reads: as a [t, 1] column (the streamed layout) every value costs a
   128-lane tile in the kernel's store and in an XLA pass that repacks
   it (0.333 against 0.302 ms, and 1.2–3.6 ms a step of ``reduce``).
@@ -99,8 +143,8 @@ rowsum(dO·O) itself. The softmax scale is folded into q inside the body.
   readings, on folded rows.)
 
 **Streamed** — everything else (cross-length calls, lengths over the
-budget: 4k and up at head 128, the 16k / 32k long-context path), as
-before PR 28:
+budget: 8k and up at head 128, 3k and up in float32, the 16k / 32k
+long-context path), as before PR 28:
 
 - forward grid = (batch*heads, q_blocks, k_blocks); the k axis is the
   innermost ("arbitrary") dimension so the [block_q, d] accumulator,
@@ -129,7 +173,7 @@ before PR 28:
   precomputed D-matrix masks (f32 slow, i8 unsupported), dead-block
   index clamping, exp2-space softmax, 2048-wide blocks (VMEM). See
   BASELINE.md "Flash-attention forward roofline". None of that was
-  read at 1k or 2k, where this path no longer runs.
+  read at 1k, 2k or 4k, where this path no longer runs.
 
 Both paths: the softmax scale is folded into q ONCE (the streamed path
 in XLA before the kernel, the resident one in the body, rounded the
@@ -485,12 +529,25 @@ _flash_streamed.defvjp(_flash_streamed_fwd, _flash_streamed_bwd)
 # [b*h, t, d] copy. One body serves both.
 
 #: the in-body block, both kernels (the module docstring has the chip's
-#: readings of 128, 256 and 512)
+#: readings of 128, 256 and 512, and of 256, 512 and 1024 at 4k)
 _RESIDENT_BLOCK = 512
+#: the longest row whose block loop is straight-line code in the body.
+#: Longer rows loop over their blocks: an unrolled kernel's code grows with
+#: the square of the row (1.8 MB and 19 s of compiling a call site at 4k
+#: against 0.2 MB and 2 s looped: the module docstring has the readings)
+_UNROLLED_ROWS = 2048
+#: VMEM of a v5e core
+_VMEM_CORE = 128 * 2 ** 20
 #: what a row's operands, accumulators and block temporaries may take of
-#: VMEM for the resident kernels to be chosen: by ``_resident_bytes`` 2k /
-#: head 128 takes 18.7 MiB, 4k / head 128 31.4 MiB, 16k / head 128 107 MiB
-_RESIDENT_BUDGET = 30 * 2 ** 20
+#: VMEM for the resident kernels to be chosen. A program asks Mosaic for
+#: twice the count (``_resident_call``), so this holds the request under
+#: 60% of the core. By ``_resident_bytes``: 2k / head 128 takes 18.7 MiB,
+#: 4k / head 128 31.4 MiB (in: the longest row the chip has read, 1.268 /
+#: 2.508 ms a call where the streamed kernels take 1.397 / 4.373), 2k /
+#: head 128 in float32 28.2 MiB, 3k in float32 39.3 MiB (out), 8k / head
+#: 128 56.8 MiB (out: not read on the chip, and its request would be
+#: nine tenths of the core)
+_RESIDENT_BUDGET = 3 * _VMEM_CORE // 10
 _SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
 
 
@@ -505,18 +562,26 @@ def _resident_block(t: int) -> int:
 
 
 def _resident_bytes(t: int, d: int, itemsize: int) -> int:
-    """VMEM the resident backward (the larger of the two kernels) holds
-    for one program: inputs and outputs double-buffered, the float32
-    accumulators, the block temporaries. A head narrower than 128 lanes
-    is padded to them, or shares them with its neighbours."""
+    """VMEM the larger of the two resident kernels holds for one program:
+    inputs and outputs double-buffered, the float32 accumulators, the
+    block temporaries. A head narrower than 128 lanes is padded to them,
+    or shares them with its neighbours. The backward is the larger at
+    every length that fits: the unrolled forward's plain softmax holds a
+    block of queries against the whole key row and would pass it from
+    about 2.3k on, where the looped bodies take over."""
     lanes = -(-d // 128) * 128
-    io = 2 * (8 * t * lanes * itemsize      # q, k, v, dO, o; dq, dk, dv
-              + 8 * t * 4)                  # lse
-    held = (3 * t * lanes * itemsize        # q scaled; dk, dv awaiting a step
-            + 8 * t * 4                     # delta
-            + 3 * t * lanes * 4)            # dq, dk, dv in float32
-    tmp = 6 * _resident_block(t) ** 2 * 4   # sT, pT, dPT, dsT and their casts
-    return io + held + tmp
+    block = _resident_block(t)
+    slab, row = t * lanes * itemsize, 8 * t * 4
+    met = t if t <= _UNROLLED_ROWS else block  # keys a query block meets
+    fwd = (2 * (4 * slab + row)               # q, k, v; o; lse
+           # scores, scores - max and their exponentials, and the cast
+           + block * met * (3 * 4 + itemsize))
+    bwd = (2 * (8 * slab + row)               # q, k, v, dO, o; dq, dk, dv; lse
+           + 3 * slab                # q scaled; dk, dv awaiting a step
+           + row                     # delta
+           + 3 * t * lanes * 4       # dq, dk, dv in float32
+           + 6 * block ** 2 * 4)     # sT, pT, dPT, dsT and their casts
+    return max(fwd, bwd)
 
 
 def _packs(heads: int, d: int) -> bool:
@@ -539,7 +604,7 @@ def flash_path(tq: int, tk: int, d: int, dtype, heads: int = 0) -> str:
     projections' own layout ("resident_packed") where ``heads`` heads of
     ``d`` are whole 128-lane column blocks, on folded [b*h, t, d] copies
     ("resident") where they are not or ``heads`` is not given. "streamed"
-    for everything else (cross-length calls, 16k and 32k), as before."""
+    for everything else (cross-length calls, 8k and longer), as before."""
     fits = tq == tk and _resident_block(tq) and _resident_bytes(
         tq, d, jnp.dtype(dtype).itemsize) <= _RESIDENT_BUDGET
     if not fits:
@@ -683,6 +748,55 @@ def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0, rows, :] = out.astype(o_ref.dtype)
 
 
+def _block_at(i, block):
+    """Rows [i * block, (i + 1) * block) of a ref, ``i`` a loop index."""
+    return pl.ds(pl.multiple_of(i * block, block), block)
+
+
+def _resident_fwd_looped_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                                *, scale, causal, block, d):
+    """The forward for rows over ``_UNROLLED_ROWS``: the same program a row
+    and the same operands in VMEM, the block loops as loops. A block of
+    queries meets its key blocks one at a time, so the softmax keeps a
+    running max and rescales (the streamed kernel's algebra); dead blocks
+    are not in the loop and only the diagonal block is masked."""
+    t, lanes = q_ref.shape[1:]
+    blocks = t // block
+
+    def q_block(i, _):
+        rows = _block_at(i, block)
+        qs = (q_ref[0, rows, :] * scale).astype(q_ref.dtype)
+        out = None
+        for a, own in enumerate(_own_lanes(lanes, d)):
+            q = _only(own, qs)
+
+            def meet(cols, carry, masked):
+                m, l, acc = carry
+                s = _dot(q, k_ref[0, cols, :], _NT)
+                if masked:
+                    s = jnp.where(_diagonal_keep(block, 0), s, _NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p, corr = jnp.exp(s - m_new), jnp.exp(m - m_new)
+                return (m_new, corr * l + jnp.sum(p, axis=1, keepdims=True),
+                        corr * acc + _dot(p.astype(v_ref.dtype),
+                                          v_ref[0, cols, :], _NN))
+            carry = (jnp.full((block, 1), _NEG_INF, jnp.float32),
+                     jnp.zeros((block, 1), jnp.float32),
+                     jnp.zeros((block, lanes), jnp.float32))
+            # causal: the key blocks before this one, then the diagonal
+            carry = jax.lax.fori_loop(
+                0, i if causal else blocks,
+                lambda j, c: meet(_block_at(j, block), c, False), carry)
+            m, l, acc = meet(rows, carry, True) if causal else carry
+            denom = jnp.maximum(l, 1e-30)
+            out = acc / denom if out is None else jnp.where(
+                own, acc / denom, out)
+            lse_ref[0, a, :, rows] = (m + jnp.log(denom)).T
+        o_ref[0, rows, :] = out.astype(o_ref.dtype)
+
+    jax.lax.fori_loop(0, blocks, q_block, None)
+
+
 # jitted, both wrappers: every layer of a model calls with the same shapes,
 # so the unrolled body is traced and lowered once a program, not once a
 # layer; XLA inlines the calls
@@ -692,8 +806,10 @@ def _resident_fwd(q, k, v, cols, heads: int, d: int, causal: bool,
     """q, k, v: [b, t, features] arrays (one fused projection three times,
     or three arrays) with ``heads`` heads of ``d`` from the column offsets
     ``cols`` -> (o [b, t, heads * d], lse [b, heads, 1, t])."""
-    kernel = functools.partial(_resident_fwd_kernel, scale=1.0 / d ** 0.5,
-                               causal=causal, block=block, d=d)
+    kernel = functools.partial(
+        _resident_fwd_kernel if q.shape[1] <= _UNROLLED_ROWS
+        else _resident_fwd_looped_kernel,
+        scale=1.0 / d ** 0.5, causal=causal, block=block, d=d)
     return _resident_call(kernel, "flash_fwd", (q, k, v), cols, (),
                           ("slab", "rows"), heads, d, (), interpret)
 
@@ -750,6 +866,61 @@ def _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
+def _resident_bwd_looped_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                                dq_ref, dk_ref, dv_ref, qs_ref, dlt_ref,
+                                dq_acc, *, scale, causal, block, d):
+    """The same pass for rows over ``_UNROLLED_ROWS``, the block loops as
+    loops: per live block the same five products and one exp chain, dk and
+    dv carried through the loop over the queries a key block meets."""
+    t, lanes = q_ref.shape[1:]
+    blocks = t // block
+    heads = _own_lanes(lanes, d)
+    qs_ref[:] = (q_ref[0] * scale).astype(qs_ref.dtype)
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def delta(i, _):
+        rows = _block_at(i, block)
+        prod = do_ref[0, rows, :].astype(jnp.float32) \
+            * o_ref[0, rows, :].astype(jnp.float32)
+        for a, own in enumerate(heads):
+            dlt_ref[a, :, rows] = jnp.sum(_only(own, prod), axis=1,
+                                          keepdims=True).T
+    jax.lax.fori_loop(0, blocks, delta, None)
+
+    def k_block(j, _):
+        keys = _block_at(j, block)
+        dk_out = dv_out = None
+        for a, own in enumerate(heads):
+            k, v = _only(own, k_ref[0, keys, :]), _only(own, v_ref[0, keys, :])
+
+            def meet(i, carry, masked):
+                dk, dv = carry
+                rows = _block_at(i, block)
+                qs, do = qs_ref[rows, :], do_ref[0, rows, :]
+                sT = _dot(k, qs, _NT)                    # [keys, queries]
+                if masked:
+                    sT = jnp.where(_diagonal_keep(block, 1), sT, _NEG_INF)
+                pT = jnp.exp(sT - lse_ref[0, a, :, rows])
+                dsT = pT * (_dot(v, do, _NT) - dlt_ref[a, :, rows])
+                pT, dsT = pT.astype(do.dtype), dsT.astype(qs.dtype)
+                dq_acc[rows, :] += _dot(dsT, k, _TN)
+                return dk + _dot(dsT, qs, _NN), dv + _dot(pT, do, _NN)
+            carry = (jnp.zeros((block, lanes), jnp.float32),) * 2
+            # causal: the diagonal block, then the queries after it
+            if causal:
+                carry = meet(j, carry, True)
+            dk, dv = jax.lax.fori_loop(
+                j + 1 if causal else 0, blocks,
+                lambda i, c: meet(i, c, False), carry)
+            dk_out = dk if dk_out is None else jnp.where(own, dk, dk_out)
+            dv_out = dv if dv_out is None else jnp.where(own, dv, dv_out)
+        dk_ref[0, keys, :] = dk_out.astype(dk_ref.dtype)
+        dv_ref[0, keys, :] = dv_out.astype(dv_ref.dtype)
+
+    jax.lax.fori_loop(0, blocks, k_block, None)
+    dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+
 def _resident_bwd_thirds_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                                 out_ref, dk_ref, dv_ref, *scratch, **static):
     """The same pass with dq, dk and dv as the three thirds of one
@@ -760,8 +931,9 @@ def _resident_bwd_thirds_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
     @pl.when(step == 0)
     def _compute():
-        _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                             out_ref, dk_ref, dv_ref, *scratch, **static)
+        _resident_bwd_body(q_ref.shape[1])(
+            q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, out_ref, dk_ref,
+            dv_ref, *scratch, **static)
 
     @pl.when(step == 1)
     def _dk():
@@ -770,6 +942,11 @@ def _resident_bwd_thirds_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     @pl.when(step == 2)
     def _dv():
         out_ref[:] = dv_ref[:]
+
+
+def _resident_bwd_body(t: int):
+    return (_resident_bwd_kernel if t <= _UNROLLED_ROWS
+            else _resident_bwd_looped_kernel)
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
@@ -786,7 +963,7 @@ def _resident_bwd(q, k, v, o, lse, g, cols, heads: int, causal: bool,
         kernel = functools.partial(_resident_bwd_thirds_kernel, **static)
         scratch = (pltpu.VMEM((1, t, lanes), q.dtype),) * 2 + scratch
     else:
-        kernel = functools.partial(_resident_bwd_kernel, **static)
+        kernel = functools.partial(_resident_bwd_body(t), **static)
     return _resident_call(
         kernel, "flash_dq_dkv", (q, k, v, g, o), cols + (0, 0), (lse,),
         "thirds" if thirds else ("slab",) * 3, heads, d, scratch, interpret)

@@ -36,8 +36,10 @@ from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 
 # the module itself: ``ops/__init__`` re-exports the function under its name
 FA = import_module("deeplearning4j_tpu.ops.flash_attention")
-#: the three GPT cells' calls: batch, heads, length, head width
-CELLS = ("8,16,1024,64", "32,16,256,64", "2,12,2048,128")
+#: the cells' calls: batch, heads, length, head width (the three GPT cells,
+#: the looped cell, the hybrid cell's one attention layer)
+CELLS = ("8,16,1024,64", "32,16,256,64", "2,12,2048,128",
+         "2,16,4096,128", "2,32,4096,64")
 CALLS = 10
 
 

@@ -78,6 +78,21 @@ def small_blocks(monkeypatch):
     return 128
 
 
+@pytest.fixture(params=["unrolled", "looped"])
+def body(request, monkeypatch):
+    """The resident kernels' two bodies: the block loop as straight-line
+    code (rows up to 2k on the chip) or as loops (longer rows), here both
+    at the test's length. The wrappers are ``jax.jit``s keyed on shapes, so
+    their caches are dropped with the choice."""
+    drop = lambda: [f.clear_cache() for f in (FA._resident_fwd,
+                                              FA._resident_bwd)]
+    if request.param == "looped":
+        monkeypatch.setattr(FA, "_UNROLLED_ROWS", 0)
+    drop()
+    yield request.param
+    drop()
+
+
 @pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("causal", [False, True])
 def test_matches_oracle(rng, causal, path):
@@ -200,7 +215,7 @@ def test_a_checkpoint_that_keeps_the_named_residuals_runs_no_second_forward(
 
 @pytest.mark.parametrize("heads", [1, 2])
 @pytest.mark.parametrize("causal", [False, True])
-def test_paths_agree(rng, small_blocks, causal, heads):
+def test_paths_agree(rng, small_blocks, body, causal, heads):
     """The two sets of kernels on the same inputs: o, lse, dq, dk, dv to
     float32 tolerance (the sums run in another order). ``heads`` = 1 hands
     the resident body the folded rows, 2 the same rows packed two to a
@@ -234,6 +249,44 @@ def test_paths_agree(rng, small_blocks, causal, heads):
                                    rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("h,d", [(1, 128), (2, 64)])
+def test_the_4k_row_agrees_with_the_streamed_kernels_and_the_oracle(rng, h, d):
+    """One row of 4096 at the in-body block the chip runs (8 diagonal blocks
+    of 36 live ones: the looped bodies), a head of 128 and a pair of 64 in
+    one program: o and lse against the streamed forward, dq, dk, dv against
+    the streamed pair and against the XLA formulation's gradients."""
+    t = 4096
+    q, k, v, g = (jnp.asarray(rng.standard_normal((1, t, h, d)), jnp.float32)
+                  for _ in range(4))
+    assert FA.flash_path(t, t, d, jnp.bfloat16, heads=h) == "resident_packed"
+    pack = lambda z: z.reshape(1, t, h * d)
+    fold = lambda z: z.transpose(0, 2, 1, 3).reshape(h, t, d)
+    unfold = lambda z: np.asarray(z).reshape(h, t, d).transpose(1, 0, 2) \
+        .reshape(1, t, h * d)
+    block = FA._resident_block(t)
+    assert block == 512 and t > FA._UNROLLED_ROWS
+    o_r, lse_r = FA._resident_fwd(pack(q), pack(k), pack(v), (0, 0, 0), h, d,
+                                  True, block, True)
+    o_s, lse_s = FA._flash_fwd_impl(fold(q), fold(k), fold(v), True, 1024,
+                                    1024, True)
+    np.testing.assert_allclose(np.asarray(o_r), unfold(o_s),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse_r).reshape(h, t),
+                               np.asarray(lse_s)[..., 0],
+                               rtol=2e-5, atol=2e-5)
+    got = FA._resident_bwd(pack(q), pack(k), pack(v), o_r, lse_r, pack(g),
+                           (0, 0, 0), h, True, block, True)
+    streamed = FA._flash_bwd_impl(fold(q), fold(k), fold(v), o_s, lse_s,
+                                  fold(g), True, 512, 512, True)
+    oracle = jax.grad(lambda q, k, v: jnp.sum(scaled_dot_product_attention(
+        q, k, v, causal=True) * g), (0, 1, 2))(q, k, v)
+    for a, b, c in zip(got, streamed, oracle):
+        np.testing.assert_allclose(np.asarray(a), unfold(b),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(pack(c)),
+                                   rtol=2e-4, atol=2e-4)
+
+
 def _fused(q, k, v):
     b, t, h, d = q.shape
     return jnp.concatenate([z.reshape(b, t, h * d) for z in (q, k, v)], -1)
@@ -242,8 +295,8 @@ def _fused(q, k, v):
 @pytest.mark.parametrize("h,d", [(2, 64), (4, 64), (1, 128), (2, 128),
                                  (4, 32), (3, 64)])
 @pytest.mark.parametrize("causal", [False, True])
-def test_fused_qkv_entry_matches_split_and_fold(rng, small_blocks, causal,
-                                                h, d):
+def test_fused_qkv_entry_matches_split_and_fold(rng, small_blocks, body,
+                                                causal, h, d):
     """``flash_attention_qkv`` on the projection's own [b, t, 3*h*d] array
     (q, k, v read at their column offsets, dq, dk, dv written at them)
     against ``jnp.split`` + the folded path: the same o and the same dqkv,
@@ -325,17 +378,38 @@ def test_jit_and_under_vmap(rng):
     (1024, 1024, 64, jnp.bfloat16, "resident"),    # gpt2-medium.pretrain-1k
     (2048, 2048, 128, jnp.bfloat16, "resident"),   # cerebras-gpt-590m...-2k
     (256, 256, 64, jnp.bfloat16, "resident"),
-    (4096, 4096, 128, jnp.bfloat16, "streamed"),   # over the VMEM budget
+    (4096, 4096, 128, jnp.bfloat16, "resident"),   # ouro-2.6b...-looped-4k
+    (8192, 8192, 128, jnp.bfloat16, "streamed"),   # over the VMEM budget
     (16384, 16384, 128, jnp.bfloat16, "streamed"),
     (32768, 32768, 128, jnp.bfloat16, "streamed"),
-    (2048, 2048, 128, jnp.float32, "resident"),    # 28.2 MiB of the 30
-    (3072, 3072, 128, jnp.float32, "streamed"),
+    (2048, 2048, 128, jnp.float32, "resident"),    # 28.2 MiB of the 38.4
+    (3072, 3072, 128, jnp.float32, "streamed"),    # 39.3 MiB
+    (4096, 4096, 128, jnp.float32, "streamed"),    # 50.4 MiB
     (64, 256, 64, jnp.bfloat16, "streamed"),       # tq < tk, the serving tail
     (1032, 1032, 64, jnp.bfloat16, "streamed"),    # 8 x 129: no block >= 128
 ])
 def test_path_is_a_pure_function_of_shapes(tq, tk, d, dtype, want):
     assert FA.flash_path(tq, tk, d, dtype) == want
     assert FA.flash_path(tq, tk, d, jnp.dtype(dtype)) == want  # and again
+
+
+@pytest.mark.parametrize("t,d,itemsize,want", [
+    # the backward's, as before the forward was counted: the GPT cells'
+    # kernels ask Mosaic for twice these
+    (256, 64, 2, 3_235_840),
+    (1024, 64, 2, 12_943_360),
+    (2048, 128, 2, 19_595_264),
+    (2048, 128, 4, 29_556_736),
+    (4096, 128, 2, 32_899_072),    # 31.4 MiB of the 38.4
+    (4096, 64, 2, 32_899_072),     # a pair of heads shares the 128 lanes
+])
+def test_vmem_count_is_the_larger_kernels(t, d, itemsize, want):
+    """The backward's at every length that fits, and so the scoped limit in
+    the lowered kernels is what it was: a forward that met the whole key
+    row at once would pass it at 4k (36.3 MiB), the looped one meets a block
+    at a time."""
+    assert FA._resident_bytes(t, d, itemsize) == want
+    assert 2 * want < FA._VMEM_CORE
 
 
 @pytest.mark.parametrize("t,tk,h,d,want", [
@@ -349,7 +423,10 @@ def test_path_is_a_pure_function_of_shapes(tq, tk, d, dtype, want):
     (1024, 1024, 4, 96, "resident"),          # 96 divides no tile
     (1024, 1024, 0, 64, "resident"),          # folded rows: no head count
     (64, 256, 16, 64, "streamed"),            # cross-length
-    (4096, 4096, 32, 64, "streamed"),         # granite...-4k's one layer
+    (4096, 4096, 32, 64, "resident_packed"),  # granite...-4k's one layer
+    (4096, 4096, 16, 128, "resident_packed"),  # ouro-2.6b...-looped-4k
+    (4096, 4096, 3, 64, "resident"),
+    (8192, 8192, 16, 128, "streamed"),
     (16384, 16384, 8, 128, "streamed"),
 ])
 def test_packed_layout_is_chosen_from_the_shapes(t, tk, h, d, want):
@@ -405,7 +482,42 @@ def test_long_context_lowers_to_the_streamed_kernels():
     """16k / head 128 reaches the code it reached before the resident
     kernels existed; a cell's shape does not."""
     assert _kernel_names(16384, 128) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert _kernel_names(8192, 128) == ["flash_dkv", "flash_dq", "flash_fwd"]
     assert _kernel_names(1024, 64) == ["flash_dq_dkv", "flash_fwd"]
+
+
+@pytest.mark.parametrize("d", [128, 64])
+def test_the_4k_row_lowers_to_the_resident_kernels(d):
+    """The looped cell's calls (heads of 128) and the hybrid cell's one
+    attention layer (pairs of 64) at 4096."""
+    assert _kernel_names(4096, d) == ["flash_dq_dkv", "flash_fwd"]
+
+
+def _block_step_text(monkeypatch, block, b, t, width):
+    """The training step of one block on [b, t, width] in bfloat16, lowered
+    for the TPU with the kernels as Mosaic calls: the resident pair, and no
+    transpose but of the weight gradients (two-dimensional), so no head
+    fold stands anywhere in the step."""
+    monkeypatch.setattr(FA, "pallas_interpret", lambda: False)
+    bf16 = lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16)
+    params = jax.tree.map(bf16, jax.eval_shape(block.init_params,
+                                               jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((b, t, width), jnp.bfloat16)
+
+    def loss(params, x):
+        out, _ = block.forward(params, x, {}, True)
+        return jnp.sum(out.astype(jnp.float32))
+    with jax.enable_x64(False):  # the suite's x64 is not the chip's setting
+        text = jax.jit(jax.grad(loss, (0, 1))).trace(params, x).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
+        "flash_dq_dkv", "flash_fwd"]
+    for shape in re.findall(r"stablehlo\.transpose .*?\(tensor<([\dx]+)x\w+>\)",
+                            text):
+        assert shape.count("x") == 1, shape  # a weight gradient's
+    made = dict(re.findall(r"(%\d+)(?::\d+)? = (?:stablehlo\.|call @)(\w+)",
+                           text))
+    return text, made
 
 
 @pytest.mark.parametrize("b,h,t,d", [
@@ -424,29 +536,11 @@ def test_gpt_block_step_hands_the_projections_to_the_kernels(
     (two-dimensional) stands anywhere in the step."""
     from deeplearning4j_tpu.models.zoo.transformer import gpt
 
-    monkeypatch.setattr(FA, "pallas_interpret", lambda: False)
     block = gpt(vocab_size=64, d_model=h * d, n_layers=1, num_heads=h,
                 max_len=t).impls[1]
-    bf16 = lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16)
-    params = jax.tree.map(bf16, jax.eval_shape(block.init_params,
-                                               jax.random.key(0)))
-    x = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16)
-
-    def loss(params, x):
-        out, _ = block.forward(params, x, {}, True)
-        return jnp.sum(out.astype(jnp.float32))
-    with jax.enable_x64(False):  # the suite's x64 is not the chip's setting
-        text = jax.jit(jax.grad(loss, (0, 1))).trace(params, x).lower(
-            lowering_platforms=("tpu",)).as_text()
-    assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
-        "flash_dq_dkv", "flash_fwd"]
+    text, made = _block_step_text(monkeypatch, block, b, t, h * d)
     assert "stablehlo.slice" not in text
     assert "stablehlo.concatenate" not in text
-    for shape in re.findall(r"stablehlo\.transpose .*?\(tensor<([\dx]+)x\w+>\)",
-                            text):
-        assert shape.count("x") == 1, shape  # a weight gradient's
-    made = dict(re.findall(r"(%\d+)(?::\d+)? = (?:stablehlo\.|call @)(\w+)",
-                           text))
     fwd = re.search(r"(%\d+):2 = call @_resident_fwd\((%\d+), (%\d+), "
                     r"(%\d+)\)", text)
     o, *qkv = fwd.groups()
@@ -458,3 +552,31 @@ def test_gpt_block_step_hands_the_projections_to_the_kernels(
     assert operands == qkv and made[g] == "dot_general"
     users = re.findall(r"stablehlo\.(\w+) [^\n=]*" + dqkv + r"\b", text)
     assert users == ["dot_general", "dot_general"], users
+
+
+def test_looped_block_step_hands_q_k_v_to_the_kernels_as_they_lie(
+        monkeypatch):
+    """The training step of one looped-LM block at its cell's shapes (2 rows
+    of 4096, 16 heads of 128, rotary, as many key/value heads), lowered for
+    the TPU: what ``rope`` and ``kv_repeat`` make goes into ``flash_fwd`` by
+    reshapes alone, the kernel's o into ``attn_out_proj``'s product, and the
+    backward is the one kernel: no transpose but of the weight gradients
+    (two-dimensional) stands anywhere in the step, so no head fold does."""
+    from deeplearning4j_tpu.models.zoo.looped_lm import looped_lm
+
+    b, t, h, d = 2, 4096, 16, 128
+    block = looped_lm(dict(
+        hidden_size=h * d, vocab_size=64, num_hidden_layers=1, total_ut_steps=1,
+        num_attention_heads=h, num_key_value_heads=h, head_dim=d,
+        intermediate_size=256, rms_norm_eps=1e-6, rope_theta=1e6)).impls[1]
+    text, made = _block_step_text(monkeypatch, block, b, t, h * d)
+    source = dict(re.findall(r"(%\d+) = stablehlo\.reshape (%\d+) ", text))
+    fwd = re.search(r"(%\d+):2 = call @_resident_fwd\((%\d+), (%\d+), "
+                    r"(%\d+)\)", text)
+    o, *qkv = fwd.groups()
+    assert len(set(qkv)) == 3
+    for z in qkv:  # [b, t, h, d] -> [b, t, h * d]: a reshape of what was made
+        assert made[z] == "reshape" and made[source[z]] != "transpose", z
+    bwd = re.search(r"(%\d+):3 = call @_resident_bwd\((%\d+), (%\d+), "
+                    + r"(%\d+), " + f"{o}#0, {o}#1, " + r"(%\d+)\)", text)
+    assert bwd and list(bwd.groups()[1:4]) == qkv
