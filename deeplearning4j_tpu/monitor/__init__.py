@@ -118,6 +118,12 @@ FLASH_PATH_COUNTER = "dl4j_flash_path_total"
 # ops/ssd.py: ssd_scan calls traced, labeled path="kernel" (ssd_fwd / ssd_bwd)
 # or "xla" (the plain chunked form), chosen from the shapes while tracing
 SSD_PATH_COUNTER = "dl4j_ssd_path_total"
+# ops/selective_scan.py: selective_scan calls traced, labeled path="kernel"
+# (selscan_fwd / selscan_bwd) or "xla" (a lax.scan over the tokens)
+SELSCAN_PATH_COUNTER = "dl4j_selscan_path_total"
+# ops/flash_attention.py: flash_attention calls traced with a window (a query
+# sees itself and the window - 1 keys before it), whatever kernels they chose
+FLASH_WINDOWED_COUNTER = "dl4j_flash_windowed_total"
 # nn/multilayer.py: blocks whose bodies the train step just built recomputes
 # in its backward pass (conf.recompute_blocks); 0 for a model that keeps them
 RECOMPUTED_BLOCKS_GAUGE = "dl4j_recomputed_blocks"
@@ -131,6 +137,9 @@ SPAN_PASSES_GAUGE = "dl4j_span_passes"
 # nn/multilayer.py: applications of block layers (LayerImpl.recomputable) in
 # that step; a block of a repeated span counts once a pass
 BLOCK_APPLICATIONS_GAUGE = "dl4j_block_applications"
+# nn/multilayer.py: named values that layers of that step hand forward to
+# later layers (Layer.provides); 0 for a plain chain
+FORWARDED_VALUES_GAUGE = "dl4j_forwarded_values"
 
 # Serving plane (parallel/inference.py ParallelInference — the
 # micro-batching engine behind StreamingInference): request/batch

@@ -346,6 +346,89 @@ class GroupedQueryBlock(GatedDecoderBlock):
     rope_theta: Optional[float] = None
 
 
+def parse_reads(layer: "Layer"):
+    """The values a layer reads, as ``(providing layer, name)`` pairs: its
+    configuration's ``reads`` entries ``"<layer>.<name>"``; none for a layer
+    whose configuration has no such key."""
+    return [tuple(entry.partition(".")[::2])
+            for entry in getattr(layer, "reads", ())]
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerNormDecoderBlock(FeedForwardLayer):
+    """What the decoder blocks of the decoder-hybrid-decoder family share:
+    pre-LayerNorm with gain and bias, a mixer, and a gated MLP
+    ``(a * silu(g)) W_fc2`` with ``[a, g] = h W_fc1`` of hidden width
+    ``ffn_hidden``, no MLP bias. Training only, as the hybrid family.
+
+    A block may hand values forward to later layers and read what earlier
+    ones handed on (``MultiLayerNetwork``'s seam): ``provides`` lists the
+    names it makes, ``reads`` the names it takes, each with the layer that
+    makes it as ``"<layer>.<name>"`` (``"layer3.memory"``)."""
+
+    ffn_hidden: int = 0
+    ln_eps: float = 1e-5
+    kept_values: Optional[Tuple[str, ...]] = None
+    provides: Tuple[str, ...] = ()
+    reads: Tuple[str, ...] = ()
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class Mamba1Block(LayerNormDecoderBlock):
+    """A Mamba-1 selective-scan mixer (``ops/selective_scan.py``) and the
+    gated MLP. ``d_inner`` channels, a state of ``d_state`` a channel with a
+    decay of its own for every (channel, state) pair, a causal depthwise
+    convolution of width ``d_conv`` before the scan, the step projected
+    through ``dt_rank``. May provide ``memory``: the scan's output before
+    the gate. n_in == n_out == d_model."""
+
+    d_inner: int = 0
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 0
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class DiffAttentionBlock(LayerNormDecoderBlock):
+    """Differential attention (arXiv:2410.05258) and the gated MLP: heads in
+    adjacent pairs, the second softmax map of a pair subtracted from the
+    first times a learned ``lambda``, the difference applied to the pair's
+    two value heads side by side, an RMSNorm over them, ``1 - lambda_init``
+    with ``lambda_init = 0.8 - 0.6 exp(-0.3 layer_index)``. ``num_kv_heads``
+    key/value heads under ``num_heads`` query heads, biases on both
+    projections, no positions. ``window``: a query sees itself and the
+    ``window - 1`` keys before it. May provide ``kv`` (its keys and values
+    after their bias); with ``cross`` it has a query projection only and
+    reads ``kv``. n_in == n_out == d_model."""
+
+    num_heads: int = 8
+    num_kv_heads: int = 8
+    window: Optional[int] = None
+    cross: bool = False
+    layer_index: int = 0
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class GMUBlock(LayerNormDecoderBlock):
+    """A gated memory unit and the gated MLP: ``(silu(h W_1) * m) W_2`` with
+    ``m`` [b, t, d_inner] the ``memory`` it reads from an earlier Mamba-1
+    layer, in place of a scan of its own. n_in == n_out == d_model."""
+
+    d_inner: int = 0
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class LayerNormLayer(FeedForwardLayer):
+    """LayerNorm over the last axis with gain and bias: the norm before the
+    head of a model whose blocks use it."""
+
+    eps: float = 1e-5
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class MoELayer(FeedForwardLayer):

@@ -47,7 +47,7 @@ def xla_attention():
         _FORCE_XLA.pop()
 
 
-def _flash_per_device(q, k, v, causal: bool, mesh):
+def _flash_per_device(q, k, v, causal: bool, mesh, window=None):
     """The flash kernel mapped over a placement mesh: attention is
     independent per (batch row, head), so every device runs the kernel
     on its own rows and heads — batch divided over the mesh's
@@ -63,13 +63,15 @@ def _flash_per_device(q, k, v, causal: bool, mesh):
                   if mesh.shape.get(a, 1) > 1 and h % mesh.shape[a] == 0),
                  None)
     spec = P(batch or None, None, heads, None)
-    local = lambda q, k, v: flash_attention(q, k, v, causal=causal)
+    local = lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                            window=window)
     # the kernel's outputs carry no varying-axes type: skip that check
     return device_collective(local, mesh, in_specs=(spec, spec, spec),
                              out_specs=spec, check_vma=False)(q, k, v)
 
 
-def dispatch_attention(q, k, v, causal: bool, mask=None, mesh=None):
+def dispatch_attention(q, k, v, causal: bool, mask=None, mesh=None,
+                       window=None):
     """Shared parallelism dispatch for every attention-bearing layer:
     ring attention under an active sequence mesh (DP×SP when the mesh
     also has a 'data' axis), otherwise the flash Pallas kernel — per
@@ -77,18 +79,23 @@ def dispatch_attention(q, k, v, causal: bool, mask=None, mesh=None):
     Key-validity masks fall back to the XLA path inside the kernel
     wrapper (which the partitioner handles, so they skip the per-device
     map; ring blocks assume dense time, so masked inputs also stay off
-    the ring). An active ``xla_attention()`` context overrides all."""
+    the ring). An active ``xla_attention()`` context overrides all.
+    ``window`` (a query sees itself and the ``window - 1`` keys before it)
+    goes to the flash kernels and to the XLA form; the ring has none."""
     if _FORCE_XLA:
-        return scaled_dot_product_attention(q, k, v, causal=causal, mask=mask)
+        return scaled_dot_product_attention(q, k, v, causal=causal, mask=mask,
+                                            window=window)
     seq = current_sequence_mesh()
     if seq is not None and mask is None:
+        if window is not None:
+            raise NotImplementedError("ring attention has no window")
         mesh, axis = seq
         batch_axis = "data" if "data" in mesh.shape else None
         return ring_attention(q, k, v, mesh, axis=axis, causal=causal,
                               batch_axis=batch_axis)
     if mesh is not None and mesh.size > 1 and mask is None:
-        return _flash_per_device(q, k, v, causal, mesh)
-    return flash_attention(q, k, v, causal=causal, mask=mask)
+        return _flash_per_device(q, k, v, causal, mesh, window)
+    return flash_attention(q, k, v, causal=causal, mask=mask, window=window)
 
 
 def dispatch_qkv_attention(qkv, heads: int, causal: bool, mask=None,

@@ -35,6 +35,7 @@ from deeplearning4j_tpu.datasets.iterators import (
     feed_pipeline_enabled,
 )
 from deeplearning4j_tpu.nn.conf.configuration import MultiLayerConfiguration
+from deeplearning4j_tpu.nn.conf.layers import parse_reads
 import deeplearning4j_tpu.nn.layers  # noqa: F401  (registers layer impls)
 from deeplearning4j_tpu.nn.layers.base import build_layer
 from deeplearning4j_tpu.nn.updater import (
@@ -44,6 +45,7 @@ from deeplearning4j_tpu.nn.updater import (
     normalize_gradient,
 )
 from deeplearning4j_tpu.monitor import (BLOCK_APPLICATIONS_GAUGE,
+                                        FORWARDED_VALUES_GAUGE,
                                         H2D_BYTES_COUNTER,
                                         RECOMPUTE_KEPT_VALUES_GAUGE,
                                         RECOMPUTED_BLOCKS_GAUGE,
@@ -86,6 +88,16 @@ LOOPED_STEP_SCOPES = (
     "exit_loss", "embed", "rms1", "qkv_proj", "rope", "kv_repeat",
     "attention", "attn_out_proj", "mixer_out_norm", "rms2", "mlp_gate_up",
     "mlp_down", "mlp_out_norm", "final_norm", "fold_heads", "unfold_heads")
+#: and for a model of the decoder-hybrid-decoder family
+#: (models/zoo/sambay.py): a Mamba-1 block, a differential attention block
+#: and a gated memory unit on one LayerNorm + gated-MLP body
+SAMBAY_STEP_SCOPES = (
+    "grad_norm", "optimizer_update", "lm_head", "loss", "embed", "ln1",
+    "mamba_in_proj", "mamba_conv", "mamba_x_proj", "mamba_dt",
+    "selective_scan", "mamba_gate", "mamba_out_proj", "qkv_proj", "attention",
+    "diff_combine", "attn_out_proj", "gmu_in_proj", "gmu_gate",
+    "gmu_out_proj", "ln2", "mlp_fc", "mlp_proj", "final_norm", "fold_heads",
+    "unfold_heads")
 
 
 class MultiLayerNetwork:
@@ -128,6 +140,23 @@ class MultiLayerNetwork:
             (i, s) for s in range(max(1, self._span_passes))
             for i in range(first, end)]
         self._span_end = end if conf.repeat_span else None
+        # values that layers hand forward to later ones: for each layer that
+        # takes part, the (providing layer, name) pairs it reads
+        self._reads: Dict[int, List[Tuple[str, str]]] = {}
+        made: Dict[str, Tuple[str, ...]] = {}
+        for i, impl in enumerate(self.impls):
+            reads = parse_reads(impl.conf)
+            for src, name in reads:
+                if name not in made.get(src, ()):
+                    raise ValueError(
+                        f"{impl.name} reads {name!r} from {src!r}, which is "
+                        "no earlier layer that provides it")
+            if reads and first <= i < end:
+                raise ValueError(f"{impl.name} reads {reads} inside the "
+                                 "repeated span: a pass has no provider")
+            made[impl.name] = tuple(getattr(impl.conf, "provides", ()))
+            if reads or made[impl.name]:
+                self._reads[i] = reads
         self.params: Optional[Params] = None
         self.states: Optional[Dict[str, Any]] = None
         self.opt_state: Optional[Dict[str, Any]] = None
@@ -202,6 +231,7 @@ class MultiLayerNetwork:
         n_last = len(self.impls) - 1
         acts = [None] * (n_last + 1)
         new_states = {}
+        forwarded = {}  # (providing layer, name) -> the value handed forward
         if self._cd is not None and self.impls[0].cast_input:
             x = x.astype(self._cd)
         for i, s in self._applications + [(n_last, 0)]:
@@ -221,8 +251,16 @@ class MultiLayerNetwork:
                         x = x.astype(jnp.float32)
                 else:
                     p = impl.cast_params(p, self._cd)
-            x, ns = impl.forward(p, x, states[impl.name], train,
-                                 self._layer_rng(rng, i, s), mask=fmask)
+            if i in self._reads:
+                x, ns, provided = impl.forward_with(
+                    p, x, states[impl.name], train, self._layer_rng(rng, i, s),
+                    mask=fmask, read={name: forwarded[src, name]
+                                      for src, name in self._reads[i]})
+                forwarded.update({(impl.name, name): value
+                                  for name, value in provided.items()})
+            else:
+                x, ns = impl.forward(p, x, states[impl.name], train,
+                                     self._layer_rng(rng, i, s), mask=fmask)
             if self._cd is not None:
                 ns = cast_like(ns, states[impl.name])
             new_states[impl.name] = ns
@@ -246,6 +284,7 @@ class MultiLayerNetwork:
             x = x.astype(self._cd)
         passes = []  # a repeated span's output after each pass
         layers = {}  # index -> the layer's call, made once: a span reuses it
+        forwarded = {}  # (providing layer, name) -> the value handed forward
         for i, s in self._applications:
             impl = self.impls[i]
             pre = self.conf.input_preprocessors.get(i)
@@ -260,6 +299,21 @@ class MultiLayerNetwork:
                     if self._cd is not None:
                         ns = cast_like(ns, state)
                     return x, ns
+
+                if i in self._reads:
+                    # a layer that hands values forward or reads them: what
+                    # it reads is one more input of its call and what it
+                    # provides one more output, so a recomputed body keeps
+                    # what it provides and is handed what it reads
+
+                    def layer(p, x, state, lrng, read, impl=impl):
+                        if self._cd is not None:
+                            p = impl.cast_params(p, self._cd)
+                        x, ns, provided = impl.forward_with(
+                            p, x, state, train, lrng, mask=fmask, read=read)
+                        if self._cd is not None:
+                            ns = cast_like(ns, state)
+                        return x, ns, provided
 
                 if train and self._recomputes(impl):
                     # the block's body runs again in the backward pass: what
@@ -278,9 +332,18 @@ class MultiLayerNetwork:
             scope = (jax.named_scope(f"pass{s}") if self._span_end
                      else contextlib.nullcontext())
             with scope:
-                x, new_states[impl.name] = layer(
-                    params[impl.name], x, states[impl.name],
-                    self._layer_rng(rng, i, s))
+                if i in self._reads:
+                    x, new_states[impl.name], provided = layer(
+                        params[impl.name], x, states[impl.name],
+                        self._layer_rng(rng, i, s),
+                        {name: forwarded[src, name]
+                         for src, name in self._reads[i]})
+                    forwarded.update({(impl.name, name): value
+                                      for name, value in provided.items()})
+                else:
+                    x, new_states[impl.name] = layer(
+                        params[impl.name], x, states[impl.name],
+                        self._layer_rng(rng, i, s))
             if i + 1 == self._span_end:
                 passes.append(x)
         i_out = len(self.impls) - 1
@@ -334,6 +397,10 @@ class MultiLayerNetwork:
             BLOCK_APPLICATIONS_GAUGE, "applications of block layers in that "
             "step: a block of a repeated span counts once a pass").set(
             sum(impl.recomputable for impl in applied))
+        get_registry().gauge(
+            FORWARDED_VALUES_GAUGE, "named values that layers of that step "
+            "hand forward to later layers").set(
+            sum(len(getattr(impl.conf, "provides", ())) for impl in applied))
         gn_specs = []
         for impl in self.impls:
             nt = GradientNormalization(self.gc.resolve(impl.conf, "gradient_normalization"))

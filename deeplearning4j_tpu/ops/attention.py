@@ -25,6 +25,7 @@ def scaled_dot_product_attention(
     v: jnp.ndarray,  # [b, tk, h, d]
     causal: bool = False,
     mask: Optional[jnp.ndarray] = None,  # [b, tk] key validity
+    window: Optional[int] = None,  # causal: the query's key and window - 1 before
 ) -> jnp.ndarray:
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
@@ -32,6 +33,9 @@ def scaled_dot_product_attention(
     if causal:
         tq, tk = q.shape[1], k.shape[1]
         causal_mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            causal_mask &= ~jnp.tril(jnp.ones((tq, tk), bool),
+                                     k=tk - tq - window)
         scores = jnp.where(causal_mask[None, None], scores, neg)
     if mask is not None:
         scores = jnp.where(mask[:, None, None, :] > 0, scores, neg)

@@ -199,7 +199,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.monitor import FLASH_PATH_COUNTER, get_registry
+from deeplearning4j_tpu.monitor import (FLASH_PATH_COUNTER,
+                                        FLASH_WINDOWED_COUNTER, get_registry)
 from deeplearning4j_tpu.ops.attention import scaled_dot_product_attention
 from deeplearning4j_tpu.util.device import pallas_interpret
 
@@ -229,15 +230,45 @@ def _causal_live(offset, q0, bq, k0):
     return k0 <= q0 + bq - 1 + offset
 
 
+def _window_live(offset, q0, bq, k0, bk, window):
+    """The twin of ``_causal_live`` for a window (query row r sees key col
+    c iff 0 <= r + offset - c < window): whether the block's newest key is
+    still inside the band of the block's oldest query."""
+    return k0 + bk - 1 > q0 + offset - window
+
+
+def _band(n_in: int, block_in: int, n_out: int, block_out: int,
+          first_seen, last_seen):
+    """For a windowed call, the blocks of the inner grid axis that a block of
+    the outer one meets: ``(steps, first)``, with ``steps`` the most blocks
+    any outer block meets (the inner grid's length) and ``first(i)`` the
+    first inner block of outer block ``i``, traceable. ``first_seen(r)`` and
+    ``last_seen(r)`` map an outer row to the first inner row of its first
+    row's band and the last of its last row's."""
+    spans = []
+    for i in range(n_out):
+        lo = max(0, first_seen(i * block_out)) // block_in
+        hi = min(n_in * block_in - 1,
+                 last_seen(i * block_out + block_out - 1)) // block_in
+        spans.append(hi - lo + 1)
+    first = lambda i: jnp.maximum(0, first_seen(i * block_out)) // block_in
+    return max(spans), first
+
+
 # --------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, causal: bool, block_q: int, block_k: int, offset: int):
+                *, causal: bool, block_q: int, block_k: int, offset: int,
+                window=None, first_k=None, n_k=None):
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    kj = step = pl.program_id(2)
     nk = pl.num_programs(2)
+    if window is not None:
+        # the k axis of the grid holds the band's blocks only: step ``step``
+        # of query block qi is key block first_k(qi) + step
+        kj = first_k(qi) + step
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -245,6 +276,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     live = _causal_live(offset, qi * block_q, block_q,
                         kj * block_k) if causal else True
+    if window is not None:
+        live = live & (kj < n_k) & _window_live(
+            offset, qi * block_q, block_q, kj * block_k, block_k, window)
 
     def _step():
         # q arrives pre-scaled (one XLA pass outside the kernel beats a
@@ -259,6 +293,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             ok = (qi * block_q + rows + offset) >= (kj * block_k + cols)
+            if window is not None:
+                ok = ok & ((qi * block_q + rows + offset)
+                           - (kj * block_k + cols) < window)
             s = jnp.where(ok, s, _NEG_INF)
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
@@ -279,15 +316,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     else:
         _step()
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == nk - 1)
     def _final():
         denom = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
         lse_ref[0] = m_ref[:, :1] + jnp.log(denom)   # [block_q, 1] column
 
 
+def _key_band(tq, tk, block_q, block_k, window):
+    """``_band`` of the key blocks a query block meets, and the keys' index
+    map over grid (bh, q block, step): the band's blocks, held inside the
+    row (a step past the band is dead and asks for no new block)."""
+    nq, nk, offset = tq // block_q, tk // block_k, tk - tq
+    steps, first_k = _band(nk, block_k, nq, block_q,
+                           lambda r: r + offset - window + 1,
+                           lambda r: r + offset)
+    kmap = lambda b, i, j: (b, jnp.minimum(first_k(i) + j, nk - 1), 0)
+    return steps, kmap, dict(window=window, first_k=first_k, n_k=nk)
+
+
 def _flash_fwd_impl(q, k, v, causal: bool, block_q: int, block_k: int,
-                    interpret: bool):
+                    interpret: bool, window=None):
     """q,k,v: [bh, t, d] (heads folded into batch) -> (o, lse[bh, t])."""
     bh, tq, d = q.shape
     tk = k.shape[1]
@@ -296,13 +345,17 @@ def _flash_fwd_impl(q, k, v, causal: bool, block_q: int, block_k: int,
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q,
         block_k=block_k, offset=tk - tq)
+    kmap = lambda b, i, j: (b, j, 0)
+    if window is not None:
+        nk, kmap, band = _key_band(tq, tk, block_q, block_k, window)
+        kernel = functools.partial(kernel, **band)
     return pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **_VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **_VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **_VMEM),
+            pl.BlockSpec((1, block_k, d), kmap, **_VMEM),
+            pl.BlockSpec((1, block_k, d), kmap, **_VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **_VMEM),
@@ -332,7 +385,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, block_q: int, block_k: int,
 #   dq = scale · Σ_j dS_ij k_j           => dq_acc += dsTᵀ · k (contract 0,0)
 
 def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-               *, masked, q0, k0, offset, block_q, block_k):
+               *, masked, q0, k0, offset, block_q, block_k, window=None):
     qs = q_ref[0]  # pre-scaled outside the kernels
     sT = jax.lax.dot_general(k_ref[0], qs, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -340,6 +393,8 @@ def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         krow = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
         qcol = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
         ok = (q0 + qcol + offset) >= (k0 + krow)
+        if window is not None:
+            ok = ok & ((q0 + qcol + offset) - (k0 + krow) < window)
         sT = jnp.where(ok, sT, _NEG_INF)
     # lse/delta arrive as [1, block_q] rows (pre-reshaped outside the
     # kernel) and broadcast across the block_k sublanes
@@ -351,23 +406,29 @@ def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
-               acc_ref, *, scale, causal, block_q, block_k, offset):
+               acc_ref, *, scale, causal, block_q, block_k, offset,
+               window=None, first_k=None, n_k=None):
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    kj = step = pl.program_id(2)
     nk = pl.num_programs(2)
+    if window is not None:
+        kj = first_k(qi) + step  # the band's blocks only, as the forward
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     live = _causal_live(offset, qi * block_q, block_q,
                         kj * block_k) if causal else True
+    if window is not None:
+        live = live & (kj < n_k) & _window_live(
+            offset, qi * block_q, block_q, kj * block_k, block_k, window)
 
     def _step():
         _, _, dsT = _bwd_block(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
             masked=causal, q0=qi * block_q, k0=kj * block_k, offset=offset,
-            block_q=block_q, block_k=block_k)
+            block_q=block_q, block_k=block_k, window=window)
         acc_ref[:] += jax.lax.dot_general(
             dsT, k_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -377,31 +438,37 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
     else:
         _step()
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == nk - 1)
     def _final():
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dlt_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc,
-                *, causal, block_q, block_k, offset):
+                *, causal, block_q, block_k, offset,
+                window=None, first_q=None, n_q=None):
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi = step = pl.program_id(2)
     nq = pl.num_programs(2)
+    if window is not None:
+        qi = first_q(kj) + step  # the query blocks that see this key block
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     live = _causal_live(offset, qi * block_q, block_q,
                         kj * block_k) if causal else True
+    if window is not None:
+        live = live & (qi < n_q) & _window_live(
+            offset, qi * block_q, block_q, kj * block_k, block_k, window)
 
     def _step():
         qs, pT, dsT = _bwd_block(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
             masked=causal, q0=qi * block_q, k0=kj * block_k, offset=offset,
-            block_q=block_q, block_k=block_k)
+            block_q=block_q, block_k=block_k, window=window)
         dv_acc[:] += jax.lax.dot_general(
             pT, do_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -414,14 +481,14 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dlt_ref,
     else:
         _step()
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _final():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd_impl(q, k, v, o, lse, g, causal: bool,
-                    block_q: int, block_k: int, interpret: bool):
+                    block_q: int, block_k: int, interpret: bool, window=None):
     bh, tq, d = q.shape
     tk = k.shape[1]
     scale = 1.0 / (d ** 0.5)
@@ -439,11 +506,37 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal: bool,
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0), **_VMEM)
     rowspec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                            **_VMEM)
+    dq_kernel = functools.partial(_dq_kernel, scale=scale, causal=causal,
+                                  block_q=block_q, block_k=block_k,
+                                  offset=offset)
+    dkv_kernel = functools.partial(_dkv_kernel, causal=causal,
+                                   block_q=block_q, block_k=block_k,
+                                   offset=offset)
+    k_steps, q_steps = nk, nq
+    # dk/dv grid: (bh, k_blocks, q_blocks) — q innermost
+    kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0), **_VMEM)
+    qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0), **_VMEM)
+    rowspec2 = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i),
+                            **_VMEM)
+    if window is not None:
+        k_steps, kmap, band = _key_band(tq, tk, block_q, block_k, window)
+        dq_kernel = functools.partial(dq_kernel, **band)
+        kspec = pl.BlockSpec((1, block_k, d), kmap, **_VMEM)
+        # and the query blocks that see a key block
+        q_steps, first_q = _band(nq, block_q, nk, block_k,
+                                 lambda c: c - offset,
+                                 lambda c: c + window - 1 - offset)
+        dkv_kernel = functools.partial(dkv_kernel, window=window,
+                                       first_q=first_q, n_q=nq)
+        qblock = lambda j, i: jnp.minimum(first_q(j) + i, nq - 1)
+        qspec2 = pl.BlockSpec((1, block_q, d),
+                              lambda b, j, i: (b, qblock(j, i), 0), **_VMEM)
+        rowspec2 = pl.BlockSpec((1, 1, block_q),
+                                lambda b, j, i: (b, 0, qblock(j, i)), **_VMEM)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, offset=offset),
-        grid=(bh, nq, nk),
+        dq_kernel,
+        grid=(bh, nq, k_steps),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
@@ -452,15 +545,9 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal: bool,
         name="flash_dq",
     )(q, k, v, g, lse, delta)
 
-    # dk/dv grid: (bh, k_blocks, q_blocks) — q innermost
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0), **_VMEM)
-    qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0), **_VMEM)
-    rowspec2 = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i),
-                            **_VMEM)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k, offset=offset),
-        grid=(bh, nk, nq),
+        dkv_kernel,
+        grid=(bh, nk, q_steps),
         in_specs=[kspec2, kspec2, qspec2, qspec2, rowspec2, rowspec2],
         out_specs=[kspec2, kspec2],
         out_shape=[jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
@@ -473,9 +560,11 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal: bool,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_streamed(q, k, v, causal, block_q, block_k, interpret):
-    o, _ = _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_streamed(q, k, v, causal, block_q, block_k, interpret,
+                    window=None):
+    o, _ = _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret,
+                           window)
     return o
 
 
@@ -491,13 +580,14 @@ def _named_residuals(o, lse):
                  for z, name in zip((o, lse), FLASH_RESIDUAL_NAMES))
 
 
-def _flash_streamed_fwd(q, k, v, causal, block_q, block_k, interpret):
-    o, lse = _named_residuals(
-        *_flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret))
+def _flash_streamed_fwd(q, k, v, causal, block_q, block_k, interpret,
+                        window=None):
+    o, lse = _named_residuals(*_flash_fwd_impl(
+        q, k, v, causal, block_q, block_k, interpret, window))
     return o, (q, k, v, o, lse)
 
 
-def _flash_streamed_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_streamed_bwd(causal, block_q, block_k, interpret, window, res, g):
     q, k, v, o, lse = res
     # backward blocks: score blocks live in VMEM 4x over (pT/dPT/dsT
     # temporaries), so cap at 512x512. A caller-chosen forward block
@@ -506,7 +596,8 @@ def _flash_streamed_bwd(causal, block_q, block_k, interpret, res, g):
     # (it ran, so it divides the length) rather than divide by zero.
     bq = _pick_block(q.shape[1], min(block_q, 512)) or block_q
     bk = _pick_block(k.shape[1], min(block_k, 512)) or block_k
-    return _flash_bwd_impl(q, k, v, o, lse, g, causal, bq, bk, interpret)
+    return _flash_bwd_impl(q, k, v, o, lse, g, causal, bq, bk, interpret,
+                           window)
 
 
 _flash_streamed.defvjp(_flash_streamed_fwd, _flash_streamed_bwd)
@@ -597,14 +688,18 @@ def _program_lanes(heads: int, d: int) -> int:
     return d if heads == 1 or d % 128 == 0 else 128
 
 
-def flash_path(tq: int, tk: int, d: int, dtype, heads: int = 0) -> str:
+def flash_path(tq: int, tk: int, d: int, dtype, heads: int = 0,
+               window: Optional[int] = None) -> str:
     """Which kernels ``flash_attention`` runs at these shapes, a pure
     function of them. The resident kernels for self-attention lengths that
     split into in-body blocks and whose row fits the VMEM budget: on the
     projections' own layout ("resident_packed") where ``heads`` heads of
     ``d`` are whole 128-lane column blocks, on folded [b*h, t, d] copies
     ("resident") where they are not or ``heads`` is not given. "streamed"
-    for everything else (cross-length calls, 8k and longer), as before."""
+    for everything else (cross-length calls, 8k and longer), as before, and
+    for every call with a ``window``: the resident kernels have none."""
+    if window is not None:
+        return "streamed"
     fits = tq == tk and _resident_block(tq) and _resident_bytes(
         tq, d, jnp.dtype(dtype).itemsize) <= _RESIDENT_BUDGET
     if not fits:
@@ -1031,9 +1126,14 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Drop-in for ``scaled_dot_product_attention`` (same [b, t, h, d]
-    convention). Falls back to the XLA formulation when the kernel
+    convention). With ``window`` (causal calls only) a query sees itself and
+    the ``window - 1`` keys before it: the streamed kernels then visit only
+    the key blocks of a query block's band (the k axis of their grid is the
+    band, and the two edge blocks are masked), so the work is ``t * window``
+    and not ``t * t / 2``. Falls back to the XLA formulation when the kernel
     can't apply (key-validity mask, sequence lengths that no block
     size divides, or causal cross-attention with tq > tk — whose
     zero-attendable-key rows the online softmax would silently average
@@ -1045,19 +1145,33 @@ def flash_attention(
     where the XLA formulation OOMs on the [b, h, t, t] buffer."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is for causal calls, and holds at "
+                             f"least the query's own key: got {window}")
+        get_registry().counter(
+            FLASH_WINDOWED_COUNTER, "flash_attention calls traced with a "
+            "window").inc()
+        if window >= tk:
+            window = None  # every earlier key is inside it: plain causal
     # v5e-tuned defaults: causal favors square 1024-blocks (fewer
     # diagonal crossings per live block); non-causal favors 512x1024
     if block_q is None:
         block_q = 1024 if causal else 512
     if block_k is None:
         block_k = 1024
+    if window is not None:
+        # a block no wider than the window: a query block then meets the
+        # diagonal block and the one or two before it
+        block_q, block_k = (min(z, max(128, window)) for z in (block_q, block_k))
     bq = _pick_block(tq, block_q)
     bk = _pick_block(tk, block_k)
     if mask is not None or not bq or not bk or (causal and tq > tk):
-        return scaled_dot_product_attention(q, k, v, causal=causal, mask=mask)
+        return scaled_dot_product_attention(q, k, v, causal=causal, mask=mask,
+                                            window=window)
     if interpret is None:
         interpret = pallas_interpret()
-    path = flash_path(tq, tk, d, q.dtype, heads=h)
+    path = flash_path(tq, tk, d, q.dtype, heads=h, window=window)
     _count_path(path)
     if path == "resident_packed":
         # the projections' layout is the kernels': free reshapes, no copy
@@ -1072,7 +1186,7 @@ def flash_attention(
     if path == "resident":
         o = _flash_resident(q, k, v, 1, causal, interpret)
     else:
-        o = _flash_streamed(q, k, v, causal, bq, bk, interpret)
+        o = _flash_streamed(q, k, v, causal, bq, bk, interpret, window)
     with jax.named_scope("unfold_heads"):
         return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
 

@@ -448,6 +448,9 @@ KNOWN_DL4J_METRICS = {
     "dl4j_recompute_kept_values",
     "dl4j_span_passes",
     "dl4j_block_applications",
+    "dl4j_selscan_path_total",
+    "dl4j_flash_windowed_total",
+    "dl4j_forwarded_values",
     # serving plane (parallel/inference.py ParallelInference)
     "dl4j_infer_requests_total",
     "dl4j_infer_batches_total",

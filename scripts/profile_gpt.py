@@ -21,10 +21,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from deeplearning4j_tpu.monitor import (FLASH_PATH_COUNTER, SSD_PATH_COUNTER,
+from deeplearning4j_tpu.monitor import (FLASH_PATH_COUNTER,
+                                        FLASH_WINDOWED_COUNTER,
+                                        SELSCAN_PATH_COUNTER, SSD_PATH_COUNTER,
                                         get_registry)
 from deeplearning4j_tpu.nn.multilayer import (HYBRID_STEP_SCOPES,
-                                              LOOPED_STEP_SCOPES, STEP_SCOPES)
+                                              LOOPED_STEP_SCOPES,
+                                              SAMBAY_STEP_SCOPES, STEP_SCOPES)
 from deeplearning4j_tpu.util import profiler
 from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 from deeplearning4j_tpu.util.device import device_peaks
@@ -115,7 +118,8 @@ def profile_cell(workload, seed, dispatches=3):
     # one tick a traced call, by the path its shapes chose
     print("kernels chosen while tracing:", {
         f"{name}{dict(labels)}": metric.value
-        for name in (FLASH_PATH_COUNTER, SSD_PATH_COUNTER)
+        for name in (FLASH_PATH_COUNTER, FLASH_WINDOWED_COUNTER,
+                     SSD_PATH_COUNTER, SELSCAN_PATH_COUNTER)
         for labels, metric in get_registry().family(name).items()})
     log_dir = os.path.join("chiprun_out", "trace-" + workload)
     with profiler.trace(log_dir):
@@ -123,6 +127,7 @@ def profile_cell(workload, seed, dispatches=3):
             r.dispatch()
     # the family by the key only its configurations have
     scopes = (LOOPED_STEP_SCOPES if "total_ut_steps" in cell["config"]
+              else SAMBAY_STEP_SCOPES if "sliding_window" in cell["config"]
               else HYBRID_STEP_SCOPES if "layer_types" in cell["config"]
               else STEP_SCOPES)
     print_trace(log_dir, dispatches * r.k, scopes)
