@@ -46,6 +46,7 @@ FULL = dict(
     flash_shapes=(  # (batch, time, heads, head_dim, backward too)
         (8, 2048, 8, 128, True), (16, 1024, 8, 64, True),
         (1, 16384, 8, 128, False), (1, 32768, 8, 128, True)),
+    rotary_shapes=((2, 4096, 16, 128), (2, 2048, 8, 64)),
     lstm=dict(vocab=64, hidden=512, seq=128, batch=1024),
     gemm_n=8192, gemm_chain=8,
 )
@@ -58,6 +59,7 @@ TINY = dict(
     preempt_lengths=(50, 44, 33, 27),
     classify_len=16,
     flash_shapes=((2, 64, 2, 16, True), (1, 128, 2, 8, False)),
+    rotary_shapes=((2, 64, 2, 64), (1, 32, 3, 16)),
     lstm=dict(vocab=16, hidden=128, seq=8, batch=8),
     gemm_n=256, gemm_chain=2,
 )
@@ -68,6 +70,8 @@ TINY = dict(
 #: chains a handful of roundings (scores, weights, accumulator casts)
 FLASH_TOL = 2e-2
 LSTM_TOL = 2e-2
+#: one bfloat16 rounding of the result, and float32 angles at 4k positions
+ROTARY_TOL = 1e-2
 #: ... and for the LSTM's gradients, which compound 128 recurrent steps
 #: of it and (the bias) sum 131072 bf16 terms: 5e-2 measured under the
 #: interpreter at the benchmark shape, the rest under 1e-2
@@ -479,6 +483,42 @@ def _flash_check(s: Smoke, b, t, h, d, backward: bool) -> None:
                      f"{n_rows} query rows) {time.perf_counter() - t0:.1f}s")
 
 
+def _rotary_check(s: Smoke, b, t, h, d) -> None:
+    """``rotary`` (the Pallas pass where the heads are whole 128-lane
+    column blocks: one roll a head of 128, two and a select where two
+    heads share the lanes) and its gradient rule against the rotation
+    written out in complex float32 on the same bfloat16 inputs."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.ops.attention import rotary
+
+    theta = 1e6
+    key = jax.random.PRNGKey(t + d)
+    x, g = (jax.random.normal(jax.random.fold_in(key, i), (b, t, h, d),
+                              jnp.bfloat16) for i in range(2))
+
+    def reference(x):
+        z = jax.lax.complex(*jnp.split(x.astype(jnp.float32), 2, axis=-1))
+        angle = jnp.arange(t, dtype=jnp.float32)[:, None, None] * theta ** (
+            -jnp.arange(d // 2, dtype=jnp.float32) / (d // 2))
+        z = z * jnp.exp(1j * angle)
+        return jnp.concatenate([z.real, z.imag], axis=-1)
+
+    def both(f):
+        def out_and_dx(x, g):
+            out, pull = jax.vjp(f, x)
+            return (out,) + pull(g.astype(out.dtype))
+        return jax.jit(out_and_dx)
+    t0 = time.perf_counter()
+    errs = [_normalized_error(got, want) for got, want in zip(
+        both(lambda x: rotary(x, theta))(x, g), both(reference)(x, g))]
+    msg = (f"rotary b{b} t{t} h{h} hd{d}: fwd err {errs[0]:.1e} dx err "
+           f"{errs[1]:.1e}")
+    assert max(errs) <= ROTARY_TOL, f"{msg} > {ROTARY_TOL}"
+    s.log("kernels", f"{msg} (tol {ROTARY_TOL}, vs complex float32 "
+                     f"jax.numpy) {time.perf_counter() - t0:.1f}s")
+
+
 def _lstm_check(s: Smoke) -> None:
     import jax
     import jax.numpy as jnp
@@ -561,6 +601,8 @@ def phase_kernels(s: Smoke) -> None:
     before = s.watch.snapshot()
     for shape in s.cfg["flash_shapes"]:
         _flash_check(s, *shape)
+    for shape in s.cfg["rotary_shapes"]:
+        _rotary_check(s, *shape)
     _lstm_check(s)
     s.log("kernels", f"programs {s.compiles_since(before)}")
 

@@ -27,6 +27,7 @@ from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
 # the module itself: ``ops/__init__`` re-exports the function under its name
 FA = importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
+ATTENTION = importlib.import_module("deeplearning4j_tpu.ops.attention")
 PATHS = ("resident", "resident_packed", "streamed")
 
 
@@ -493,12 +494,15 @@ def test_the_4k_row_lowers_to_the_resident_kernels(d):
     assert _kernel_names(4096, d) == ["flash_dq_dkv", "flash_fwd"]
 
 
-def _block_step_text(monkeypatch, block, b, t, width):
+def _block_step_text(monkeypatch, block, b, t, width,
+                     kernels=("flash_dq_dkv", "flash_fwd")):
     """The training step of one block on [b, t, width] in bfloat16, lowered
-    for the TPU with the kernels as Mosaic calls: the resident pair, and no
-    transpose but of the weight gradients (two-dimensional), so no head
-    fold stands anywhere in the step."""
+    for the TPU with the kernels as Mosaic calls: the resident pair (and
+    what else the block names in ``kernels``), and no transpose but of the
+    weight gradients (two-dimensional), so no head fold stands anywhere in
+    the step."""
     monkeypatch.setattr(FA, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(ATTENTION, "pallas_interpret", lambda: False)
     bf16 = lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16)
     params = jax.tree.map(bf16, jax.eval_shape(block.init_params,
                                                jax.random.key(0)))
@@ -510,8 +514,8 @@ def _block_step_text(monkeypatch, block, b, t, width):
     with jax.enable_x64(False):  # the suite's x64 is not the chip's setting
         text = jax.jit(jax.grad(loss, (0, 1))).trace(params, x).lower(
             lowering_platforms=("tpu",)).as_text()
-    assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
-        "flash_dq_dkv", "flash_fwd"]
+    assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == sorted(
+        kernels)
     for shape in re.findall(r"stablehlo\.transpose .*?\(tensor<([\dx]+)x\w+>\)",
                             text):
         assert shape.count("x") == 1, shape  # a weight gradient's
@@ -561,7 +565,12 @@ def test_looped_block_step_hands_q_k_v_to_the_kernels_as_they_lie(
     the TPU: what ``rope`` and ``kv_repeat`` make goes into ``flash_fwd`` by
     reshapes alone, the kernel's o into ``attn_out_proj``'s product, and the
     backward is the one kernel: no transpose but of the weight gradients
-    (two-dimensional) stands anywhere in the step, so no head fold does."""
+    (two-dimensional) stands anywhere in the step, so no head fold does.
+    ``rope`` is one pass over q and one over k on [b, t, h * d], forward
+    and backward: between ``qkv_proj``'s products and the kernels, and
+    between the kernel's gradients and the weight-gradient products, a
+    [b, t, h, d] value is only ever reshaped (but for ``kv_repeat``'s
+    transpose, a sum over the one copy of each head)."""
     from deeplearning4j_tpu.models.zoo.looped_lm import looped_lm
 
     b, t, h, d = 2, 4096, 16, 128
@@ -569,7 +578,14 @@ def test_looped_block_step_hands_q_k_v_to_the_kernels_as_they_lie(
         hidden_size=h * d, vocab_size=64, num_hidden_layers=1, total_ut_steps=1,
         num_attention_heads=h, num_key_value_heads=h, head_dim=d,
         intermediate_size=256, rms_norm_eps=1e-6, rope_theta=1e6)).impls[1]
-    text, made = _block_step_text(monkeypatch, block, b, t, h * d)
+    whole, _ = _block_step_text(
+        monkeypatch, block, b, t, h * d,
+        kernels=["flash_dq_dkv", "flash_fwd"] + ["rotary_turn"] * 2)
+    # value names start again in every function: follow them in the step's
+    # own body, where ``rope``, the kernels and the products are called
+    text = whole[:whole.index("func.func private")]
+    made = dict(re.findall(r"(%\d+)(?::\d+)? = (?:stablehlo\.|call @)(\w+)",
+                           text))
     source = dict(re.findall(r"(%\d+) = stablehlo\.reshape (%\d+) ", text))
     fwd = re.search(r"(%\d+):2 = call @_resident_fwd\((%\d+), (%\d+), "
                     r"(%\d+)\)", text)
@@ -580,3 +596,33 @@ def test_looped_block_step_hands_q_k_v_to_the_kernels_as_they_lie(
     bwd = re.search(r"(%\d+):3 = call @_resident_bwd\((%\d+), (%\d+), "
                     + r"(%\d+), " + f"{o}#0, {o}#1, " + r"(%\d+)\)", text)
     assert bwd and list(bwd.groups()[1:4]) == qkv
+    # q and k: the product, the pass, the kernel, with reshapes between (and
+    # for k the one copy ``kv_repeat`` makes of each head)
+    source.update(re.findall(
+        r"(%\d+) = stablehlo\.(?:reshape |broadcast_in_dim |reduce\()"
+        r"(%\d+(?:#\d+)?)[ ,]", text))
+    passed = dict(re.findall(
+        r"(%\d+) = call @_rotate\w*\((%\d+)\) : \(tensor<2x4096x2048xbf16>\)"
+        r" -> tensor<2x4096x2048xbf16>", text))
+
+    def origin(z):
+        while z in source:
+            z = source[z]
+        return z
+    turned = [origin(z) for z in qkv[:2]]
+    assert made[turned[0]] == made[turned[1]] == "_rotate"
+    assert [made[origin(passed[z])] for z in turned] == ["dot_general"] * 2
+    # their gradients: the kernel, the pass, the two gradient products
+    back = {origin(arg): z for z, arg in passed.items() if z not in turned}
+    assert sorted(back) == [f"{bwd.group(1)}#{i}" for i in (0, 1)]
+    assert len({made[z] for z in back.values()}) == 1
+    users = [origin(z) for z in re.findall(
+        r"stablehlo\.dot_general (%\d+), ", text)]
+    for z in back.values():
+        assert users.count(z) == 2, z
+    # and nothing but a reshape makes a [b, t, h, d] value in any function
+    # of the step (the two sums are ``kv_repeat``'s transpose, over the one
+    # copy of a head)
+    rank4 = re.findall(r"= (?:stablehlo\.|call @)(\w+)[^\n]*"
+                       r"-> tensor<2x4096x16x128x\w+>\n", whole)
+    assert set(rank4) == {"reshape", "reduce"} and rank4.count("reduce") == 2

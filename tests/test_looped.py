@@ -5,6 +5,8 @@ a hand count; the sandwich block and the whole tiny model against the plain
 reference (``benchmarks/reference/ouro_looped_plain.py``); what a recomputed
 block keeps; the keys the configuration classes gained."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -83,6 +85,74 @@ def test_rotated_scores_depend_on_the_distance_alone():
         diagonal = np.diagonal(scores, offset=-gap)
         np.testing.assert_allclose(diagonal, diagonal[0], rtol=1e-4, atol=1e-5)
     assert np.ptp(scores) > 0.1  # and they do depend on it
+
+
+def _rotary_as_written(x, theta):
+    """``rotary`` as it stood on [b, t, h, d]: halves sliced out of the last
+    axis, a [t, 1, d/2] table over the heads, a concatenate."""
+    t, d = x.shape[1], x.shape[-1]
+    half = d // 2
+    freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                   * (-2.0 * math.log(theta) / d))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _assert_same_rotation(got, want, rtol):
+    """Equal to ``rtol`` in float32 (the two forms contract their
+    multiply-adds differently: a last bit of the larger product), to one
+    ulp in bfloat16 (a last bit of float32 can cross a rounding boundary)."""
+    assert got.dtype == want.dtype and got.shape == want.shape
+    got, want = (np.asarray(z, np.float32) for z in (got, want))
+    if rtol is None:
+        gap = np.abs(got - want)
+        ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
+        assert (gap <= ulp + 1e-6).all(), gap.max()
+        assert (gap > 0).mean() < 0.01
+    else:
+        np.testing.assert_allclose(got, want, rtol=rtol, atol=rtol)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (4, 2)])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rotary_on_the_flat_layout_is_the_written_out_rotation(
+        dtype, d, heads, kv_heads):
+    """q and k of a grouped-query block at widths the Pallas pass takes
+    (heads in whole 128-lane column blocks): the forward against the
+    formula on [b, t, h, d], the gradient rule (the inverse rotation of the
+    incoming gradient) against autodiff of that formula."""
+    theta, t = 1e6, 256
+    for n, seed in ((heads, 3), (kv_heads, 4)):
+        kx, kg = jax.random.split(jax.random.PRNGKey(seed))
+        x = jax.random.normal(kx, (2, t, n, d), jnp.float32).astype(dtype)
+        g = jax.random.normal(kg, (2, t, n, d), jnp.float32).astype(dtype)
+        got, pull = jax.vjp(lambda x: rotary(x, theta), x)
+        want, pull_written = jax.vjp(lambda x: _rotary_as_written(x, theta), x)
+        loose = dtype == jnp.bfloat16
+        _assert_same_rotation(got, want, None if loose else 1e-6)
+        _assert_same_rotation(pull(g)[0], pull_written(g)[0],
+                              None if loose else 1e-5)
+
+
+def test_rotary_keeps_float32_angles_at_position_65535():
+    """A bfloat16 angle at position 65k is off by whole turns; the float32
+    one is off by 65,535 roundings of 6e-8, a few thousandths of a turn."""
+    theta, t, d = 1e6, 65536, 128
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, t, 1, d), jnp.float32)
+    got = rotary(x, theta)
+    _assert_same_rotation(got, _rotary_as_written(x, theta), 1e-6)
+    z = np.asarray(x[..., :d // 2], np.float64) \
+        + 1j * np.asarray(x[..., d // 2:], np.float64)
+    freq = theta ** (-np.arange(d // 2) / (d // 2))
+    turned = z * np.exp(1j * np.arange(t)[None, :, None, None] * freq)
+    exact = np.concatenate([turned.real, turned.imag], axis=-1)
+    np.testing.assert_allclose(got[:, -256:], exact[:, -256:], atol=0.05)
+    assert np.abs(exact[:, -256:] - np.asarray(x[:, -256:])).max() > 1.0
 
 
 # --------------------------------------------------------- the exit head
