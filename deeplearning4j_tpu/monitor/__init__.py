@@ -22,11 +22,15 @@ program), ``device_step`` (compiled train step), ``all_reduce``
 resolution of the deferred-score ring), and under ``fit_scan``'s
 ``compile`` / ``device_step`` its two children: ``launch`` (the call of
 the compiled program: argument handling and enqueue, it returns before
-the device is done; under ``compile`` it is ``compile_launch``, the
-call that also traces, lowers and loads or compiles the program, so
-that the ``launch`` histogram holds steady-state calls only) and
-``fetch`` (the score fetch: the wait for the device and the
-device→host copy). Every span record carries ``id``,
+the device is done; under ``compile`` it is ``compile_launch``, which
+also makes the program, so that the ``launch`` histogram holds
+steady-state calls only) and ``fetch`` (the score fetch: the wait for
+the device and the device→host copy). ``compile_launch`` has four
+children that tile it (``nn/scan_dispatch.py``): ``trace_step`` (the
+step traced to a jaxpr), ``lower_step`` (lowered to StableHLO),
+``load_step`` (the executable retrieved from the persistent cache and
+loaded, or compiled by the backend) and ``first_launch`` (the call of
+what was made). Every span record carries ``id``,
 ``parent`` (the span open on the same thread when it started, or null)
 and ``dispatch`` (its tree's root, shared by the spans of one dispatch);
 the same spans appear as ``dl4j/<name>`` on the host plane of a device
@@ -43,7 +47,15 @@ to the canonical shape), ``dl4j_jit_cache_miss_total`` (train-step
 dispatches that had to trace+compile), ``dl4j_score_sync_total``
 (device→host score fetches — each one is a chip round-trip).
 ``fit_scan`` ticks ``dl4j_jit_cache_miss_total`` on the first dispatch of
-each program, as ``fit`` does.
+each program, as ``fit`` does; a staged set of another shape or dtype is
+another program. What that first dispatch made is set once, from what
+``load_step`` returned and once the dispatch is enqueued (the analyses
+are read while the host would only wait):
+``dl4j_step_program_bytes{part=...}`` (``code``: the
+executable's size; ``arguments``, ``temporaries``, ``outputs``,
+``aliased``: the compiler's memory count) and ``dl4j_step_program_flops``
+(XLA's operation count of a step); a runtime that gives no analysis
+leaves them unset.
 
 The serving plane (parallel/inference.py ``ParallelInference``)
 publishes ``dl4j_infer_requests_total`` / ``dl4j_infer_batches_total``
@@ -140,6 +152,15 @@ BLOCK_APPLICATIONS_GAUGE = "dl4j_block_applications"
 # nn/multilayer.py: named values that layers of that step hand forward to
 # later layers (Layer.provides); 0 for a plain chain
 FORWARDED_VALUES_GAUGE = "dl4j_forwarded_values"
+# nn/scan_dispatch.py: the fit_scan program the last first dispatch made, by
+# the compiler's own count (``memory_analysis()``), labeled part="code" (the
+# executable's generated code), "arguments", "temporaries", "outputs" or
+# "aliased" (outputs that alias donated arguments); arguments + temporaries +
+# outputs - aliased is what the step needs of the device's memory
+STEP_PROGRAM_BYTES_GAUGE = "dl4j_step_program_bytes"
+# nn/scan_dispatch.py: XLA's operation count of that program
+# (``cost_analysis()``; a scanned step's body counts once)
+STEP_PROGRAM_FLOPS_GAUGE = "dl4j_step_program_flops"
 
 # Serving plane (parallel/inference.py ParallelInference — the
 # micro-batching engine behind StreamingInference): request/batch

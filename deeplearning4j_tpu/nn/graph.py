@@ -39,6 +39,7 @@ from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.graph import GraphVertex, vertex_from_dict
 from deeplearning4j_tpu.monitor import H2D_BYTES_COUNTER, get_registry, span
 from deeplearning4j_tpu.nn.conf.layers import layer_from_dict
+from deeplearning4j_tpu.nn.scan_dispatch import scan_dispatch
 from deeplearning4j_tpu.optimize.deferred import (
     host_step,
     note_dispatch,
@@ -686,22 +687,7 @@ class ComputationGraph:
         if self.params is None:
             self.init()
         xb, yb = staged if staged is not None else self.stage_scan(data, batch_size)
-        key = ("scan_fit", epochs, self._seq_token())
-        compiling = key not in self._jits
-        if compiling:
-            self._jits[key] = self._make_scan_fit(epochs)
-        fit = self._jits[key]
-        rng_key = self._train_rng()
-        # the span tree of MultiLayerNetwork.fit_scan: the call, the fetch
-        with span("compile" if compiling else "device_step",
-                  path="graph_fit_scan", epochs=epochs):
-            with span("compile_launch" if compiling else "launch"):
-                self.params, self.opt_state, self.states, scores = fit(
-                    self.params, self.opt_state, self.states, xb, yb, rng_key)
-            with span("fetch"):
-                out = np.asarray(scores)  # score fetch = device sync
-        self._score = float(out[-1])
-        return out
+        return scan_dispatch(self, "graph_fit_scan", epochs, xb, yb)
 
     # ------------------------------------------------------------- pretrain
 

@@ -51,8 +51,8 @@ from deeplearning4j_tpu.monitor import (BLOCK_APPLICATIONS_GAUGE,
                                         RECOMPUTED_BLOCKS_GAUGE,
                                         SPAN_PASSES_GAUGE, get_registry, span)
 from deeplearning4j_tpu.nn.observed import SyncedStateAttr
+from deeplearning4j_tpu.nn.scan_dispatch import scan_dispatch
 from deeplearning4j_tpu.optimize.deferred import (
-    count_jit_cache_miss,
     host_step,
     note_dispatch,
     score_sink,
@@ -866,28 +866,7 @@ class MultiLayerNetwork:
         if self.params is None:
             self.init()
         xb, yb = staged if staged is not None else self.stage_scan(ds, batch_size)
-        key = ("scan_fit", epochs, self._seq_token())
-        compiling = key not in self._jits
-        if compiling:
-            self._jits[key] = self._make_scan_fit(epochs)
-        fit = self._jits[key]
-        rng_key = self._train_rng()
-        if compiling:
-            count_jit_cache_miss()
-        # one dispatch = one span tree: the parent, the call (argument
-        # handling and enqueue: it returns before the device is done; the
-        # first call of a program also traces, lowers and loads it, and
-        # goes by a name of its own) and the fetch (the wait and the
-        # device-to-host copy)
-        with span("compile" if compiling else "device_step",
-                  path="fit_scan", epochs=epochs):
-            with span("compile_launch" if compiling else "launch"):
-                self.params, self.opt_state, self.states, scores = fit(
-                    self.params, self.opt_state, self.states, xb, yb, rng_key)
-            with span("fetch"):
-                out = np.asarray(scores)  # score fetch = device sync
-        self._score = float(out[-1])
-        return out
+        return scan_dispatch(self, "fit_scan", epochs, xb, yb)
 
     # ------------------------------------------------------------- inference
 

@@ -117,6 +117,8 @@ SCOPE_STAT = "tf_op"
 #: children by the row of ``gaps_by_host_span`` that each is put down to
 DISPATCH_SPANS = ("compile", "device_step")
 _CHILD_KEYS = {"launch": "launch", "compile_launch": "launch",
+               "trace_step": "launch", "lower_step": "launch",
+               "load_step": "launch", "first_launch": "launch",
                "fetch": "fetch"}
 
 _WRAPPED = re.compile(r"^(?:\w+\()*([^()]*)\)*$")
@@ -309,8 +311,8 @@ def gaps_by_host_span(profile) -> Dict[str, object]:
       began — the device is done, the host still waits and copies;
     - ``python``: inside no child span — the caller's loop and the
       span's own bookkeeping between ``fetch`` and ``launch``;
-    - ``launch``: inside a ``launch`` (or ``compile_launch``) span —
-      argument handling, enqueue;
+    - ``launch``: inside a ``launch`` span (or ``compile_launch`` and its
+      four stages) — argument handling, enqueue;
     - ``unattributed``: after ``launch`` returned and before the first
       op ran — the device's own start, which no host span explains.
 

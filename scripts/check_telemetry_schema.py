@@ -43,7 +43,9 @@ OPTIONAL_KEYS = {"attrs": dict}
 # the span tree (monitor/tracing.py): a span's own id, the id of the span
 # that was open on its thread when it started (null for a root), and the id
 # of its tree's root, which the spans of one dispatch share — fit_scan emits
-# ``compile`` / ``device_step`` with the children ``launch`` and ``fetch``
+# ``device_step`` > ``launch``, ``fetch`` and, for a program's first
+# dispatch, ``compile`` > ``compile_launch`` (> ``trace_step``,
+# ``lower_step``, ``load_step``, ``first_launch``), ``fetch``
 SPAN_TREE_KEYS = {"id": int, "parent": (int, type(None)), "dispatch": int}
 
 
@@ -451,6 +453,8 @@ KNOWN_DL4J_METRICS = {
     "dl4j_selscan_path_total",
     "dl4j_flash_windowed_total",
     "dl4j_forwarded_values",
+    "dl4j_step_program_bytes",
+    "dl4j_step_program_flops",
     # serving plane (parallel/inference.py ParallelInference)
     "dl4j_infer_requests_total",
     "dl4j_infer_batches_total",

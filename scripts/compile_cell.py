@@ -1,8 +1,11 @@
 """Compile a benchmark cell's ``fit_scan`` program for a v5e in a sandbox that
-has none, and print what the compiler says of it: ``memory_analysis()`` (the
-only number that sees the step's temporaries), ``cost_analysis()``'s flops a
-step, and the Pallas kernels in the compiled text. An AOT compile, not a chip
-run: nothing is executed and nothing here is a time.
+has none, and print what the compiler says of it: the gauges a chip run's
+first dispatch sets from ``memory_analysis()`` and ``cost_analysis()``
+(``nn/scan_dispatch.record_step_program``: the only numbers that see the
+step's temporaries; the benchmark's ``step_compiled_peak_gb`` and
+``step_executable_mib`` read the same gauges on the chip), and the Pallas
+kernels in the compiled text. An AOT compile, not a chip run: nothing is
+executed and nothing here is a time.
 
 Usage: python scripts/compile_cell.py <cell of BENCHMARK.json>
 
@@ -27,6 +30,8 @@ from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks import run as bench
+from deeplearning4j_tpu.nn.scan_dispatch import (record_step_program,
+                                                 step_program_report)
 
 
 def main(cell):
@@ -68,19 +73,19 @@ def main(cell):
     t0 = time.perf_counter()
     compiled = fit.trace(*shapes, batch, batch, on_chip((2,), jnp.uint32)) \
         .lower(lowering_platforms=("tpu",)).compile()
-    mem = compiled.memory_analysis()
+    record_step_program(compiled)
+    made = step_program_report()
     kernels = collections.Counter(
         re.match(r"\s*%([A-Za-z_]+)", line).group(1)
         for line in compiled.as_text().splitlines()
         if 'custom_call_target="tpu_custom_call"' in line)
     print(json.dumps({
         "cell": cell, "compile_s": round(time.perf_counter() - t0, 1),
-        "arguments_GB": mem.argument_size_in_bytes / 1e9,
-        "temporaries_GB": mem.temp_size_in_bytes / 1e9,
-        "count_GB": (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-                     + mem.output_size_in_bytes
-                     - mem.alias_size_in_bytes) / 1e9,
-        "flops_a_step_T": compiled.cost_analysis()["flops"] / 1e12,
+        "arguments_GB": made["arguments_bytes"] / 1e9,
+        "temporaries_GB": made["temporaries_bytes"] / 1e9,
+        "count_GB": made["count_bytes"] / 1e9,
+        "code_MiB": made["code_bytes"] / 2 ** 20,
+        "flops_a_step_T": made["flops"] / 1e12,
         "kernel_calls_a_step": dict(kernels)}))
 
 

@@ -25,6 +25,8 @@ from deeplearning4j_tpu.monitor import (FLASH_PATH_COUNTER,
                                         FLASH_WINDOWED_COUNTER,
                                         SELSCAN_PATH_COUNTER, SSD_PATH_COUNTER,
                                         get_registry)
+from deeplearning4j_tpu.monitor import phase_breakdown
+from deeplearning4j_tpu.nn.scan_dispatch import step_program_report
 from deeplearning4j_tpu.nn.multilayer import (HYBRID_STEP_SCOPES,
                                               LOOPED_STEP_SCOPES,
                                               SAMBAY_STEP_SCOPES, STEP_SCOPES)
@@ -121,6 +123,19 @@ def profile_cell(workload, seed, dispatches=3):
         for name in (FLASH_PATH_COUNTER, FLASH_WINDOWED_COUNTER,
                      SSD_PATH_COUNTER, SELSCAN_PATH_COUNTER)
         for labels, metric in get_registry().family(name).items()})
+    # the first dispatch by stage, and what it made by the compiler's count
+    phases = phase_breakdown()
+    print("first dispatch, s:", {
+        p: round(phases[p]["total_ms"] / 1e3, 3)
+        for p in ("compile", "compile_launch", "trace_step", "lower_step",
+                  "load_step", "first_launch") if p in phases})
+    made = step_program_report()
+    print("the step's program:", made and {
+        "code_MiB": made["code_bytes"] / 2 ** 20,
+        **{k[:-len("_bytes")] + "_GB": made[k] / 1e9
+           for k in ("arguments_bytes", "temporaries_bytes", "outputs_bytes",
+                     "aliased_bytes", "count_bytes")},
+        "xla_flops_a_step_T": made.get("flops", float("nan")) / 1e12})
     log_dir = os.path.join("chiprun_out", "trace-" + workload)
     with profiler.trace(log_dir):
         for _ in range(dispatches):

@@ -5,6 +5,7 @@ from benchmarks.tests.test_layer_readers import *  # noqa: F401,F403
 from benchmarks.tests.test_hybrid_cell import *  # noqa: F401,F403
 from benchmarks.tests.test_looped_cell import *  # noqa: F401,F403
 from benchmarks.tests.test_sambay_cell import *  # noqa: F401,F403
+from benchmarks.tests.test_setup_readers import *  # noqa: F401,F403
 
 
 def test_no_test_of_the_benchmark_is_shadowed_by_another():

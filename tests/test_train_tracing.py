@@ -111,45 +111,7 @@ def test_grad_norm_scope_appears_when_normalization_is_on():
 
 # --------------------------------------------------------------- span tree
 
-def test_fit_scan_dispatch_is_a_span_tree(registry, no_compile_cache):
-    net, staged = _net_and_set()
-    tracer = monitor.enable_tracing()
-    net.fit_scan(None, 2, staged=staged)
-    net.fit_scan(None, 2, staged=staged)
-    monitor.disable_tracing()
-    spans = [e for e in tracer.events() if e["type"] == "span"
-             and e["name"] in ("compile", "device_step", "compile_launch",
-                               "launch", "fetch")]
-    # the call that compiles goes by a name of its own
-    assert [s["name"] for s in spans] == ["compile_launch", "fetch", "compile",
-                                          "launch", "fetch", "device_step"]
-    by_id = {s["id"]: s for s in spans}
-    assert len(by_id) == 6
-    for first in (0, 3):
-        launch, fetch, parent = spans[first:first + 3]
-        assert parent["parent"] is None and parent["dispatch"] == parent["id"]
-        t0, t1 = parent["ts_us"], parent["ts_us"] + parent["dur_us"]
-        for child in (launch, fetch):
-            assert by_id[child["parent"]] is parent  # the edge resolves
-            assert child["dispatch"] == parent["id"]  # one id a dispatch
-            assert t0 <= child["ts_us"]
-            assert child["ts_us"] + child["dur_us"] <= t1 + 1e-3
-        assert launch["ts_us"] + launch["dur_us"] <= fetch["ts_us"] + 1e-3
-    assert spans[2]["dispatch"] != spans[5]["dispatch"]
-
-    # the launch of the first dispatch held the trace, the lowering and the
-    # compile; the second's did not
-    assert spans[3]["dur_us"] < spans[0]["dur_us"] / 10
-    assert spans[2]["attrs"] == spans[5]["attrs"] == {"path": "fit_scan",
-                                                      "epochs": 1}
-    # so the launch histogram holds steady-state calls only
-    assert registry.get(monitor.PHASE_HISTOGRAM, phase="launch").count == 1
-    assert registry.get(monitor.PHASE_HISTOGRAM,
-                        phase="compile_launch").count == 1
-    assert registry.get(monitor.PHASE_HISTOGRAM, phase="fetch").count == 2
-
-
-def test_graph_fit_scan_emits_the_same_tree(registry):
+def _graph_and_set():
     from deeplearning4j_tpu.datasets.dataset import MultiDataSet
     from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
     from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
@@ -163,19 +125,175 @@ def test_graph_fit_scan_emits_the_same_tree(registry):
                                           loss_function="mcxent"), "d1")
             .set_outputs("out").build())
     graph = ComputationGraph(conf).init()
-    data = MultiDataSet([np.zeros((4, 4), np.float32)],
-                        [np.eye(2, dtype=np.float32)[[0, 1, 0, 1]]])
+    data = MultiDataSet([np.zeros((8, 4), np.float32)],
+                        [np.eye(2, dtype=np.float32)[[0, 1] * 4]])
+    return graph, graph.stage_scan(data, 2)
+
+
+STAGES = ("trace_step", "lower_step", "load_step", "first_launch")
+TREE = ("compile", "device_step", "compile_launch", "launch", "fetch") + STAGES
+#: the two containers share one dispatch (nn/scan_dispatch.py)
+CONTAINERS = pytest.mark.parametrize("make, path", [
+    (_net_and_set, "fit_scan"), (_graph_and_set, "graph_fit_scan")],
+    ids=["network", "graph"])
+
+
+def _tree_of(tracer):
+    return [e for e in tracer.events()
+            if e["type"] == "span" and e["name"] in TREE]
+
+
+@CONTAINERS
+def test_fit_scan_dispatch_is_a_span_tree(registry, no_compile_cache, make,
+                                          path):
+    net, staged = make()
     tracer = monitor.enable_tracing()
-    graph.fit_scan(data, 2)
-    graph.fit_scan(data, 2)
+    net.fit_scan(None, 2, staged=staged)
+    net.fit_scan(None, 2, staged=staged)
     monitor.disable_tracing()
-    tree = [e for e in tracer.events() if e["type"] == "span"
-            and e["name"] in ("compile", "device_step", "compile_launch",
-                              "launch", "fetch")]
-    assert [s["name"] for s in tree] == ["compile_launch", "fetch", "compile",
-                                         "launch", "fetch", "device_step"]
-    assert tree[3]["parent"] == tree[4]["parent"] == tree[5]["id"]
-    assert tree[5]["attrs"]["path"] == "graph_fit_scan"
+    spans = _tree_of(tracer)
+    # the first dispatch makes the program in stages, each under a name of
+    # its own; the second calls what the first made
+    assert [s["name"] for s in spans] == [
+        *STAGES, "compile_launch", "fetch", "compile",
+        "launch", "fetch", "device_step"]
+    by_id = {s["id"]: s for s in spans}
+    assert len(by_id) == 10
+    end = lambda s: s["ts_us"] + s["dur_us"]
+    for launch, fetch, parent in (spans[4:7], spans[7:10]):
+        assert parent["parent"] is None and parent["dispatch"] == parent["id"]
+        for child in (launch, fetch):
+            assert by_id[child["parent"]] is parent  # the edge resolves
+            assert child["dispatch"] == parent["id"]  # one id a dispatch
+            assert parent["ts_us"] <= child["ts_us"]
+            assert end(child) <= end(parent) + 1e-3
+        assert end(launch) <= fetch["ts_us"] + 1e-3
+    assert spans[6]["dispatch"] != spans[9]["dispatch"]
+
+    # the four stages are compile_launch's children, in order, and tile it
+    # to within a millisecond
+    made = spans[4]
+    for before, stage in zip((None,) + tuple(spans[:3]), spans[:4]):
+        assert by_id[stage["parent"]] is made
+        assert stage["dispatch"] == spans[6]["id"]
+        if before is not None:
+            assert end(before) <= stage["ts_us"] + 1e-3
+    assert made["ts_us"] <= spans[0]["ts_us"] and end(spans[3]) <= end(made) + 1e-3
+    assert abs(made["dur_us"] - sum(s["dur_us"] for s in spans[:4])) < 1e3
+
+    # the stages held the trace, the lowering and the compile: the call of
+    # what they made is a call, as the second dispatch's is
+    assert spans[3]["dur_us"] < made["dur_us"] / 10
+    assert spans[7]["dur_us"] < made["dur_us"] / 10
+    assert spans[6]["attrs"] == spans[9]["attrs"] == {"path": path,
+                                                      "epochs": 1}
+    # so the launch histogram holds steady-state calls only, and the second
+    # dispatch added nothing to the stages' histograms
+    count = lambda phase: registry.get(monitor.PHASE_HISTOGRAM,
+                                       phase=phase).count
+    assert count("launch") == 1 and count("fetch") == 2
+    assert [count(p) for p in ("compile_launch",) + STAGES] == [1] * 5
+
+
+@CONTAINERS
+def test_another_staged_shape_is_a_compile_and_says_so(registry, make, path):
+    net, (xb, yb) = make()
+    miss = lambda: registry.family_total(monitor.JIT_CACHE_MISS_COUNTER)
+    count = lambda phase: getattr(registry.get(
+        monitor.PHASE_HISTOGRAM, phase=phase), "count", 0)
+    net.fit_scan(None, 2, staged=(xb, yb))
+    net.fit_scan(None, 2, staged=(xb, yb))
+    assert (miss(), count("launch"), count("compile")) == (1, 1, 1)
+    # half as many minibatches: the same epochs, another program
+    half = jax.tree.map(lambda a: a[:2], (xb, yb))
+    tracer = monitor.enable_tracing()
+    net.fit_scan(None, 2, staged=half)
+    monitor.disable_tracing()
+    assert [s["name"] for s in _tree_of(tracer)] == [
+        *STAGES, "compile_launch", "fetch", "compile"]
+    assert miss() == 2 and count("launch") == 1  # it did not hide in launch
+    assert [count(p) for p in ("compile", "compile_launch") + STAGES] == [2] * 6
+    # both programs stay: either set's next dispatch is a launch
+    net.fit_scan(None, 2, staged=half)
+    net.fit_scan(None, 2, staged=(xb, yb))
+    assert (miss(), count("launch"), count("compile")) == (2, 3, 2)
+    # another dtype is another program too
+    net.fit_scan(None, 2, staged=jax.tree.map(
+        lambda a: a.astype(np.float16), half))
+    assert (miss(), count("launch"), count("compile")) == (3, 3, 3)
+
+
+def test_the_staged_first_call_is_the_jit_calls_program(registry):
+    # the program that the stages make is the one a plain first call of the
+    # jit function made before: the same text, the same losses to the bit,
+    # the same state after it
+    net, staged = _net_and_set()
+    args = (net.params, net.opt_state, net.states, *staged, net._train_rng())
+    fit = net._make_scan_fit(1)
+    assert fit.trace(*args).lower().as_text() == _lowered(net, staged)
+    staged_losses = net.fit_scan(None, 2, staged=staged)
+
+    plain, plain_staged = _net_and_set()
+    p, o, s, losses = plain._make_scan_fit(1)(
+        plain.params, plain.opt_state, plain.states, *plain_staged,
+        plain._train_rng())
+    assert np.asarray(losses).tobytes() == staged_losses.tobytes()
+    same = jax.tree.map(lambda a, b: np.asarray(a).tobytes()
+                        == np.asarray(b).tobytes(),
+                        (net.params, net.opt_state), (p, o))
+    assert all(jax.tree.leaves(same))
+    # and the second dispatch goes on from there as the jit function does
+    again = net.fit_scan(None, 2, staged=staged)
+    *_, plain_again = plain._make_scan_fit(1)(p, o, s, *plain_staged,
+                                              plain._train_rng())
+    assert np.asarray(plain_again).tobytes() == again.tobytes()
+
+
+def test_what_was_made_is_set_once_as_gauges(registry):
+    from deeplearning4j_tpu.nn import scan_dispatch
+    assert scan_dispatch.step_program_report() is None  # nothing made yet
+    net, staged = _net_and_set()
+    fit = net._make_scan_fit(1)
+    mem = fit.trace(net.params, net.opt_state, net.states, *staged,
+                    net._train_rng()).lower().compile().memory_analysis()
+    net.fit_scan(None, 2, staged=staged)
+    part = lambda p: registry.get(monitor.STEP_PROGRAM_BYTES_GAUGE,
+                                  part=p).value
+    assert set(dict(k)["part"] for k in registry.family(
+        monitor.STEP_PROGRAM_BYTES_GAUGE)) == {
+            "code", "arguments", "temporaries", "outputs", "aliased"}
+    assert part("arguments") == mem.argument_size_in_bytes > 0
+    assert part("temporaries") == mem.temp_size_in_bytes
+    assert part("outputs") == mem.output_size_in_bytes > 0
+    assert part("aliased") == mem.alias_size_in_bytes
+    assert part("code") == mem.generated_code_size_in_bytes
+    flops = registry.get(monitor.STEP_PROGRAM_FLOPS_GAUGE).value
+    assert flops > 0
+    report = scan_dispatch.step_program_report()
+    assert report["count_bytes"] == (
+        part("arguments") + part("temporaries") + part("outputs")
+        - part("aliased"))
+    assert report["flops"] == flops and report["code_bytes"] == part("code")
+    # a steady-state dispatch sets nothing again
+    registry.gauge(monitor.STEP_PROGRAM_FLOPS_GAUGE).set(-1.0)
+    net.fit_scan(None, 2, staged=staged)
+    assert registry.get(monitor.STEP_PROGRAM_FLOPS_GAUGE).value == -1.0
+
+
+def test_a_runtime_without_analysis_leaves_the_gauges_unset(registry):
+    from deeplearning4j_tpu.nn import scan_dispatch
+
+    class Silent:
+        def memory_analysis(self):
+            return None  # as a runtime that has none answers
+
+        def cost_analysis(self):
+            raise NotImplementedError("no cost analysis on this platform")
+
+    scan_dispatch.record_step_program(Silent())
+    assert registry.get(monitor.STEP_PROGRAM_FLOPS_GAUGE) is None
+    assert registry.family(monitor.STEP_PROGRAM_BYTES_GAUGE) == {}  # never 0
+    assert scan_dispatch.step_program_report() is None
 
 
 def test_monitor_spans_need_no_jax():
@@ -331,6 +449,33 @@ def test_host_spans_and_gaps_on_a_written_out_profile():
     assert profiler.host_spans(_profile(device, [])) == []
 
 
+def test_gaps_put_the_four_stages_down_to_launch():
+    ms = 10 ** 6
+    device = [_event("%while.1", 60 * ms, 40 * ms)]
+    host = [
+        _event("dl4j/compile", 0, 105 * ms, id=1, dispatch=1),
+        _event("dl4j/compile_launch", 1 * ms, 54 * ms, id=2, dispatch=1),
+        _event("dl4j/trace_step", 1 * ms, 20 * ms, id=3, dispatch=1),
+        _event("dl4j/lower_step", 21 * ms, 10 * ms, id=4, dispatch=1),
+        _event("dl4j/load_step", 31 * ms, 20 * ms, id=5, dispatch=1),
+        _event("dl4j/first_launch", 51 * ms, 4 * ms, id=6, dispatch=1),
+        _event("dl4j/fetch", 56 * ms, 48 * ms, id=7, dispatch=1),
+    ]
+    got = profiler.gaps_by_host_span(_profile(device, host))
+    # idle 0..60 and 100..105: 1 python, 54 launch (the stages are inside
+    # compile_launch: counted once), 1 python, 4 device start | 4 fetch, 1
+    assert got["launch_s"] == pytest.approx(0.054)
+    assert got["python_s"] == pytest.approx(0.003)
+    assert got["unattributed_s"] == pytest.approx(0.004)
+    assert got["fetch_s"] == pytest.approx(0.004)
+    assert got["idle_s"] == pytest.approx(0.065)
+    # a capture that began inside compile_launch still sums
+    late = [e for e in host if e.name != "dl4j/compile_launch"]
+    got = profiler.gaps_by_host_span(_profile(device, late))
+    assert got["launch_s"] == pytest.approx(0.054)
+    assert got["idle_s"] == pytest.approx(0.065)
+
+
 def test_op_names_reads_the_metadata_stat_from_the_file(tmp_path):
     def varint(n):
         out = bytearray()
@@ -402,6 +547,9 @@ def test_schema_knows_the_span_tree_and_the_new_families(
     assert schema.validate_chrome_trace_file(trace_path) == []
     text = registry.prometheus_text()
     assert "# TYPE dl4j_jit_cache_miss_total" in text
+    assert 'dl4j_step_program_bytes{part="temporaries"}' in text
+    assert "# TYPE dl4j_step_program_flops gauge" in text
+    assert 'dl4j_phase_duration_ms_count{phase="load_step"} 1' in text
     assert schema.validate_prometheus_text(text) == []
     assert schema.validate_known_metrics(text) == []
 
