@@ -20,7 +20,7 @@ from deeplearning4j_tpu.nn.quantize import is_quantized, qmatmul, qtake
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import Activation, activate
 from deeplearning4j_tpu.ops.losses import (LossFunction, _masked_mean,
-                                           compute_loss)
+                                           compute_loss, target_value)
 
 
 def _fused_logits_pair(activation: str, loss_function: str) -> bool:
@@ -170,11 +170,9 @@ class ExitGateOutputImpl(RnnOutputImpl):
         with jax.named_scope("lm_head"):
             z = self.preout(params, x)
         with jax.named_scope("loss"):
-            # flattened before the gather, as ops/losses.compute_loss does
-            z2 = z.reshape(-1, z.shape[-1]).astype(jnp.float32)
-            tgt = jnp.take_along_axis(z2, ids.reshape(-1, 1), axis=1)[:, 0]
-            return (jax.scipy.special.logsumexp(z2, axis=-1)
-                    - tgt).reshape(ids.shape)
+            z = z.astype(jnp.float32)
+            return (jax.scipy.special.logsumexp(z, axis=-1)
+                    - target_value(z, ids))
 
     def _gate_logit(self, params, x):
         with jax.named_scope("exit_gate"):

@@ -109,6 +109,24 @@ def _per_example(loss_fn_name: LossFunction, labels: jnp.ndarray, preds: jnp.nda
     raise ValueError(f"unknown loss function {f}")
 
 
+def target_value(z: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
+    """``z[..., ids]``: the value of sparse ids ``[...]`` along the last axis
+    of ``z`` ``[..., V]``, as a masked sum over that axis (negative ids read
+    class 0; ids >= V read 0.0).
+
+    Not ``take_along_axis``: a gather fixes its operand's layout, so where
+    the head's matmul writes the logits vocabulary-second (as it does for a
+    ``[8, 1024, 50257]`` float32 LM batch on a v5e) XLA relays all of them
+    out to read one number a token. A masked sum reads whatever layout the
+    matmul chose, and XLA fuses it into a pass that reads the logits anyway
+    (the log-sum-exp's, or the bias gradient's). The value is the gather's
+    to the bit (a sum of one value and zeros), the gradient the same
+    ``iota == id`` select the gather's transpose lowers to."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, z.shape, z.ndim - 1) \
+        == jnp.clip(ids, 0, None)[..., None]
+    return jnp.sum(jnp.where(hit, z, 0), axis=-1)
+
+
 def compute_loss(
     name: Union[str, LossFunction],
     labels: jnp.ndarray,
@@ -145,35 +163,24 @@ def compute_loss(
             f"sparse integer labels (shape {labels.shape} vs predictions "
             f"{predictions.shape}) are only supported for mcxent/nll")
     if sparse:
-        # integer class-id labels: gather the target log-prob instead of
+        # integer class-id labels: pick the target log-prob instead of
         # materializing one-hots — for a [b, t] LM batch over vocab V
         # this removes the [b, t, V] label tensor entirely (HBM traffic
         # and host->device staging shrink by a factor of V).
         # Contract: ids must be in [0, V); NEGATIVE ids are the
         # ignore-index convention — zero loss, excluded from the mean.
-        # (ids >= V clamp silently under jit, unlike the one-hot path —
-        # data validation belongs host-side.)
+        # (ids >= V read a target of 0.0 silently — data validation
+        # belongs host-side.)
         ids = labels.astype(jnp.int32)
         ignore = ids < 0
-        # flatten to 2D before the gather: XLA compiles take_along_axis
-        # on a >2D operand into a catastrophic gather (measured 53 ms vs
-        # 6.8 ms flattened for a [16,1024,8192] LM batch on v5e — it was
-        # ~50% of the whole GPT-base train step)
-        lead = ids.shape
-        nout = predictions.shape[-1]
-        pred2 = predictions.reshape(-1, nout)
-        ids2 = jnp.clip(ids, 0, None).reshape(-1, 1)
+        tgt = target_value(predictions, ids)
         if from_logits:
-            # -log_softmax[target] == logsumexp - target logit; gathering
-            # from the RAW logits keeps the softmax out of the gather's
-            # fusion entirely
-            tgt = jnp.take_along_axis(pred2, ids2, axis=1)[:, 0]
-            per_ex = (jax.scipy.special.logsumexp(pred2, axis=-1)
-                      - tgt).reshape(lead)
+            # -log_softmax[target] == logsumexp - target logit, both read
+            # from the RAW logits
+            per_ex = jax.scipy.special.logsumexp(predictions, axis=-1) - tgt
         else:
-            # gather first, then log N elements (not the [N, V] matrix)
-            tgt = jnp.take_along_axis(pred2, ids2, axis=1)[:, 0]
-            per_ex = -jnp.log(jnp.clip(tgt, _EPS, 1.0)).reshape(lead)
+            # pick first, then log N elements (not the [N, V] matrix)
+            per_ex = -jnp.log(jnp.clip(tgt, _EPS, 1.0))
         if mask is None:
             mask = (~ignore).astype(per_ex.dtype)
         else:

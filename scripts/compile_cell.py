@@ -3,9 +3,11 @@ has none, and print what the compiler says of it: the gauges a chip run's
 first dispatch sets from ``memory_analysis()`` and ``cost_analysis()``
 (``nn/scan_dispatch.record_step_program``: the only numbers that see the
 step's temporaries; the benchmark's ``step_compiled_peak_gb`` and
-``step_executable_mib`` read the same gauges on the chip), and the Pallas
-kernels in the compiled text. An AOT compile, not a chip run: nothing is
-executed and nothing here is a time.
+``step_executable_mib`` read the same gauges on the chip), the Pallas
+kernels in the compiled text, and its ``copy`` instructions of 64 MB and more
+(a relayout XLA put between a value's writer and a reader that wants another
+layout: shape, the two layouts, ``op_name``). An AOT compile, not a chip run:
+nothing is executed and nothing here is a time.
 
 Usage: python scripts/compile_cell.py <cell of BENCHMARK.json>
 
@@ -16,6 +18,7 @@ configuration) in the tree and compile again to size another keep-set.
 import collections
 import importlib
 import json
+import math
 import os
 import re
 import sys
@@ -32,6 +35,41 @@ from jax.sharding import SingleDeviceSharding
 from benchmarks import run as bench
 from deeplearning4j_tpu.nn.scan_dispatch import (record_step_program,
                                                  step_program_report)
+
+
+#: `%name = f32[8,1024,50257]{2,1,0:T(8,128)} copy(%operand), metadata={...}`
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\](\{[^}]*\})? "
+    r"([\w\-]+)\((?:[^%)]*?%([\w.\-]+))?")
+
+
+def large_copies(text, at_least=64e6):
+    """The compiled text's ``copy`` instructions of ``at_least`` bytes and
+    more (not ``copy-start`` / ``copy-done``, XLA's asynchronous moves),
+    alike ones counted together: the shape, the operand's layout and the
+    copy's own, whether the order of the dimensions differs (a relayout: the
+    other copies move a value between memory spaces), and ``op_name``."""
+    layouts, copies = {}, collections.Counter()
+    for line in text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        name, dtype, dims, layout, opcode, operand = found.groups()
+        layouts[name] = layout
+        width = re.search(r"\d+", dtype)
+        size = math.prod(int(d) for d in dims.split(",") if d) \
+            * (int(width.group()) // 8 if width else 1)
+        if opcode == "copy" and size >= at_least:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            # an operand stands above its reader in the text
+            copies[f"{dtype}[{dims}]", round(size / 1e6, 1),
+                   layouts.get(operand), layout,
+                   op_name and op_name.group(1)] += 1
+    order = lambda layout: layout.strip("{}").split(":")[0]
+    return [{"shape": shape, "MB": mb, "from": was, "to": to,
+             "relayout": bool(was and to) and order(was) != order(to),
+             "op_name": op_name, "copies": n}
+            for (shape, mb, was, to, op_name), n in copies.items()]
 
 
 def main(cell):
@@ -75,9 +113,10 @@ def main(cell):
         .lower(lowering_platforms=("tpu",)).compile()
     record_step_program(compiled)
     made = step_program_report()
+    text = compiled.as_text()
     kernels = collections.Counter(
         re.match(r"\s*%([A-Za-z_]+)", line).group(1)
-        for line in compiled.as_text().splitlines()
+        for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line)
     print(json.dumps({
         "cell": cell, "compile_s": round(time.perf_counter() - t0, 1),
@@ -86,7 +125,8 @@ def main(cell):
         "count_GB": made["count_bytes"] / 1e9,
         "code_MiB": made["code_bytes"] / 2 ** 20,
         "flops_a_step_T": made["flops"] / 1e12,
-        "kernel_calls_a_step": dict(kernels)}))
+        "kernel_calls_a_step": dict(kernels),
+        "copies_of_64_MB_and_more": large_copies(text)}))
 
 
 if __name__ == "__main__":

@@ -63,3 +63,40 @@ def assert_bucket_exact():
             f"{min(float(np.abs(got - r).max()) for r in refs):.3g}")
 
     return check
+
+
+@pytest.fixture
+def gathers_under():
+    """``find(text, scope)``: the names of the gathers that stand under the
+    named scope ``scope`` in a lowered text (``as_text(debug_info=True)``).
+    A gather's name is its own location's, or, where it sits in a private
+    function (``jit(take_along_axis)``, ``jit(_take)``), the name of each call
+    that reaches that function: the scope is on the call."""
+    import re
+
+    def find(text, scope):
+        named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+        sites, holders, inside = [], set(), None  # (function, callee, name)
+        for line in text.splitlines():
+            func = re.match(r"\s*func\.func \w+ @(\w+)", line)
+            if func:
+                inside = func.group(1)
+            at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+            name = named.get(at.group(1), "") if at else ""
+            call = re.search(r"\bcall @(\w+)\(", line)
+            if "stablehlo.gather" in line:
+                holders.add(inside)
+                sites.append((inside, None, name))
+            elif call:
+                sites.append((inside, call.group(1), name))
+        grew = True
+        while grew:  # a function that calls a holder holds a gather too
+            callers = {f for f, callee, _ in sites if callee in holders}
+            grew = not callers <= holders
+            holders |= callers
+        part = re.compile(rf"(^|[/(]){re.escape(scope)}[)/]")
+        return sorted({name for _, callee, name in sites
+                       if (callee is None or callee in holders)
+                       and part.search(name + "/")})
+
+    return find

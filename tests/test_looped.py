@@ -195,6 +195,37 @@ def test_exit_probabilities_sum_to_one_and_the_loss_is_a_hand_count():
     assert float(first) == pytest.approx(token0, rel=1e-5)
 
 
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_token_losses_are_the_gathers_value_and_gradient(compute_dtype):
+    """The tiny net's head on one pass's output: the masked sum against the
+    gather from the logits flattened to 2-D, the losses bitwise, the gradient
+    with respect to the head's input to 1e-6 of its largest entry."""
+    net = _net(compute_dtype).init()
+    head = net.out
+    params = head.cast_params(net.params[head.name], jnp.dtype(compute_dtype))
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 32, TINY["hidden_size"]),
+                          jnp.dtype(compute_dtype))
+    ids = jnp.asarray(_batch(t=32)[0][:, 1:], jnp.int32)
+
+    def gathered(x):
+        z2 = head.preout(params, x).astype(jnp.float32).reshape(
+            -1, TINY["vocab_size"])
+        tgt = jnp.take_along_axis(z2, ids.reshape(-1, 1), axis=1)[:, 0]
+        return (jax.scipy.special.logsumexp(z2, axis=-1)
+                - tgt).reshape(ids.shape)
+
+    got = head._token_losses(params, x, ids)
+    want = gathered(x)
+    assert got.dtype == jnp.float32
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    g_got = jax.grad(lambda x: head._token_losses(params, x, ids).sum())(x)
+    g_want = jax.grad(lambda x: gathered(x).sum())(x)
+    scale = float(jnp.max(jnp.abs(g_want.astype(jnp.float32))))
+    np.testing.assert_allclose(g_got.astype(jnp.float32),
+                               g_want.astype(jnp.float32),
+                               rtol=1e-6, atol=1e-6 * scale)
+
+
 def test_output_is_the_last_passes_prediction():
     net = _net()
     ref, _ = _with_reference_weights(net)
@@ -474,6 +505,12 @@ def test_the_step_names_its_parts_and_its_passes():
             continue  # no normalization here; attention at 16 takes XLA's form
         assert scope in text, scope
     assert all(f"pass{s}" in text for s in range(3)) and "pass3" not in text
+
+
+def test_the_looped_step_holds_no_gather_under_its_loss(gathers_under):
+    text = _lowered(_net("bfloat16").init(), debug_info=True)
+    assert gathers_under(text, "loss") == []
+    assert gathers_under(text, "embed")  # the reader reads this text
 
 
 # ------------------------------------------------------------ the builder

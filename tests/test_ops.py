@@ -10,7 +10,8 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.ops.activations import Activation, activate, activation_gradient
-from deeplearning4j_tpu.ops.losses import LossFunction, compute_loss
+from deeplearning4j_tpu.ops.losses import (LossFunction, _masked_mean,
+                                           compute_loss, target_value)
 from deeplearning4j_tpu.nn.weights import WeightInit, init_weights
 
 
@@ -166,6 +167,65 @@ def test_sparse_mcxent_ignore_index(rng):
     want = compute_loss("mcxent", sparse, logits, mask=keep, from_logits=True)
     got = compute_loss("mcxent", ignored, logits, from_logits=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+
+
+def _gathered_loss(ids, preds, mask, from_logits):
+    """The sparse branch as it stood: the target picked by a gather from the
+    predictions flattened to 2-D."""
+    ids = ids.astype(jnp.int32)
+    pred2 = preds.reshape(-1, preds.shape[-1])
+    tgt = jnp.take_along_axis(
+        pred2, jnp.clip(ids, 0, None).reshape(-1, 1), axis=1)[:, 0]
+    if from_logits:
+        per_ex = jax.scipy.special.logsumexp(pred2, axis=-1) - tgt
+    else:
+        per_ex = -jnp.log(jnp.clip(tgt, 1e-7, 1.0))
+    keep = (ids >= 0).astype(jnp.float32)
+    if mask is not None:
+        keep = mask * keep
+    return _masked_mean(per_ex.reshape(ids.shape), keep)
+
+
+@pytest.mark.parametrize("ids_are", ["all kept", "masked", "some ignored",
+                                     "all ignored"])
+@pytest.mark.parametrize("from_logits", [True, False],
+                         ids=["logits", "probabilities"])
+@pytest.mark.parametrize("lead", [(6,), (3, 17)], ids=["2-D", "3-D"])
+def test_sparse_target_is_the_gathers_value_and_gradient(
+        rng, lead, from_logits, ids_are):
+    """The masked sum over the class axis against ``take_along_axis``: the
+    loss bitwise (a sum of one value and zeros), the gradient with respect to
+    the predictions to 1e-6."""
+    c = 257
+    logits = jnp.asarray(rng.standard_normal(lead + (c,)), jnp.float32)
+    preds = logits if from_logits else jax.nn.softmax(logits, axis=-1)
+    ids = rng.integers(0, c, lead).astype(np.float32)
+    mask = None
+    if ids_are == "masked":
+        mask = jnp.asarray(rng.random(lead) > 0.4, jnp.float32)
+    elif ids_are == "some ignored":
+        ids[rng.random(lead) > 0.6] = -1.0
+        ids.flat[0] = -100.0
+    elif ids_are == "all ignored":
+        ids[...] = -1.0
+    ids = jnp.asarray(ids)
+    got, g_got = jax.value_and_grad(lambda z: compute_loss(
+        "mcxent", ids, z, mask=mask, from_logits=from_logits))(preds)
+    want, g_want = jax.value_and_grad(
+        lambda z: _gathered_loss(ids, z, mask, from_logits))(preds)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    np.testing.assert_allclose(g_got, g_want, rtol=1e-6, atol=1e-6)
+    if ids_are == "all ignored":
+        assert float(got) == 0.0 and not np.any(g_got)
+
+
+def test_target_value_reads_the_last_axis_of_any_rank(rng):
+    z = jnp.asarray(rng.standard_normal((2, 3, 4, 9)), jnp.float32)
+    ids = jnp.asarray(rng.integers(0, 9, (2, 3, 4)), jnp.int32)
+    want = jnp.take_along_axis(z, ids[..., None], axis=-1)[..., 0]
+    np.testing.assert_array_equal(target_value(z, ids), want)
+    # a negative id reads class 0 (the loss masks it out)
+    np.testing.assert_array_equal(target_value(z, -ids - 1), z[..., 0])
 
 
 class TestMaxpoolMaskVJP:

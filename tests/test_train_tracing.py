@@ -109,6 +109,25 @@ def test_grad_norm_scope_appears_when_normalization_is_on():
     assert '"jvp(lm_head)/' in text and '"jvp(loss)/' in text
 
 
+def test_the_step_picks_the_target_logit_without_a_gather(gathers_under):
+    """A gather fixes the logits' layout (a 1.65 GB relayout a step of the
+    GPT cells until PR 38): the loss reads its target with a masked sum."""
+    import jax.numpy as jnp
+    net, staged = _net_and_set()
+    text = _lowered(net, staged, debug_info=True)
+    assert gathers_under(text, "loss") == []
+    # the reader sees the step's other gather, and one where it is looked for
+    assert gathers_under(text, "embed")
+
+    def gathered(z, ids):
+        with jax.named_scope("loss"):
+            return jnp.take_along_axis(z, ids, axis=1).sum()
+
+    planted = jax.jit(gathered).lower(
+        jnp.zeros((8, 64)), jnp.zeros((8, 1), jnp.int32))
+    assert gathers_under(planted.as_text(debug_info=True), "loss")
+
+
 # --------------------------------------------------------------- span tree
 
 def _graph_and_set():
@@ -517,6 +536,30 @@ def test_self_times_is_the_walk_the_script_uses():
                    "%fusion.2": 5}
     assert profiler.op_group("%fusion.12 = bf16[8]{0} fusion(...)") == "fusion"
     assert profiler.op_group("%jvp_flash_fwd_.288 = (...)") == "jvp_flash_fwd_"
+
+
+def test_compile_cell_lists_the_relayouts_of_a_compiled_text():
+    """``scripts/compile_cell.py`` on the three lines PR 38 found in the GPT
+    cells' step, beside what it must not list."""
+    from scripts.compile_cell import large_copies
+    text = """
+  %get-tuple-element.29904 = f32[8,1024,50257]{1,2,0:T(8,128)} get-tuple-element(%fusion.5134), index=1
+  %copy.299 = f32[8,1024,50257]{2,1,0:T(8,128)} copy(%get-tuple-element.29904), metadata={op_name="jit(run)/jvp(lm_head)/add" source_file="f.py"}
+  %bitcast.2927 = f32[8192,50257]{1,0:T(8,128)} bitcast(%copy.299), metadata={op_name="jit(run)/jvp(loss)/reshape"}
+  %param.3 = f32[2048,8512]{0,1:T(8,128)} parameter(3)
+  %copy.7 = f32[2048,8512]{0,1:T(8,128)S(1)} copy(%param.3), metadata={op_name="jit(run)/remat2"}
+  %copy.8 = f32[2048,8512]{0,1:T(8,128)S(1)} copy(%param.3), metadata={op_name="jit(run)/remat2"}
+  %copy.9 = f32[1024,1024]{0,1:T(8,128)} copy(%get-tuple-element.29904)
+  %copy-done.4 = f32[8,1024,50257]{1,2,0:T(8,128)S(1)} copy-done(%copy-start.4)
+"""
+    assert large_copies(text) == [
+        {"shape": "f32[8,1024,50257]", "MB": 1646.8,
+         "from": "{1,2,0:T(8,128)}", "to": "{2,1,0:T(8,128)}",
+         "relayout": True, "op_name": "jit(run)/jvp(lm_head)/add",
+         "copies": 1},
+        {"shape": "f32[2048,8512]", "MB": 69.7, "from": "{0,1:T(8,128)}",
+         "to": "{0,1:T(8,128)S(1)}", "relayout": False,
+         "op_name": "jit(run)/remat2", "copies": 2}]
 
 
 # ------------------------------------------------------------------ schema
