@@ -136,6 +136,17 @@ SELSCAN_PATH_COUNTER = "dl4j_selscan_path_total"
 # ops/flash_attention.py: flash_attention calls traced with a window (a query
 # sees itself and the window - 1 keys before it), whatever kernels they chose
 FLASH_WINDOWED_COUNTER = "dl4j_flash_windowed_total"
+# nn/layers/moe.py: routed expert layers traced, labeled path="gmm" (the
+# grouped-matmul kernels gmm_fwd / gmm_dx / gmm_dw) or "xla" (ragged_dot),
+# chosen from the shapes and the backend while tracing
+MOE_PATH_COUNTER = "dl4j_moe_path_total"
+# nn/multilayer.py: routed experts each expert layer of the train step just
+# built holds (the most of any; 0: none), and how many such layers it applies
+MOE_EXPERTS_HELD_GAUGE = "dl4j_moe_experts_held"
+MOE_LAYERS_GAUGE = "dl4j_moe_layers"
+# set by a training driver under the calibrated expert bias: the held
+# experts' share of the assignments, labeled stat="min" | "max" over layers
+MOE_HELD_SHARE_GAUGE = "dl4j_moe_held_share"
 # nn/multilayer.py: blocks whose bodies the train step just built recomputes
 # in its backward pass (conf.recompute_blocks); 0 for a model that keeps them
 RECOMPUTED_BLOCKS_GAUGE = "dl4j_recomputed_blocks"

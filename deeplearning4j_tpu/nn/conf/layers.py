@@ -298,7 +298,18 @@ class GatedDecoderBlock(FeedForwardLayer):
     a mixer, a gated MLP ``(silu(a) * b) W_down`` with ``[a, b] = h
     W_gate_up`` of hidden width ``ffn_hidden``, no biases, and both
     residual branches scaled by ``residual_multiplier``. Training only:
-    these blocks have no cache (ROADMAP Reach A.8)."""
+    these blocks have no cache (ROADMAP Reach A.8).
+
+    With ``num_experts`` > 0 the gated MLP is a layer of routed experts
+    (``nn/layers/moe.py::routed_experts``): a router scores all
+    ``num_experts``, each token takes ``experts_per_token`` of them on the
+    sigmoid scores plus, with
+    ``expert_bias``, a per-expert bias that decides the selection only and
+    lives in the layer's state, weighted by the scores (normalised over the
+    picks with ``norm_topk_prob``, times ``routed_scaling_factor``); each
+    expert is a gated MLP of width ``expert_hidden``. The layer holds the
+    experts ``experts_held = (first, count)`` (count 0: all) and computes
+    their part of the result; no capacity, no token dropped."""
 
     ffn_hidden: int = 0
     rms_eps: float = 1e-5
@@ -310,6 +321,13 @@ class GatedDecoderBlock(FeedForwardLayer):
     # nn/layers/hybrid.py); None: what the block's class keeps. A block that
     # runs several times a step holds each kept value once an application
     kept_values: Optional[Tuple[str, ...]] = None
+    num_experts: int = 0
+    experts_per_token: int = 0
+    experts_held: Tuple[int, int] = (0, 0)
+    expert_hidden: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    expert_bias: bool = False
 
 
 @register_layer
@@ -344,6 +362,20 @@ class GroupedQueryBlock(GatedDecoderBlock):
     num_kv_heads: int = 8
     attention_multiplier: Optional[float] = None
     rope_theta: Optional[float] = None
+    # an RMSNorm over each head of q and of k (a gain of ``d_head`` each)
+    # before the rotation
+    qk_norm: bool = False
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class ShortConvBlock(GatedDecoderBlock):
+    """A gated short-convolution mixer and the gated MLP (or routed experts):
+    ``[B, C, x] = h W_in`` (three widths of d_model), ``u = B * x``, a causal
+    depthwise convolution of ``conv_kernel`` taps over time with no bias and
+    no activation, ``y = (C * conv(u)) W_out``. n_in == n_out == d_model."""
+
+    conv_kernel: int = 3
 
 
 def parse_reads(layer: "Layer"):

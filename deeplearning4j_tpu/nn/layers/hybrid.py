@@ -4,7 +4,9 @@ their scaled residual branches, and the stand-alone RMSNorm before the head;
 and the blocks of the decoder-hybrid-decoder family (``LayerNormDecoderImpl``
 down: a Mamba-1 block, a differential attention block and a gated memory
 unit on one LayerNorm + gated-MLP body, some of which hand values forward to
-later layers), and its stand-alone LayerNorm.
+later layers), and its stand-alone LayerNorm. The RMSNorm body also carries
+a gated short-convolution mixer, and its gated MLP may be a layer of routed
+experts (``nn/layers/moe.py``).
 
 No reference counterpart. Both blocks run ``x + m * mixer(norm(x))`` then
 ``x + m * mlp(norm(x))``; the mixers differ. With ``branch_norms`` each
@@ -29,6 +31,10 @@ from jax.ad_checkpoint import checkpoint_name
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.attention import dispatch_attention
 from deeplearning4j_tpu.nn.layers.base import LayerImpl, register_impl
+from deeplearning4j_tpu.nn.layers.moe import (EXPERT_BIAS_KEY,
+                                              EXPERT_GATE_UP_PRODUCT,
+                                              init_routed_params,
+                                              routed_experts)
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.attention import rotary
 from deeplearning4j_tpu.ops.flash_attention import FLASH_RESIDUAL_NAMES
@@ -61,13 +67,18 @@ def layer_norm(x, gain, bias, eps):
     return out.astype(x.dtype)
 
 
-def causal_conv_silu(x, w, bias):
-    """``silu`` of a causal depthwise convolution over time of x [b, t, c]
-    with taps w [d_conv, c]: tap j reads the token d_conv - 1 - j back."""
+def causal_conv(x, w):
+    """A causal depthwise convolution over time of x [b, t, c] with taps
+    w [d_conv, c]: tap j reads the token d_conv - 1 - j back."""
     taps, t = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     w = w.astype(x.dtype)
-    x = sum(padded[:, j:j + t] * w[j] for j in range(taps))
+    return sum(padded[:, j:j + t] * w[j] for j in range(taps))
+
+
+def causal_conv_silu(x, w, bias):
+    """``silu`` of ``causal_conv`` plus a bias."""
+    x = causal_conv(x, w)
     return jax.nn.silu(x + bias.astype(x.dtype))
 
 
@@ -95,7 +106,9 @@ class RMSNormImpl(LayerImpl):
 
 
 class GatedDecoderImpl(LayerImpl):
-    """The shared body; a subclass gives ``_mixer_params`` and ``_mixer``."""
+    """The shared body; a subclass gives ``_mixer_params`` and ``_mixer``.
+    The feed-forward is the gated MLP, or the routed experts where the
+    configuration has ``num_experts``."""
 
     #: the container may recompute this layer's forward in the backward pass
     recomputable = True
@@ -103,12 +116,31 @@ class GatedDecoderImpl(LayerImpl):
     #: body to make again: 4 * ffn_hidden bytes a token in bfloat16 beside
     #: the 2 * d_model of the block's input
     kept_names = (GATE_UP_PRODUCT,)
-    #: what ``kept_values`` of the configuration may name in its place
-    KEEPABLE = (GATE_UP_PRODUCT,)
+    #: what ``kept_values`` of the configuration may name in its place (the
+    #: routed experts' gate/up product where the block has experts)
+    KEEPABLE = (GATE_UP_PRODUCT, EXPERT_GATE_UP_PRODUCT)
+    #: leaves kept in float32 whatever the compute dtype
+    FLOAT32_LEAVES = ()
 
     def __init__(self, global_conf, conf, name):
         super().__init__(global_conf, conf, name)
         self.kept_names = _keep_set(self, conf, name)
+
+    def cast_params(self, params, dtype):
+        cast = cast_floats(params, dtype)
+        # and the routed experts' router: its product is float32
+        keep = self.FLOAT32_LEAVES + tuple(k for k in ("W_router",)
+                                           if k in params)
+        cast.update({k: params[k] for k in keep})
+        return cast
+
+    def init_state(self):
+        c = self.conf
+        if c.num_experts and c.expert_bias:
+            # the selection bias: set by whoever balances the experts' loads,
+            # never by a gradient
+            return {EXPERT_BIAS_KEY: jnp.zeros((c.num_experts,), jnp.float32)}
+        return {}
 
     def _matrix(self, key, shape):
         c = self.conf
@@ -119,17 +151,22 @@ class GatedDecoderImpl(LayerImpl):
         c = self.conf
         if c.n_out != c.n_in:
             raise ValueError(f"{type(c).__name__} needs n_in == n_out (d_model)")
-        if c.ffn_hidden <= 0:
-            raise ValueError(f"{type(c).__name__} needs ffn_hidden > 0")
         d, f = c.n_out, c.ffn_hidden
         k_mix, k_up, k_down = jax.random.split(key, 3)
         params = self._mixer_params(k_mix)
-        params.update({
-            "rms1_g": jnp.ones((d,), jnp.float32),
-            "rms2_g": jnp.ones((d,), jnp.float32),
-            "W_gate_up": self._matrix(k_up, (d, 2 * f)),
-            "W_down": self._matrix(k_down, (f, d)),
-        })
+        params.update({"rms1_g": jnp.ones((d,), jnp.float32),
+                       "rms2_g": jnp.ones((d,), jnp.float32)})
+        if c.num_experts:
+            if c.expert_hidden <= 0 or not 0 < c.experts_per_token <= c.num_experts:
+                raise ValueError(f"{type(c).__name__} with experts needs "
+                                 "expert_hidden > 0 and 0 < experts_per_token"
+                                 " <= num_experts")
+            params.update(init_routed_params(k_up, c, self._matrix))
+        elif f <= 0:
+            raise ValueError(f"{type(c).__name__} needs ffn_hidden > 0")
+        else:
+            params.update({"W_gate_up": self._matrix(k_up, (d, 2 * f)),
+                           "W_down": self._matrix(k_down, (f, d))})
         if c.branch_norms:
             params.update({"mixer_norm_g": jnp.ones((d,), jnp.float32),
                            "mlp_norm_g": jnp.ones((d,), jnp.float32)})
@@ -149,12 +186,15 @@ class GatedDecoderImpl(LayerImpl):
         x = x + (m * h).astype(x.dtype)
         with jax.named_scope("rms2"):
             h = rms_norm(x, params["rms2_g"], c.rms_eps)
-        with jax.named_scope("mlp_gate_up"):
-            a, b = jnp.split(checkpoint_name(h @ params["W_gate_up"],
-                                             GATE_UP_PRODUCT), 2, axis=-1)
-            h = jax.nn.silu(a) * b
-        with jax.named_scope("mlp_down"):
-            h = h @ params["W_down"]
+        if c.num_experts:
+            h = routed_experts(params, h, state, c)
+        else:
+            with jax.named_scope("mlp_gate_up"):
+                a, b = jnp.split(checkpoint_name(h @ params["W_gate_up"],
+                                                 GATE_UP_PRODUCT), 2, axis=-1)
+                h = jax.nn.silu(a) * b
+            with jax.named_scope("mlp_down"):
+                h = h @ params["W_down"]
         if c.branch_norms:
             with jax.named_scope("mlp_out_norm"):
                 h = rms_norm(h, params["mlp_norm_g"], c.rms_eps)
@@ -177,11 +217,6 @@ class Mamba2BlockImpl(GatedDecoderImpl):
     #: leaves the scan reads in float32 whatever the compute dtype: a decay
     #: rounded to bfloat16 is another model
     FLOAT32_LEAVES = ("A_log", "dt_bias", "D")
-
-    def cast_params(self, params, dtype):
-        cast = cast_floats(params, dtype)
-        cast.update({k: params[k] for k in self.FLOAT32_LEAVES})
-        return cast
 
     def _widths(self):
         c = self.conf
@@ -256,10 +291,15 @@ class GroupedQueryBlockImpl(GatedDecoderImpl):
         d = c.n_out
         kv = c.num_kv_heads * (d // c.num_heads)
         ks = jax.random.split(key, 4)
-        return {"Wq": self._matrix(ks[0], (d, d)),
-                "Wk": self._matrix(ks[1], (d, kv)),
-                "Wv": self._matrix(ks[2], (d, kv)),
-                "Wo": self._matrix(ks[3], (d, d))}
+        params = {"Wq": self._matrix(ks[0], (d, d)),
+                  "Wk": self._matrix(ks[1], (d, kv)),
+                  "Wv": self._matrix(ks[2], (d, kv)),
+                  "Wo": self._matrix(ks[3], (d, d))}
+        if c.qk_norm:
+            hd = d // c.num_heads
+            params.update({"q_norm_g": jnp.ones((hd,), jnp.float32),
+                           "k_norm_g": jnp.ones((hd,), jnp.float32)})
+        return params
 
     def _mixer(self, params, h, mask):
         c = self.conf
@@ -274,6 +314,11 @@ class GroupedQueryBlockImpl(GatedDecoderImpl):
             q = q.reshape(b, t, heads, hd)
             if mult is not None:
                 q = (q * (mult * math.sqrt(hd))).astype(q.dtype)
+            if c.qk_norm:
+                with jax.named_scope("qk_norm"):
+                    q = rms_norm(q, params["q_norm_g"], c.rms_eps)
+                    k = rms_norm(k.reshape(b, t, kv, hd), params["k_norm_g"],
+                                 c.rms_eps).reshape(b, t, kv * hd)
             if c.rope_theta is not None:
                 with jax.named_scope("rope"):
                     q = rotary(q, c.rope_theta)
@@ -288,6 +333,31 @@ class GroupedQueryBlockImpl(GatedDecoderImpl):
                                    mesh=self._mesh)
         with jax.named_scope("attn_out_proj"):
             return o.reshape(b, t, d) @ params["Wo"]
+
+
+@register_impl(L.ShortConvBlock)
+class ShortConvBlockImpl(GatedDecoderImpl):
+    """The gated short convolution: ``[B, C, x] = h W_in``, ``u = B * x``, a
+    causal depthwise convolution of u (no bias, no activation), ``(C *
+    conv(u)) W_out``."""
+
+    def _mixer_params(self, key):
+        c = self.conf
+        d = c.n_out
+        k_in, k_conv, k_out = jax.random.split(key, 3)
+        bound = 1.0 / math.sqrt(c.conv_kernel)
+        return {"W_in": self._matrix(k_in, (d, 3 * d)),
+                "conv_w": jax.random.uniform(k_conv, (c.conv_kernel, d),
+                                             jnp.float32, -bound, bound),
+                "W_out": self._matrix(k_out, (d, d))}
+
+    def _mixer(self, params, h, mask):
+        with jax.named_scope("conv_in_proj"):
+            gate_b, gate_c, x = jnp.split(h @ params["W_in"], 3, axis=-1)
+        with jax.named_scope("short_conv"):
+            y = gate_c * causal_conv(gate_b * x, params["conv_w"])
+        with jax.named_scope("conv_out_proj"):
+            return y @ params["W_out"]
 
 
 # ------------------------------------ the decoder-hybrid-decoder family

@@ -47,6 +47,8 @@ from deeplearning4j_tpu.nn.updater import (
 from deeplearning4j_tpu.monitor import (BLOCK_APPLICATIONS_GAUGE,
                                         FORWARDED_VALUES_GAUGE,
                                         H2D_BYTES_COUNTER,
+                                        MOE_EXPERTS_HELD_GAUGE,
+                                        MOE_LAYERS_GAUGE,
                                         RECOMPUTE_KEPT_VALUES_GAUGE,
                                         RECOMPUTED_BLOCKS_GAUGE,
                                         SPAN_PASSES_GAUGE, get_registry, span)
@@ -98,6 +100,16 @@ SAMBAY_STEP_SCOPES = (
     "diff_combine", "attn_out_proj", "gmu_in_proj", "gmu_gate",
     "gmu_out_proj", "ln2", "mlp_fc", "mlp_proj", "final_norm", "fold_heads",
     "unfold_heads")
+#: and for a model of gated short convolutions, QK-normed grouped-query
+#: attention and routed experts (models/zoo/lfm2_moe.py): the RMSNorm body
+#: with its expert layer (the router, the sort and gather of the held
+#: assignments, the two grouped products, the weighted sum back)
+LFM2_STEP_SCOPES = (
+    "grad_norm", "optimizer_update", "lm_head", "loss", "embed", "rms1",
+    "conv_in_proj", "short_conv", "conv_out_proj", "qkv_proj", "qk_norm",
+    "rope", "kv_repeat", "attention", "attn_out_proj", "rms2", "mlp_gate_up",
+    "mlp_down", "router", "moe_permute", "expert_gate_up", "expert_down",
+    "moe_combine", "final_norm", "fold_heads", "unfold_heads")
 
 
 class MultiLayerNetwork:
@@ -401,6 +413,17 @@ class MultiLayerNetwork:
             FORWARDED_VALUES_GAUGE, "named values that layers of that step "
             "hand forward to later layers").set(
             sum(len(getattr(impl.conf, "provides", ())) for impl in applied))
+        experts = [impl.conf for impl in applied
+                   if getattr(impl.conf, "experts_held", None)
+                   and impl.conf.num_experts]
+        get_registry().gauge(
+            MOE_EXPERTS_HELD_GAUGE, "routed experts each expert layer of that "
+            "step holds (the most of any); 0: no expert layer").set(
+            max((c.experts_held[1] or c.num_experts for c in experts),
+                default=0))
+        get_registry().gauge(
+            MOE_LAYERS_GAUGE, "applications of routed expert layers in that "
+            "step").set(len(experts))
         gn_specs = []
         for impl in self.impls:
             nt = GradientNormalization(self.gc.resolve(impl.conf, "gradient_normalization"))

@@ -455,6 +455,10 @@ KNOWN_DL4J_METRICS = {
     "dl4j_forwarded_values",
     "dl4j_step_program_bytes",
     "dl4j_step_program_flops",
+    "dl4j_moe_path_total",
+    "dl4j_moe_experts_held",
+    "dl4j_moe_layers",
+    "dl4j_moe_held_share",
     # serving plane (parallel/inference.py ParallelInference)
     "dl4j_infer_requests_total",
     "dl4j_infer_batches_total",

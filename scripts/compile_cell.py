@@ -81,7 +81,8 @@ def main(cell):
                               f"benchmarks/traffic/{workload['traffic']}.json")
     # the kernels themselves, not the interpreter the CPU backend would get
     # (by module name: ``ops/__init__`` re-exports functions under them)
-    for mod in ("ssd", "selective_scan", "flash_attention", "attention"):
+    for mod in ("ssd", "selective_scan", "flash_attention", "attention",
+                "grouped_matmul"):
         importlib.import_module(
             f"deeplearning4j_tpu.ops.{mod}").pallas_interpret = lambda: False
     # a compile for a described device cannot be read back from the cache

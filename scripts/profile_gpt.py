@@ -23,11 +23,13 @@ import numpy as np
 
 from deeplearning4j_tpu.monitor import (FLASH_PATH_COUNTER,
                                         FLASH_WINDOWED_COUNTER,
+                                        MOE_PATH_COUNTER,
                                         SELSCAN_PATH_COUNTER, SSD_PATH_COUNTER,
                                         get_registry)
 from deeplearning4j_tpu.monitor import phase_breakdown
 from deeplearning4j_tpu.nn.scan_dispatch import step_program_report
 from deeplearning4j_tpu.nn.multilayer import (HYBRID_STEP_SCOPES,
+                                              LFM2_STEP_SCOPES,
                                               LOOPED_STEP_SCOPES,
                                               SAMBAY_STEP_SCOPES, STEP_SCOPES)
 from deeplearning4j_tpu.util import profiler
@@ -121,7 +123,7 @@ def profile_cell(workload, seed, dispatches=3):
     print("kernels chosen while tracing:", {
         f"{name}{dict(labels)}": metric.value
         for name in (FLASH_PATH_COUNTER, FLASH_WINDOWED_COUNTER,
-                     SSD_PATH_COUNTER, SELSCAN_PATH_COUNTER)
+                     SSD_PATH_COUNTER, SELSCAN_PATH_COUNTER, MOE_PATH_COUNTER)
         for labels, metric in get_registry().family(name).items()})
     # the first dispatch by stage, and what it made by the compiler's count
     phases = phase_breakdown()
@@ -143,6 +145,7 @@ def profile_cell(workload, seed, dispatches=3):
     # the family by the key only its configurations have
     scopes = (LOOPED_STEP_SCOPES if "total_ut_steps" in cell["config"]
               else SAMBAY_STEP_SCOPES if "sliding_window" in cell["config"]
+              else LFM2_STEP_SCOPES if "num_routed_experts" in cell["config"]
               else HYBRID_STEP_SCOPES if "layer_types" in cell["config"]
               else STEP_SCOPES)
     print_trace(log_dir, dispatches * r.k, scopes)
